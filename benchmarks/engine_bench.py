@@ -11,10 +11,12 @@ number is the median over repeats (3 in ``--quick`` — the repeat-median the
 CI perf gate leans on against runner jitter).
 
 The ``--devices`` dimension re-runs the scan engine with
-``FedConfig.mesh_shape=k`` for each requested device count: every count
-spawns a worker process with ``XLA_FLAGS=--xla_force_host_platform_
-device_count=k`` (the flag must land before jax initializes), so one
-invocation records the 1-vs-k scaling curve.
+``FedConfig.mesh_shape=k`` for each requested device count, so one
+invocation records the 1-vs-k scaling curve.  On the CPU backend every
+count spawns a worker process with ``XLA_FLAGS=--xla_force_host_platform_
+device_count=k`` (the flag must land before jax initializes); on an
+accelerator, whose chips belong to one process, every count runs in this
+process over the first k devices.
 
 The ``defense`` axis re-runs the scan engine per robust-defense strategy
 (none vs dense foolsgold vs the sketched cluster-aware variant), pricing
@@ -389,8 +391,13 @@ def bench_faults(quick: bool = False) -> dict:
 
 
 def bench_devices(quick: bool = False, counts=DEVICE_COUNTS) -> dict:
-    """rounds/sec of the scan engine per host device count: one worker
-    process per count so the XLA device flag precedes jax init."""
+    """rounds/sec of the scan engine per device count.  On the CPU backend
+    each count gets a worker process, so its XLA host-device flag precedes
+    jax init.  On an accelerator this process already holds the chips, and
+    a child could not open them: every count runs here, over
+    ``jax.devices()[:k]``."""
+    if jax.default_backend() != "cpu":
+        return {str(k): bench_sharded_worker(k, quick) for k in counts}
     result = {}
     for k in counts:
         env = dict(os.environ)

@@ -22,7 +22,9 @@ import numpy as np
 
 from repro.common.config import INPUT_SHAPES
 from repro.configs import get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_KIND, chip_peaks
+
+TARGET = chip_peaks(TARGET_KIND)  # the v5e chip these rooflines model
 
 DRYRUN_PATH = os.environ.get("DRYRUN_PATH", "dryrun_all.jsonl")
 ROOFLINE_PATH = os.environ.get("ROOFLINE_PATH", "roofline_all.jsonl")
@@ -85,9 +87,9 @@ def load_records(path: Optional[str] = None):
 def analyse(rec: dict) -> dict:
     chips = rec["chips"]
     mf = model_flops(rec["arch"], rec["shape"])
-    t_c = rec["hlo_flops"] / PEAK_FLOPS_BF16
-    t_m = rec["hlo_bytes"] / HBM_BW
-    t_x = rec["collective_bytes_total"] / ICI_BW
+    t_c = rec["hlo_flops"] / TARGET.flops_bf16
+    t_m = rec["hlo_bytes"] / TARGET.hbm_bw
+    t_x = rec["collective_bytes_total"] / TARGET.ici_bw
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])[0]
     useful = mf / chips / max(rec["hlo_flops"], 1.0)

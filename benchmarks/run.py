@@ -22,7 +22,8 @@ def main() -> None:
         rows += fedar_figs.selection_ablation()
         rows += fedar_figs.poisoning_defense()
     engine_rows, engine_summary = engine_bench.bench(quick=quick)
-    # mesh-sharded scaling runs in worker processes (device flag precedes jax)
+    # mesh-sharded scaling: CPU worker processes (the device flag precedes
+    # jax), or in this process on an accelerator, which it already holds
     engine_devices = engine_bench.bench_devices(quick=quick)
     engine_defense = engine_bench.bench_defense(quick=quick)
     engine_scenario = engine_bench.bench_scenario(quick=quick)

@@ -115,6 +115,9 @@ def main(argv=None):
             + f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
 
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     results = {}
     if not args.baseline:
         print(f"== FedAR federated LM ({args.arch}) ==")

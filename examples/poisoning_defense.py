@@ -88,12 +88,14 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.common.compile_cache import enable_compile_cache
     from repro.configs.fedar_mnist import MnistConfig, fleet_fed
     from repro.core.fedar import FedARServer
     from repro.core.resources import TaskRequirement
     from repro.data.federated import sybil_fleet, table2_fleet
     from repro.data.sources import eval_source, get_source
 
+    enable_compile_cache()
     paper_scale = args.clients == 12
     mesh = args.devices if args.devices > 1 else None
     source = get_source(args.dataset, cache_dir=args.cache_dir)

@@ -132,10 +132,12 @@ def main():
     import numpy as np
 
     from repro import FedARServer, TaskRequirement, make_federated
+    from repro.common.compile_cache import enable_compile_cache
     from repro.configs.fedar_mnist import MnistConfig, fleet_fed
     from repro.data.datasets import VirtualFleet
     from repro.data.sources import eval_source
 
+    enable_compile_cache()
     name = args.dataset
     if name == "auto":
         name = "table2" if args.clients == 12 else "scaled"

@@ -12,11 +12,13 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models.model import Model
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("gemma3-1b").reduced()
     model = Model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))

@@ -297,8 +297,8 @@ class FedConfig:
     # None or 1 keeps the single-device path (exact seed numerics); k > 1
     # shards every client-indexed (N, ...) tensor into N/k blocks and turns
     # aggregation into a trust*staleness-weighted psum.  num_clients must be
-    # divisible by the shard count.  Falls back to single-device when the
-    # host exposes one device.
+    # divisible by the shard count, and the host must expose k devices
+    # (fewer is an error, never a narrower mesh).
     mesh_shape: Optional[int] = None
     client_axis: str = "clients"
     seed: int = 0

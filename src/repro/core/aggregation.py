@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from repro.common.config import FedConfig
 from repro.core.distributed import ClientComms
 from repro.kernels.fedavg_agg import fedavg_agg
-from repro.kernels.ops import resolve_impl
+from repro.kernels.ops import interpret_mode, resolve_impl
 
 _IDENTITY = ClientComms()
 
@@ -142,7 +142,7 @@ def fedavg_aggregate(
         num = fedavg_agg(
             deltas, w_loc,
             staleness=stale_loc,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret_mode(),
         )
     else:
         decay_loc = (

@@ -26,6 +26,7 @@ whole table through ``checkpoint/ckpt.py`` (``save_store`` /
 """
 from __future__ import annotations
 
+import mmap
 from typing import NamedTuple
 
 import numpy as np
@@ -33,6 +34,30 @@ import numpy as np
 from repro.common.config import FedConfig
 from repro.core.resources import BATTERY_COST, make_fleet
 from repro.core.trust import TrustState
+
+
+# Linux's MAP_NORESERVE; the mmap module only exports it from Python 3.13
+_MAP_NORESERVE = getattr(mmap, "MAP_NORESERVE", 0x4000)
+
+
+def _lazy_zeros(shape, dtype=np.float32) -> np.ndarray:
+    """A zero-filled array whose pages are only backed once written.
+
+    The per-client model-width columns (error-feedback residual, async
+    pending delta) are (N, D): at a million clients of the 784-128-10 MLP
+    that is ~400 GB of address space, of which a run only ever writes the
+    rows of the clients it sampled.  ``np.zeros`` asks the kernel to
+    account for all of it up front and is refused; an anonymous
+    ``MAP_NORESERVE`` mapping is not accounted, reads of untouched rows
+    hit the shared zero page, and memory grows with the rows written."""
+    nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    if nbytes == 0:
+        return np.zeros(shape, dtype)
+    buf = mmap.mmap(
+        -1, nbytes,
+        flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS | _MAP_NORESERVE,
+    )
+    return np.frombuffer(buf, dtype).reshape(shape)
 
 
 class HostResources(NamedTuple):
@@ -60,7 +85,8 @@ _COLUMNS = (
 
 
 class ClientStore:
-    """Numpy-backed per-client table; O(N * smallstate) host memory."""
+    """Numpy-backed per-client table: O(N * smallstate) host memory, plus
+    O(D) for each client whose model-width rows were ever written."""
 
     def __init__(self, fed: FedConfig, history_dim: int, *,
                  residual_dim: int = 0, pending_dim: int = 0,
@@ -88,15 +114,15 @@ class ClientStore:
         self.bandwidth = np.array(res.bandwidth)
         self.battery = np.array(res.battery)
         self.compute = np.array(res.compute)
-        self.history = np.zeros((n, history_dim), np.float32)
+        self.history = _lazy_zeros((n, history_dim))
         # error-feedback residuals (core/compress.py); width 0 when the
         # cohort engine runs uncompressed
-        self.residual = np.zeros((n, residual_dim), np.float32)
+        self.residual = _lazy_zeros((n, residual_dim))
         self.last_selected = np.full(n, -1, np.int32)
         # store-resident buffered-async slots (width 0 unless the cohort
         # engine runs aggregation="async"): the resident engine's
         # EngineState.pending_* leaves, host-side
-        self.pending_delta = np.zeros((n, pending_dim), np.float32)
+        self.pending_delta = _lazy_zeros((n, pending_dim))
         self.pending_weight = np.zeros(n, np.float32)
         self.pending_issued = np.zeros(n, np.int32)
         self.pending_arrival = np.zeros(n, np.int32)

@@ -13,7 +13,8 @@ Algorithm 1's trust updates stay bit-identical to the single-device engine.
 Exports:
 
   ``client_mesh``   -- build the 1-D ``clients`` mesh from ``FedConfig``
-                       (``None`` -> single-device fallback).
+                       (``None`` on the single-device path; too few
+                       devices is an error).
   ``ClientComms``   -- identity collectives: the single-device engine and
                        the comms-parameterized math in ``core/aggregation``
                        / ``core/foolsgold`` reduce to the seed numerics.
@@ -39,7 +40,6 @@ LM pre-training lives in ``launch/train.py``.)
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional
 
 import jax
@@ -165,28 +165,25 @@ class MeshComms(ClientComms):
 
 def client_mesh(fed: FedConfig) -> Optional[Mesh]:
     """The 1-D ``clients`` mesh ``FedConfig.mesh_shape`` asks for, or
-    ``None`` for the single-device path (``mesh_shape`` unset / 1, or the
-    host exposes a single device).  A host with fewer (but >1) devices than
-    requested gets a narrower mesh with a warning, so scaling numbers are
-    never silently attributed to shards that don't exist.  ``num_clients``
-    must divide evenly into the shards so every block is rectangular."""
-    want = fed.mesh_shape or 1
-    shards = min(want, len(jax.devices()))
+    ``None`` for the single-device path (``mesh_shape`` unset / 1).  A host
+    with fewer devices than requested is an error: a narrower mesh would
+    attribute results to shards that do not exist.  ``num_clients`` must
+    divide evenly into the shards so every block is rectangular."""
+    shards = fed.mesh_shape or 1
     if shards <= 1:
         return None
-    if shards < want:
-        warnings.warn(
-            f"mesh_shape={want} requested but only {shards} devices "
-            f"available; sharding {shards}-way",
-            stacklevel=2,
+    devices = jax.devices()
+    if len(devices) < shards:
+        raise ValueError(
+            f"mesh_shape={shards} requested but only {len(devices)} "
+            f"device(s) are available"
         )
     if fed.num_clients % shards:
         raise ValueError(
             f"num_clients={fed.num_clients} not divisible by {shards} "
-            f"client shards (mesh_shape={want}, "
-            f"{len(jax.devices())} devices available)"
+            f"client shards (mesh_shape={shards})"
         )
-    return Mesh(np.array(jax.devices()[:shards]), (fed.client_axis,))
+    return Mesh(np.array(devices[:shards]), (fed.client_axis,))
 
 
 def client_spec(fed: FedConfig) -> P:

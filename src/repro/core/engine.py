@@ -73,7 +73,8 @@ from typing import NamedTuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.common.config import FedConfig
 from repro.configs.fedar_mnist import MnistConfig
@@ -188,9 +189,11 @@ class FedAREngine:
         self.model = model
         self.cfg = getattr(model, "cfg", None)
         self.fed, self.req, self.lr = fed, req, lr
-        # resolve the local-SGD backend once: the fused Pallas kernel only
+        # the local-SGD backend request: the fused Pallas kernel only
         # applies to families that ship one — an explicit ``"kernel"``
-        # request on any other family falls back to the vmapped XLA path
+        # request on any other family runs the vmapped XLA path, loudly.
+        # The route itself (``sgd_route``) is decided host-side from the
+        # data's shapes on every ``step`` / ``run`` call.
         self._sgd_kernel = (
             resolve_impl(fed.sgd_impl, "sgd") == "kernel"
             and model.supports_fused
@@ -202,6 +205,7 @@ class FedAREngine:
                 f"one; falling back to the vmapped XLA path",
                 stacklevel=2,
             )
+        self.sgd_route = None if self._sgd_kernel else "xla"
         key = jax.random.PRNGKey(fed.seed)
         self.template = model.init(key)
         self.dim = flatten(self.template).shape[0]
@@ -331,25 +335,43 @@ class FedAREngine:
             None if force_straggler is None else Pr,
         )
 
+    def _placed(self, state, data, eval_set, force_straggler):
+        """The round inputs laid out on the mesh as the sharded program
+        takes them (as they are, off the mesh).  A jit compiles once per
+        input layout: an unplaced first state and the sharded state the
+        round returns would compile the round twice, and unplaced data
+        would be scattered from one device every round.  Inputs already in
+        place are not copied."""
+        args = (state, data, eval_set, force_straggler)
+        if self.mesh is None:
+            return args
+        return self._on_mesh(
+            args, self._in_specs(data, eval_set, force_straggler)
+        )
+
+    def _on_mesh(self, tree, specs):
+        """``tree`` laid out on the mesh by its PartitionSpecs."""
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(self.mesh, spec), specs,
+            is_leaf=lambda x: isinstance(x, P),
+        )
+        return jax.device_put(tree, shardings)
+
     # ---------------------------------------------------- ClientUpdate
     def _block_sgd(self, g_flat, fields, m):
         """Local SGD over one block of clients -> stacked flat local params
         (rows, D).  ``fields`` is the dict of stacked per-client sample
         arrays keyed by ``self.model.data_keys`` (client axis leading).
-        Routes ``FedConfig.sgd_impl``: when resolved to ``"kernel"`` on a
-        family that ships a fused Pallas kernel, the model's
+        On the ``"fused"`` route (``sgd_route``) the model's
         ``fused_block_update`` runs the whole masked epochs x batches loop
-        per client inside one ``pallas_call`` (it returns ``None`` when the
-        block does not fit, e.g. VMEM); otherwise the XLA path vmaps the
-        model's ``client_update`` (the seed-exact reference)."""
+        per client inside one ``pallas_call``; otherwise the XLA path vmaps
+        the model's ``client_update`` (the seed-exact reference)."""
         fed = self.fed
-        if self._sgd_kernel:
-            fused = self.model.fused_block_update(
+        if self.sgd_route == "fused":
+            return self.model.fused_block_update(
                 g_flat, fields, m, lr=self.lr,
                 batch_size=fed.local_batch_size, epochs=fed.local_epochs,
             )
-            if fused is not None:
-                return fused
 
         def client_update(p_flat, f, m=None):
             p = unflatten(p_flat, self.template)
@@ -363,6 +385,15 @@ class FedAREngine:
             )
             return flatten(new)
 
+        if jax.tree.leaves(fields)[0].shape[0] == 1:
+            # XLA lowers a size-1 vmap without its batch axis, which
+            # reorders the per-batch gradient sums by an ulp; run a lone
+            # row as a batch of two so it matches the same client inside
+            # any wider block bit for bit (packed == dense)
+            fields, m = jax.tree.map(
+                lambda v: jnp.concatenate([v, v]), (fields, m)
+            )
+            return self._block_sgd(g_flat, fields, m)[:1]
         if m is None:
             return jax.vmap(client_update, in_axes=(None, 0))(g_flat, fields)
         return jax.vmap(client_update, in_axes=(None, 0, 0))(
@@ -406,21 +437,17 @@ class FedAREngine:
     def _ragged_block_sgd(self, g_flat, blocks):
         """Local SGD over a list of rectangular client blocks of differing
         widths -> concatenated (sum rows, D) flat local params, in block
-        order.  With the kernel route resolved, the model's
+        order.  On the ``"fused_ragged"`` route the model's
         ``fused_ragged_update`` runs ALL blocks inside ONE ragged-grid
         ``pallas_call`` (a single launch for the whole bucketed layout —
         no per-bucket dispatch); the XLA route keeps one vmap per block
         (XLA cannot fuse across the differing widths)."""
-        if self._sgd_kernel:
-            fn = getattr(self.model, "fused_ragged_update", None)
-            if fn is not None:
-                fused = fn(
-                    g_flat, blocks, lr=self.lr,
-                    batch_size=self.fed.local_batch_size,
-                    epochs=self.fed.local_epochs,
-                )
-                if fused is not None:
-                    return fused
+        if self.sgd_route == "fused_ragged":
+            return self.model.fused_ragged_update(
+                g_flat, blocks, lr=self.lr,
+                batch_size=self.fed.local_batch_size,
+                epochs=self.fed.local_epochs,
+            )
         return jnp.concatenate(
             [self._block_sgd(g_flat, f, m) for f, m in blocks]
         )
@@ -990,12 +1017,12 @@ class FedAREngine:
         spec plumbing cannot diverge between ``step`` and ``run``."""
         if self.mesh is None:
             return fn(state, data, eval_set, force_straggler)
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=self._in_specs(data, eval_set, force_straggler),
             out_specs=(self.state_specs(), self._round_out_specs()),
-            check_rep=False,
+            check_vma=False,
         )(state, data, eval_set, force_straggler)
 
     def _step_fn(self, state, data, eval_set, force_straggler, *,
@@ -1036,6 +1063,39 @@ class FedAREngine:
         return float(
             self.model.train_flops(shape, epochs=self.fed.local_epochs)
         )
+
+    def _decide_sgd_route(self, data) -> str:
+        """The local-SGD route for this data, decided host-side from its
+        shapes before tracing: ``"fused_ragged"`` (one ragged-grid kernel
+        launch over the packed buckets), ``"fused"`` (the rectangular
+        kernel over the dense sample block) or ``"xla"`` (vmapped
+        ``client_update``).  A block the kernel cannot take goes to XLA
+        under ``sgd_impl="auto"`` and raises under an explicit
+        ``"kernel"``.  The route is a function of shapes and config alone,
+        so the jit caches (keyed on shapes) never mix routes."""
+        if not self._sgd_kernel:
+            return "xla"
+        bs = self.fed.local_batch_size
+        if "packed" in data:
+            # the ragged grid streams one batch tile per step
+            width, route = bs, "fused_ragged"
+        else:
+            width = data[self.model.data_keys[0]].shape[1]
+            route = "fused"
+        if self.model.fused_fits(width, bs):
+            return route
+        if self.fed.sgd_impl == "kernel":
+            raise ValueError(
+                f'sgd_impl="kernel": a {width}-sample client block does '
+                f"not fit the fused local-SGD kernel's compiled VMEM limit; "
+                f'use sgd_impl="auto" or "einsum" for this fleet'
+            )
+        return "xla"
+
+    def _prepare_call(self, data) -> None:
+        """Host-side checks and decisions before a jitted entry point."""
+        self._check_packed(data)
+        self.sgd_route = self._decide_sgd_route(data)
 
     def _check_packed(self, data) -> None:
         """Host-side layout check: a packed dict built for k shards only
@@ -1080,27 +1140,57 @@ class FedAREngine:
             quantum=self.fed.local_batch_size,
             layout=layout,
         )
-        return jax.tree.map(jnp.asarray, raw)
+        if self.mesh is None:
+            return jax.tree.map(jnp.asarray, raw)
+        # host -> each shard's device directly, never whole on one device
+        return self._on_mesh(raw, self.data_specs(raw))
+
+    def kernel_routes(self) -> dict:
+        """The backend each hot op takes: ``sgd`` is the decided
+        ``sgd_route`` (None until ``step``/``run`` has seen data); ``agg``,
+        ``defense`` and ``compress`` are ``"kernel"`` (Pallas) or
+        ``"einsum"`` (XLA), or ``"none"`` where the subsystem is off."""
+        fed = self.fed
+        return {
+            "sgd": self.sgd_route,
+            "agg": ("scan" if fed.aggregation == "async_seq"
+                    else resolve_impl(fed.agg_impl, "agg")),
+            "defense": ("none" if self.defense.name == "none"
+                        else resolve_impl(fed.defense_impl, "defense")),
+            "compress": (resolve_impl(fed.compress_impl, "compress")
+                         if self.compression.active else "none"),
+        }
+
+    def lower_step(self, state, data, *, eval_set=None):
+        """The lowered one-round program ``step`` would run on these
+        inputs (``.as_text()`` shows which kernels it calls)."""
+        self._prepare_call(data)
+        return self._step.lower(*self._placed(state, data, eval_set, None),
+                                train_flops=self._train_flops(data))
 
     def step(self, state, data, *, eval_set=None, force_straggler=None):
         """One jitted communication round -> (state, RoundOutputs)."""
-        self._check_packed(data)
-        return self._step(state, data, eval_set, force_straggler,
-                          train_flops=self._train_flops(data))
+        self._prepare_call(data)
+        return self._step(
+            *self._placed(state, data, eval_set, force_straggler),
+            train_flops=self._train_flops(data),
+        )
 
     def run(self, state, data, *, rounds: int, eval_set=None,
             force_straggler=None):
         """R rounds in a single ``lax.scan`` -> (state, stacked outputs)."""
-        self._check_packed(data)
-        return self._run(state, data, eval_set, force_straggler,
-                         rounds=rounds, train_flops=self._train_flops(data))
+        self._prepare_call(data)
+        return self._run(
+            *self._placed(state, data, eval_set, force_straggler),
+            rounds=rounds, train_flops=self._train_flops(data),
+        )
 
     def run_python_loop(self, state, data, *, rounds: int, eval_set=None,
                         force_straggler=None):
         """Seed-style reference driver: one EAGER (un-jitted) dispatch per
         round with a device->host sync of every history row.  Kept as the
         benchmark baseline the scan engine is measured against."""
-        self._check_packed(data)
+        self._prepare_call(data)
         outs = []
         for _ in range(rounds):
             state, out = self._step_fn(
@@ -1269,6 +1359,15 @@ class CohortEngine:
                 pending_valid=jnp.asarray(rows["pending_valid"]),
             )
         return state, data, idx, valid, elig
+
+    def kernel_routes(self) -> dict:
+        return self.engine.kernel_routes()
+
+    def lower_round(self, fleet, *, eval_set=None):
+        """The lowered program of the next round (its cohort sampled, the
+        store untouched)."""
+        state, data, *_ = self._build_round_inputs(fleet)
+        return self.engine.lower_step(state, data, eval_set=eval_set)
 
     def run_round(self, fleet, *, eval_set=None):
         """One store-sampled round -> (idx, valid, RoundOutputs).

@@ -11,8 +11,8 @@ every round inside one ``lax.scan`` by default (``driver="scan"``);
 
 Multi-device: pass ``FedConfig(mesh_shape=k)`` to run the engine's rounds
 sharded over a ``clients`` mesh axis (``core/distributed.py``) — the server
-API and history layout are unchanged; with one device the config falls back
-to the single-device path.  ``FedARServer.mesh`` exposes the active mesh
+API and history layout are unchanged; a host with fewer devices than
+``mesh_shape`` is an error.  ``FedARServer.mesh`` exposes the active mesh
 (``None`` when unsharded).
 """
 from __future__ import annotations

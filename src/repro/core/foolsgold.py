@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from repro.core.distributed import ClientComms
 from repro.kernels.defense_sim import sketch_similarity
-from repro.kernels.ops import resolve_impl
+from repro.kernels.ops import interpret_mode, resolve_impl
 
 _IDENTITY = ClientComms()
 
@@ -63,7 +63,7 @@ def _similarity_block(history, active, *, comms: ClientComms, impl: str):
     unit_full = comms.gather_defense(unit)  # (N, d) — the one all-to-all
     if resolve_impl(impl, "defense") == "kernel":
         cs = sketch_similarity(
-            unit, unit_full, interpret=jax.default_backend() != "tpu"
+            unit, unit_full, interpret=interpret_mode()
         )
     else:
         cs = unit @ unit_full.T  # (N_loc, N) local similarity block

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK_D = 1024  # lane-aligned (1024 = 8 * 128)
+LANES = 128
 VMEM_BUDGET_BYTES = 4 * 1024 * 1024
 
 
@@ -116,23 +117,40 @@ def unpack_codes(packed, *, bits: int, dim: int, interpret: bool = False,
 
 
 def _topk_kernel(v_ref, i_ref, o_ref, *, block_d: int):
-    # v/i: (N, k); o: (N, BLOCK) — column window [j*BLOCK, (j+1)*BLOCK)
-    j = pl.program_id(0)
-    vals = v_ref[...].astype(jnp.float32)
-    idx = i_ref[...]
-    n, k = vals.shape
+    # v/i: (bn, kp) value/index rows; o: (bn, block_d) — column window
+    # [j*block_d, (j+1)*block_d).  k is walked in lane-aligned 128-wide
+    # chunks loaded straight from the refs; each chunk's pairs fold in
+    # order through static lane slices, so no dynamic value slicing is
+    # needed and the accumulation order is exactly t = 0, 1, ..., k-1.
+    j = pl.program_id(1)
+    bn, kp = v_ref.shape
     cols = j * block_d + jax.lax.broadcasted_iota(
-        jnp.int32, (n, block_d), 1
+        jnp.int32, (bn, block_d), 1
     )
 
-    def body(t, acc):
-        vt = jax.lax.dynamic_slice(vals, (0, t), (n, 1))
-        it = jax.lax.dynamic_slice(idx, (0, t), (n, 1))
-        return acc + vt * (it == cols).astype(jnp.float32)
+    def chunk(c, acc):
+        start = pl.multiple_of(c * LANES, LANES)
+        vc = v_ref[:, pl.ds(start, LANES)]
+        ic = i_ref[:, pl.ds(start, LANES)]
+        for lane in range(LANES):
+            vt = vc[:, lane:lane + 1]
+            it = ic[:, lane:lane + 1]
+            acc = acc + vt * (it == cols).astype(jnp.float32)
+        return acc
 
     o_ref[...] = jax.lax.fori_loop(
-        0, k, body, jnp.zeros((n, block_d), jnp.float32)
+        0, kp // LANES, chunk, jnp.zeros((bn, block_d), jnp.float32)
     )
+
+
+def _topk_blocks(n: int, kp: int, block_d: int) -> tuple[int, int]:
+    """Row block (multiple of 8, or all of n when n < 8) and column window
+    of ``topk_decode``: the double-buffered (bn, kp) value and index tiles
+    plus the (bn, block_d) output tile stay within the VMEM budget."""
+    bd = max(LANES, block_d // LANES * LANES)
+    per_row = 4 * 2 * (2 * kp + bd)
+    bn = max(8, VMEM_BUDGET_BYTES // per_row // 8 * 8)
+    return (n if n <= bn else bn), bd
 
 
 @functools.partial(jax.jit, static_argnames=("dim", "interpret", "block_d"))
@@ -140,22 +158,30 @@ def topk_decode(vals, idx, dim: int, *, interpret: bool = False,
                 block_d: int = BLOCK_D):
     """vals, idx: (N, k) -> dense (N, dim) float32; duplicate indices
     accumulate (scatter-add), matching ``ref.topk_decode_ref``.  k == 0
-    (nothing kept / all rows masked upstream) short-circuits to zeros."""
+    (nothing kept / all rows masked upstream) short-circuits to zeros.
+
+    k is padded to a lane multiple with (value 0, index -1) pairs, which
+    match no column; N is tiled in row blocks so the value/index tiles
+    fit VMEM at fleet-scale N."""
     N, k = vals.shape
     if k == 0:
         return jnp.zeros((N, dim), jnp.float32)
-    block_d = _fit_block(N, block_d)
-    pad = (-dim) % block_d
-    Dp = dim + pad
+    kp = -(-k // LANES) * LANES
+    bn, block_d = _topk_blocks(N, kp, block_d)
+    pad_n, pad_d = (-N) % bn, (-dim) % block_d
+    vals = jnp.pad(vals.astype(jnp.float32), ((0, pad_n), (0, kp - k)))
+    idx = jnp.pad(idx.astype(jnp.int32), ((0, pad_n), (0, kp - k)),
+                  constant_values=-1)
+    Np, Dp = N + pad_n, dim + pad_d
     out = pl.pallas_call(
         functools.partial(_topk_kernel, block_d=block_d),
-        grid=(Dp // block_d,),
+        grid=(Np // bn, Dp // block_d),
         in_specs=[
-            pl.BlockSpec((N, k), lambda i: (0, 0)),
-            pl.BlockSpec((N, k), lambda i: (0, 0)),
+            pl.BlockSpec((bn, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((bn, kp), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((N, block_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((N, Dp), jnp.float32),
+        out_specs=pl.BlockSpec((bn, block_d), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Np, Dp), jnp.float32),
         interpret=interpret,
-    )(vals.astype(jnp.float32), idx.astype(jnp.int32))
-    return out[:, :dim]
+    )(vals, idx)
+    return out[:N, :dim]

@@ -20,9 +20,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_D = 2048  # lane-aligned (2048 = 16 * 128)
 VMEM_BUDGET_BYTES = 4 * 1024 * 1024  # cap on the fp32 (N, block) slab
+# scoped VMEM the compiler may use: the double-buffered slab plus the
+# (N, 1) weight / staleness columns, which pad to (N, 128) tiles
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def _fit_block(n: int, block_d: int) -> int:
@@ -33,12 +37,12 @@ def _fit_block(n: int, block_d: int) -> int:
 
 
 def _agg_kernel(w_ref, s_ref, d_ref, o_ref):
-    # w_ref, s_ref: (N, 1) f32; d_ref: (N, BLOCK_D); o_ref: (BLOCK_D,)
+    # w_ref, s_ref: (N, 1) f32; d_ref: (N, BLOCK_D); o_ref: (1, BLOCK_D)
     w = w_ref[...]  # (N, 1) trust/size weights
     s = s_ref[...]  # (N, 1) staleness in rounds (0 = fresh)
     d = d_ref[...].astype(jnp.float32)  # (N, BLOCK_D)
     wd = w * jax.lax.rsqrt(1.0 + s)  # poly staleness decay, fused in-pass
-    o_ref[...] = jnp.sum(wd * d, axis=0)
+    o_ref[...] = jnp.sum(wd * d, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_d"))
@@ -75,12 +79,17 @@ def fedavg_agg(
             pl.BlockSpec((N, 1), lambda i: (0, 0)),
             pl.BlockSpec((N, block_d), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Dp,), jnp.float32),
+        # a (1, Dp) row, not a (Dp,) vector: a 1-D block must match XLA's
+        # 1024-element tiling, which the narrower blocks of big fleets miss
+        out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
         interpret=interpret,
     )(
         weights.astype(jnp.float32)[:, None],
         staleness.astype(jnp.float32)[:, None],
         deltas,
     )
-    return out[:D]
+    return out[0, :D]

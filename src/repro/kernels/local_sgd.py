@@ -51,38 +51,65 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# Scoped-VMEM limit handed to the TPU compiler for the fused kernels, and the
+# budget ``fused_fits_vmem`` checks a block against.  v5e has 128 MiB of
+# VMEM per core; 32 MiB admits the paper's 784-128-10 MLP up to 2,760 samples
+# per client at B=20.  ``tests/test_tpu_compile.py`` compiles the widest width this
+# admits for a described v5e, so the estimate below is held to what the
+# compiler accepts.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def _tile_bytes(rows: int, cols: int) -> int:
+    """fp32/int32 bytes of a (rows, cols) VMEM tile after (8, 128) padding."""
+    return 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
+
+
+def fused_vmem_bytes(n: int, batch: int, input_dim: int, hidden: int,
+                     classes: int) -> int:
+    """Padded VMEM footprint of one grid step of ``local_sgd_fused``: the
+    double-buffered (nb, B, I) sample slab and its (nb, B, 1) label / mask
+    columns, the double-buffered in/out parameter tiles, and the batch
+    body's parameter-sized temporaries (loaded weights and gradients)."""
+    nb = -(-n // batch)
+    slab = nb * (_tile_bytes(batch, input_dim) + 2 * _tile_bytes(batch, 1))
+    params = (
+        _tile_bytes(input_dim, hidden) + _tile_bytes(1, hidden)
+        + _tile_bytes(hidden, classes) + _tile_bytes(1, classes)
+    )
+    temps = 2 * (_tile_bytes(input_dim, hidden) + _tile_bytes(hidden, classes))
+    return 2 * slab + 4 * params + temps
 
 
 def fused_fits_vmem(n: int, input_dim: int, hidden: int, classes: int,
-                    budget: int = VMEM_BUDGET_BYTES) -> bool:
-    """Whether one client's working set — the (n, input_dim) sample slab,
-    the in/out parameter tiles and the per-batch temporaries — fits the
-    per-grid-step VMEM budget.  The engine falls back to the XLA vmap path
-    when a (very wide) bucket would not fit."""
-    slab = n * input_dim + 2 * n
-    params = 2 * (input_dim * hidden + hidden + hidden * classes + classes)
-    grads = input_dim * hidden + hidden * classes
-    return 4 * (slab + params + grads) <= budget
+                    batch: int = 20, limit: int = VMEM_LIMIT_BYTES) -> bool:
+    """Whether one client's ``local_sgd_fused`` grid step — n samples in
+    batches of ``batch`` — fits the scoped VMEM limit the kernel compiles
+    under.  The engine decides its local-SGD route from this, host-side,
+    before tracing (``FedAREngine.sgd_route``)."""
+    return fused_vmem_bytes(n, batch, input_dim, hidden, classes) <= limit
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _batch_body(xb, yb, mb, is_soft, w1o, b1o, w2o, b2o, *, lr):
     """One masked SGD step against the params resident in the output VMEM
-    tiles (shared by the rectangular and the ragged-grid kernels).  An
-    all-padding batch is an exact no-op (the masked loss renormalizes to
+    tiles (shared by the rectangular and the ragged-grid kernels).
+    ``xb`` (B, I), ``yb`` (B, 1) int labels, ``mb`` (B, 1) float validity.
+    An all-padding batch is an exact no-op (the masked loss renormalizes to
     zero gradient), so ``pl.when`` skips it entirely."""
     cnt = jnp.sum(mb)
 
-    # masked tile skip: an all-padding batch is an exact no-op (the
-    # masked loss renormalizes to zero gradient), so don't compute it
     @pl.when(cnt > 0.0)
     def _():
-        w1, b1 = w1o[0], b1o[0]
-        w2, b2 = w2o[0], b2o[0]
+        w1, b1 = w1o[...], b1o[...]
+        w2, b2 = w2o[...], b2o[...]
         hpre = jax.lax.dot_general(
             xb, w1, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) + b1[None, :]
+        ) + b1
         h = jnp.where(
             is_soft, jax.nn.softmax(hpre, axis=-1),
             jnp.maximum(hpre, 0.0),
@@ -90,17 +117,17 @@ def _batch_body(xb, yb, mb, is_soft, w1o, b1o, w2o, b2o, *, lr):
         logits = jax.lax.dot_general(
             h, w2, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) + b2[None, :]
+        ) + b2
         # d(masked CE)/d(logits) = (softmax - onehot) * m / sum(m)
         p = jax.nn.softmax(logits, axis=-1)
         col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        onehot = (col == yb[:, None]).astype(jnp.float32)
-        gl = (p - onehot) * (mb / jnp.maximum(cnt, 1.0))[:, None]
+        onehot = (col == yb).astype(jnp.float32)
+        gl = (p - onehot) * (mb / jnp.maximum(cnt, 1.0))
         dw2 = jax.lax.dot_general(
             h, gl, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        db2 = jnp.sum(gl, axis=0)
+        db2 = jnp.sum(gl, axis=0, keepdims=True)
         dh = jax.lax.dot_general(
             gl, w2, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -113,33 +140,88 @@ def _batch_body(xb, yb, mb, is_soft, w1o, b1o, w2o, b2o, *, lr):
             xb, dhp, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        db1 = jnp.sum(dhp, axis=0)
-        w1o[0] = w1 - lr * dw1
-        b1o[0] = b1 - lr * db1
-        w2o[0] = w2 - lr * dw2
-        b2o[0] = b2 - lr * db2
+        db1 = jnp.sum(dhp, axis=0, keepdims=True)
+        w1o[...] = w1 - lr * dw1
+        b1o[...] = b1 - lr * db1
+        w2o[...] = w2 - lr * dw2
+        b2o[...] = b2 - lr * db2
+
+
+def _init_params(w1_ref, b1_ref, w2_ref, b2_ref, w1o, b1o, w2o, b2o):
+    w1o[...] = w1_ref[...]
+    b1o[...] = b1_ref[...]
+    w2o[...] = w2_ref[...]
+    b2o[...] = b2_ref[...]
 
 
 def _sgd_kernel(act_ref, x_ref, y_ref, m_ref, w1_ref, b1_ref, w2_ref, b2_ref,
-                w1o, b1o, w2o, b2o, *, lr, nb, epochs, batch):
+                w1o, b1o, w2o, b2o, *, lr, nb, epochs):
     # one grid step == one client: params live in the output VMEM tiles and
-    # are updated in place across every batch of every epoch
-    w1o[0] = w1_ref[...]
-    b1o[...] = b1_ref[...]
-    w2o[0] = w2_ref[...]
-    b2o[...] = b2_ref[...]
-    is_soft = act_ref[0, 0] == 1
+    # are updated in place across every batch of every epoch.  Batches sit
+    # on the slab's leading (untiled) axis, so each step's batch is an
+    # aligned whole-tile read.
+    _init_params(w1_ref, b1_ref, w2_ref, b2_ref, w1o, b1o, w2o, b2o)
+    is_soft = act_ref[pl.program_id(0)] == 1
 
     def step(t, carry):
         b = jax.lax.rem(t, nb)
-        start = b * batch
-        xb = x_ref[0, pl.ds(start, batch), :]  # (B, I)
-        yb = y_ref[0, pl.ds(start, batch)]  # (B,)
-        mb = m_ref[0, pl.ds(start, batch)]  # (B,) float validity
-        _batch_body(xb, yb, mb, is_soft, w1o, b1o, w2o, b2o, lr=lr)
+        _batch_body(x_ref[b], y_ref[b], m_ref[b], is_soft,
+                    w1o, b1o, w2o, b2o, lr=lr)
         return carry
 
     jax.lax.fori_loop(0, epochs * nb, step, 0)
+
+
+def _param_in_specs(inp, hid, classes):
+    """Full-array blocks of the shared global params (same tile for every
+    grid step, whatever the grid's index-map arity)."""
+    def zero(*_):
+        return (0, 0)
+
+    return [
+        pl.BlockSpec((inp, hid), zero),
+        pl.BlockSpec((1, hid), zero),
+        pl.BlockSpec((hid, classes), zero),
+        pl.BlockSpec((1, classes), zero),
+    ]
+
+
+def _param_out(R, inp, hid, classes):
+    """Per-client post-SGD param outputs, indexed by the grid's leading
+    (client) axis: the client axis is squeezed (``None``) so every block's
+    last two dims are the full array dims; the biases carry a unit middle
+    axis for the same reason."""
+    def client(i, *_):
+        return (i, 0, 0)
+
+    specs = [
+        pl.BlockSpec((None, inp, hid), client),
+        pl.BlockSpec((None, 1, hid), client),
+        pl.BlockSpec((None, hid, classes), client),
+        pl.BlockSpec((None, 1, classes), client),
+    ]
+    shapes = [
+        jax.ShapeDtypeStruct((R, inp, hid), jnp.float32),
+        jax.ShapeDtypeStruct((R, 1, hid), jnp.float32),
+        jax.ShapeDtypeStruct((R, hid, classes), jnp.float32),
+        jax.ShapeDtypeStruct((R, 1, classes), jnp.float32),
+    ]
+    return specs, shapes
+
+
+def _params_args(w1, b1, w2, b2):
+    hid, classes = w1.shape[1], w2.shape[1]
+    return (
+        w1.astype(jnp.float32),
+        b1.astype(jnp.float32).reshape(1, hid),
+        w2.astype(jnp.float32),
+        b2.astype(jnp.float32).reshape(1, classes),
+    )
+
+
+def _unpack_outs(outs, R):
+    return {"w1": outs[0], "b1": outs[1].reshape(R, -1), "w2": outs[2],
+            "b2": outs[3].reshape(R, -1)}
 
 
 @functools.partial(
@@ -168,48 +250,34 @@ def local_sgd_fused(w1, b1, w2, b2, x, y, act, mask, *, lr: float,
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         y = jnp.pad(y, ((0, 0), (0, pad)))
         mask = jnp.pad(mask, ((0, 0), (0, pad)))
-    npad = nb * batch_size
-    kernel = functools.partial(
-        _sgd_kernel, lr=lr, nb=nb, epochs=epochs, batch=batch_size
+    kernel = functools.partial(_sgd_kernel, lr=lr, nb=nb, epochs=epochs)
+    out_specs, out_shape = _param_out(R, inp, hid, classes)
+    slab = lambda i, *_: (i, 0, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(R,),
+        in_specs=[
+            pl.BlockSpec((None, nb, batch_size, inp), slab),
+            pl.BlockSpec((None, nb, batch_size, 1), slab),
+            pl.BlockSpec((None, nb, batch_size, 1), slab),
+            *_param_in_specs(inp, hid, classes),
+        ],
+        out_specs=out_specs,
     )
     outs = pl.pallas_call(
         kernel,
-        grid=(R,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, npad, inp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, npad), lambda i: (i, 0)),
-            pl.BlockSpec((1, npad), lambda i: (i, 0)),
-            pl.BlockSpec((inp, hid), lambda i: (0, 0)),
-            pl.BlockSpec((1, hid), lambda i: (0, 0)),
-            pl.BlockSpec((hid, classes), lambda i: (0, 0)),
-            pl.BlockSpec((1, classes), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, inp, hid), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, hid), lambda i: (i, 0)),
-            pl.BlockSpec((1, hid, classes), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, classes), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, inp, hid), jnp.float32),
-            jax.ShapeDtypeStruct((R, hid), jnp.float32),
-            jax.ShapeDtypeStruct((R, hid, classes), jnp.float32),
-            jax.ShapeDtypeStruct((R, classes), jnp.float32),
-        ],
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(
-        act.astype(jnp.int32).reshape(R, 1),
-        x.astype(jnp.float32),
-        y.astype(jnp.int32),
-        mask,
-        w1.astype(jnp.float32),
-        b1.astype(jnp.float32).reshape(1, hid),
-        w2.astype(jnp.float32),
-        b2.astype(jnp.float32).reshape(1, classes),
+        act.astype(jnp.int32),
+        x.astype(jnp.float32).reshape(R, nb, batch_size, inp),
+        y.astype(jnp.int32).reshape(R, nb, batch_size, 1),
+        mask.reshape(R, nb, batch_size, 1),
+        *_params_args(w1, b1, w2, b2),
     )
-    return {"w1": outs[0], "b1": outs[1], "w2": outs[2], "b2": outs[3]}
+    return _unpack_outs(outs, R)
 
 
 def _ragged_kernel(act_ref, nb_ref, off_ref, x_ref, y_ref, m_ref,
@@ -222,17 +290,14 @@ def _ragged_kernel(act_ref, nb_ref, off_ref, x_ref, y_ref, m_ref,
 
     @pl.when((e == 0) & (b == 0))
     def _():
-        w1o[0] = w1_ref[...]
-        b1o[...] = b1_ref[...]
-        w2o[0] = w2_ref[...]
-        b2o[...] = b2_ref[...]
+        _init_params(w1_ref, b1_ref, w2_ref, b2_ref, w1o, b1o, w2o, b2o)
 
     # ragged skip: grid batch steps past this client's own batch count are
     # no-ops (the index map clamps their tile fetch to a valid slot)
     @pl.when(b < nb_ref[i])
     def _():
         _batch_body(
-            x_ref[0], y_ref[0], m_ref[0], act_ref[i] == 1,
+            x_ref[...], y_ref[...], m_ref[...], act_ref[i] == 1,
             w1o, b1o, w2o, b2o, lr=lr,
         )
 
@@ -263,64 +328,39 @@ def local_sgd_fused_ragged(w1, b1, w2, b2, xt, yt, mt, act, nb, off, *,
     "b2": (R, C)}`` — bit-identical to running ``local_sgd_fused`` per
     bucket."""
     R = act.shape[0]
-    batch, inp = xt.shape[1], xt.shape[2]
+    T, batch, inp = xt.shape
     hid = w1.shape[1]
     classes = w2.shape[1]
     kernel = functools.partial(_ragged_kernel, lr=lr)
+
+    def tile(i, e, b, act, nb, off):
+        return (off[i] + jnp.minimum(b, nb[i] - 1), 0, 0)
+
+    out_specs, out_shape = _param_out(R, inp, hid, classes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(R, epochs, nb_max),
         in_specs=[
-            pl.BlockSpec(
-                (1, batch, inp),
-                lambda i, e, b, act, nb, off: (
-                    off[i] + jnp.minimum(b, nb[i] - 1), 0, 0
-                ),
-            ),
-            pl.BlockSpec(
-                (1, batch),
-                lambda i, e, b, act, nb, off: (
-                    off[i] + jnp.minimum(b, nb[i] - 1), 0
-                ),
-            ),
-            pl.BlockSpec(
-                (1, batch),
-                lambda i, e, b, act, nb, off: (
-                    off[i] + jnp.minimum(b, nb[i] - 1), 0
-                ),
-            ),
-            pl.BlockSpec((inp, hid), lambda i, e, b, *_: (0, 0)),
-            pl.BlockSpec((1, hid), lambda i, e, b, *_: (0, 0)),
-            pl.BlockSpec((hid, classes), lambda i, e, b, *_: (0, 0)),
-            pl.BlockSpec((1, classes), lambda i, e, b, *_: (0, 0)),
+            pl.BlockSpec((None, batch, inp), tile),
+            pl.BlockSpec((None, batch, 1), tile),
+            pl.BlockSpec((None, batch, 1), tile),
+            *_param_in_specs(inp, hid, classes),
         ],
-        out_specs=[
-            pl.BlockSpec((1, inp, hid), lambda i, e, b, *_: (i, 0, 0)),
-            pl.BlockSpec((1, hid), lambda i, e, b, *_: (i, 0)),
-            pl.BlockSpec((1, hid, classes), lambda i, e, b, *_: (i, 0, 0)),
-            pl.BlockSpec((1, classes), lambda i, e, b, *_: (i, 0)),
-        ],
+        out_specs=out_specs,
     )
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((R, inp, hid), jnp.float32),
-            jax.ShapeDtypeStruct((R, hid), jnp.float32),
-            jax.ShapeDtypeStruct((R, hid, classes), jnp.float32),
-            jax.ShapeDtypeStruct((R, classes), jnp.float32),
-        ],
+        out_shape=out_shape,
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(
         act.astype(jnp.int32),
         nb.astype(jnp.int32),
         off.astype(jnp.int32),
         xt.astype(jnp.float32),
-        yt.astype(jnp.int32),
-        mt.astype(jnp.float32),
-        w1.astype(jnp.float32),
-        b1.astype(jnp.float32).reshape(1, hid),
-        w2.astype(jnp.float32),
-        b2.astype(jnp.float32).reshape(1, classes),
+        yt.astype(jnp.int32).reshape(T, batch, 1),
+        mt.astype(jnp.float32).reshape(T, batch, 1),
+        *_params_args(w1, b1, w2, b2),
     )
-    return {"w1": outs[0], "b1": outs[1], "w2": outs[2], "b2": outs[3]}
+    return _unpack_outs(outs, R)

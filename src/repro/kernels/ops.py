@@ -1,9 +1,11 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public jit'd wrappers for the Pallas kernels, and the one place that
+decides how a kernel runs.
 
-Each op dispatches between the Pallas kernel (TPU target; ``interpret=True``
-emulation on CPU) and the pure-XLA reference path.  The model code calls
-these through ``use_pallas`` config so CPU dry-runs lower the XLA path while
-TPU deployments take the kernels.
+Each op dispatches between the Pallas kernel and the pure-XLA reference
+path.  ``interpret_mode`` is the single call-time switch between compiling
+a kernel for the chip (TPU backend) and emulating it with
+``interpret=True`` (any other backend); nothing here touches the backend
+while the module is imported.
 """
 from __future__ import annotations
 
@@ -20,10 +22,15 @@ from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.local_sgd import local_sgd_fused as _local_sgd_kernel
 from repro.kernels.ssm_scan import ssm_scan as _ssm_kernel
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-
 _IMPL_KINDS = ("sgd", "agg", "defense", "compress")
 _IMPL_VALUES = ("auto", "kernel", "einsum")
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run under the interpreter: True unless the
+    default backend is a TPU, where they compile for the chip.  Asked at
+    call (trace) time, never at import."""
+    return jax.default_backend() != "tpu"
 
 
 def resolve_impl(name: str, kind: str) -> str:
@@ -42,7 +49,7 @@ def resolve_impl(name: str, kind: str) -> str:
             f"unknown impl kind {kind!r} (known: {list(_IMPL_KINDS)})"
         )
     if name == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "einsum"
+        return "einsum" if interpret_mode() else "kernel"
     if name not in _IMPL_VALUES:
         raise ValueError(
             f"unknown {kind}_impl {name!r} (expected one of {list(_IMPL_VALUES)})"
@@ -53,7 +60,7 @@ def resolve_impl(name: str, kind: str) -> str:
 def fedavg_agg(deltas, weights, *, use_pallas: bool = True, interpret: bool | None = None):
     if not use_pallas:
         return ref.fedavg_agg_ref(deltas, weights)
-    itp = (not _ON_TPU) if interpret is None else interpret
+    itp = interpret_mode() if interpret is None else interpret
     return _fedavg_agg_kernel(deltas, weights, interpret=itp)
 
 
@@ -62,7 +69,7 @@ def pack_codes(codes, *, bits: int, use_pallas: bool = True,
     """Quantization codes (N, D) -> packed uint8 (compression uplink)."""
     if not use_pallas:
         return ref.pack_codes_ref(codes, bits=bits)
-    itp = (not _ON_TPU) if interpret is None else interpret
+    itp = interpret_mode() if interpret is None else interpret
     return _pack_codes_kernel(codes, bits=bits, interpret=itp)
 
 
@@ -71,7 +78,7 @@ def unpack_codes(packed, *, bits: int, dim: int, use_pallas: bool = True,
     """Packed uint8 -> int32 codes (N, dim)."""
     if not use_pallas:
         return ref.unpack_codes_ref(packed, bits=bits, dim=dim)
-    itp = (not _ON_TPU) if interpret is None else interpret
+    itp = interpret_mode() if interpret is None else interpret
     return _unpack_codes_kernel(packed, bits=bits, dim=dim, interpret=itp)
 
 
@@ -80,7 +87,7 @@ def topk_decode(vals, idx, dim: int, *, use_pallas: bool = True,
     """Sparse top-k (vals, idx) -> dense (N, dim) float32 scatter-add."""
     if not use_pallas:
         return ref.topk_decode_ref(vals, idx, dim)
-    itp = (not _ON_TPU) if interpret is None else interpret
+    itp = interpret_mode() if interpret is None else interpret
     return _topk_decode_kernel(vals, idx, dim, interpret=itp)
 
 
@@ -96,7 +103,7 @@ def local_sgd(w1, b1, w2, b2, x, y, act, mask, *, lr: float, batch_size: int,
         return jax.vmap(
             lambda xi, yi, ai, mi: one(w1, b1, w2, b2, xi, yi, ai, mi)
         )(x, y, act, mask)
-    itp = (not _ON_TPU) if interpret is None else interpret
+    itp = interpret_mode() if interpret is None else interpret
     return _local_sgd_kernel(
         w1, b1, w2, b2, x, y, act, mask, lr=lr, batch_size=batch_size,
         epochs=epochs, interpret=itp,
@@ -107,7 +114,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, use_pallas: bool = True,
                     interpret: bool | None = None):
     if not use_pallas:
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    itp = (not _ON_TPU) if interpret is None else interpret
+    itp = interpret_mode() if interpret is None else interpret
     return _flash_kernel(q, k, v, causal=causal, window=window, interpret=itp)
 
 
@@ -115,5 +122,5 @@ def ssm_scan(xd, logdecay, Bc, Cc, *, use_pallas: bool = True,
              interpret: bool | None = None, **kw):
     if not use_pallas:
         return ref.ssm_scan_ref(xd, logdecay, Bc, Cc).astype(xd.dtype)
-    itp = (not _ON_TPU) if interpret is None else interpret
+    itp = interpret_mode() if interpret is None else interpret
     return _ssm_kernel(xd, logdecay, Bc, Cc, interpret=itp, **kw)

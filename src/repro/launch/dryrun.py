@@ -30,10 +30,12 @@ from repro.common.config import INPUT_SHAPES, TrainConfig
 from repro.configs import ARCH_IDS, cfg_for_shape, get_config
 from repro.launch import sharding
 from repro.launch.input_specs import abstract_params, input_specs
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
+from repro.launch.mesh import TARGET_KIND, chip_peaks, make_production_mesh
 from repro.launch.train import TrainState, build_train_step
 from repro.models.model import Model
 from repro.optim.optimizers import make_optimizer
+
+TARGET = chip_peaks(TARGET_KIND)  # the v5e chip these rooflines model
 
 COLLECTIVE_RE = re.compile(
     r"=\s*(\w[\w\d\[\],{}\s]*?)\s+"
@@ -191,9 +193,9 @@ def lower_one(arch, shape_name, *, multi_pod=False, tc=None,
         "collective_bytes_total": coll_total,
         # roofline terms (seconds). cost_analysis flops/bytes are per-device
         # post-partitioning on the CPU backend; see benchmarks/roofline.py.
-        "t_compute": flops / PEAK_FLOPS_BF16,
-        "t_memory": bytes_accessed / HBM_BW,
-        "t_collective": coll_total / ICI_BW,
+        "t_compute": flops / TARGET.flops_bf16,
+        "t_memory": bytes_accessed / TARGET.hbm_bw,
+        "t_collective": coll_total / TARGET.ici_bw,
         "memory": mem_rec,
     }
     if extra_tags:
@@ -263,9 +265,9 @@ def roofline_one(arch, shape_name, *, multi_pod=False, tc=None,
         n2=n2,
         compile_s=r1["compile_s"] + r2["compile_s"],
     )
-    rec["t_compute"] = rec["hlo_flops"] / PEAK_FLOPS_BF16
-    rec["t_memory"] = rec["hlo_bytes"] / HBM_BW
-    rec["t_collective"] = rec["collective_bytes_total"] / ICI_BW
+    rec["t_compute"] = rec["hlo_flops"] / TARGET.flops_bf16
+    rec["t_memory"] = rec["hlo_bytes"] / TARGET.hbm_bw
+    rec["t_collective"] = rec["collective_bytes_total"] / TARGET.ici_bw
     return rec
 
 

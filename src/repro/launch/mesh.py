@@ -11,6 +11,8 @@ cohort axis is the data axis (x pod).
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 
 
@@ -28,7 +30,37 @@ def make_host_mesh(data: int = 1, model: int = 1):
     return jax.make_mesh((data, model), ("data", "model"))
 
 
-# Hardware constants for the roofline model (TPU v5e per chip)
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
+class ChipPeaks(NamedTuple):
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # B/s
+    ici_bw: float  # B/s per link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.  A kind missing here is an error,
+# never a default: a roofline against the wrong chip is a wrong number.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12,
+        hbm_bw=819e9,
+        ici_bw=50e9,  # 1,600 Gbit/s of chip-to-chip interconnect, 4 links
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+# the chip the production meshes above are sized for
+TARGET_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind`` (as ``jax.devices()[0].device_kind``
+    reports it); an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None

@@ -26,9 +26,10 @@ Capability flags gate the engine's specialized hot paths:
 
   ``supports_fused``   -- model ships a fused Pallas local-SGD kernel;
                           ``fused_block_update`` may take a whole client
-                          block in one ``pallas_call``.  When False, the
-                          engine falls back to the vmapped XLA path (and
-                          warns if ``sgd_impl="kernel"`` was forced).
+                          block in one ``pallas_call`` when ``fused_fits``
+                          admits its width.  When False, the engine runs
+                          the vmapped XLA path (and warns if
+                          ``sgd_impl="kernel"`` was forced).
   ``packed_supported`` -- model understands the size-bucketed packed layout
                           (``FederatedDataset.packed_arrays``); the packed
                           buckets reuse ``data_keys`` field names.
@@ -76,11 +77,15 @@ class ClientModel:
         raise NotImplementedError
 
     # ------------------------------------------------- hot-path hooks
+    def fused_fits(self, width: int, batch_size: int) -> bool:
+        """Whether the fused kernel can take a client block whose sample
+        axis is ``width`` wide (``supports_fused`` families override)."""
+        return False
+
     def fused_block_update(self, global_flat, fields, sample_mask, *,
                            lr, batch_size, epochs):
-        """Optional fused-kernel ClientUpdate over a whole client block:
-        return the stacked post-SGD flat params (rows, D) — in the same
-        leaf order as ``core.engine.flatten`` — or ``None`` when the fused
-        kernel does not apply (wrong family, doesn't fit VMEM, ...), which
-        sends the engine down the vmapped XLA path."""
-        return None
+        """Fused-kernel ClientUpdate over a whole client block: the stacked
+        post-SGD flat params (rows, D), in the same leaf order as
+        ``core.engine.flatten``.  The engine calls it only on the route it
+        decided from ``fused_fits``."""
+        raise NotImplementedError

@@ -19,6 +19,7 @@ from repro.kernels.local_sgd import (
     local_sgd_fused,
     local_sgd_fused_ragged,
 )
+from repro.kernels.ops import interpret_mode
 from repro.models.client import ClientModel
 
 
@@ -173,17 +174,19 @@ class MnistClientModel(ClientModel):
             off += n
         return out
 
+    def fused_fits(self, width: int, batch_size: int) -> bool:
+        """Whether a client's ``width`` samples fit one grid step of the
+        fused kernel under its compiled VMEM limit."""
+        cfg = self.cfg
+        return fused_fits_vmem(width, cfg.input_dim, cfg.hidden,
+                               cfg.num_classes, batch=batch_size)
+
     def fused_block_update(self, global_flat, fields, sample_mask, *,
                            lr, batch_size, epochs):
         """One ``pallas_call`` runs every client's whole masked
-        epochs x batches loop; returns ``None`` when the block does not fit
-        the kernel's VMEM budget (engine falls back to the vmapped path)."""
+        epochs x batches loop (the engine routes here only when
+        ``fused_fits`` admits the block width)."""
         x, y, act = fields["x"], fields["y"], fields["activations"]
-        cfg = self.cfg
-        if not fused_fits_vmem(
-            x.shape[1], cfg.input_dim, cfg.hidden, cfg.num_classes
-        ):
-            return None
         p = self._split_flat(global_flat)
         mm = (
             jnp.ones(x.shape[:2], bool) if sample_mask is None
@@ -192,7 +195,7 @@ class MnistClientModel(ClientModel):
         new = local_sgd_fused(
             p["w1"], p["b1"], p["w2"], p["b2"], x, y, act, mm,
             lr=lr, batch_size=batch_size, epochs=epochs,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret_mode(),
         )
         # flatten order must match ``flatten`` (dict leaves sort as
         # b1, b2, w1, w2)
@@ -210,13 +213,8 @@ class MnistClientModel(ClientModel):
         bucket's clients flatten into a single batch-tile buffer addressed
         by scalar-prefetched per-client offsets, so one launch replaces the
         per-bucket dispatch loop.  Returns the (sum rows, D) post-SGD flat
-        params in block order, or ``None`` when a batch tile would not fit
-        the kernel's VMEM budget (engine falls back to per-block vmaps)."""
-        cfg = self.cfg
-        if not fused_fits_vmem(
-            batch_size, cfg.input_dim, cfg.hidden, cfg.num_classes
-        ):
-            return None
+        params in block order (the engine routes here only when
+        ``fused_fits`` admits one batch tile)."""
         xts, yts, mts, acts, nbs = [], [], [], [], []
         for fields, m in blocks:
             x, y = fields["x"], fields["y"]
@@ -242,7 +240,7 @@ class MnistClientModel(ClientModel):
             jnp.concatenate(xts), jnp.concatenate(yts), jnp.concatenate(mts),
             jnp.concatenate(acts), jnp.asarray(nb_arr), jnp.asarray(off),
             lr=lr, epochs=epochs, nb_max=int(nb_arr.max()),
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret_mode(),
         )
         rows = nb_arr.shape[0]
         return jnp.concatenate(

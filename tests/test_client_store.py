@@ -352,7 +352,6 @@ def test_cohort_device_inputs_independent_of_fleet_size():
 
 @pytest.mark.skipif(jax.device_count() < 8, reason="needs 8 devices")
 def test_reduce_tree_matches_flat_psum():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distributed import MeshComms, client_mesh
@@ -367,8 +366,8 @@ def test_reduce_tree_matches_flat_psum():
         def body(xb):  # (1, 37) shard block -> contribute its one row
             return comms.reduce_tree(xb[0])
 
-        f = shard_map(body, mesh=mesh, in_specs=P("clients"), out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=P("clients"),
+                          out_specs=P(), check_vma=False)
         return f(x)
 
     np.testing.assert_array_equal(np.asarray(run(flat_c)),

@@ -26,6 +26,20 @@ def _servers(aggregation="fedar"):
             FedARServer(MnistConfig(), fed, TaskRequirement()))
 
 
+def test_client_mesh_raises_on_too_few_devices():
+    """A mesh wider than the host's devices is an error, never a narrower
+    or dropped mesh."""
+    import jax
+    import pytest
+
+    from repro.core.distributed import client_mesh
+
+    want = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match=f"mesh_shape={want} requested"):
+        client_mesh(fleet_fed(12 * want, mesh_shape=want))
+    assert client_mesh(fleet_fed(12, mesh_shape=1)) is None
+
+
 def test_scan_matches_python_driver_trust_and_loss():
     """Acceptance bar: the scan engine reproduces the per-round driver's
     trust/accuracy histories within 1e-4 on the 12-robot MNIST config."""

@@ -66,11 +66,23 @@ def test_cluster_sketch_keeps_honest_accuracy_on_homogeneous_fleet():
 
 
 def test_dense_foolsgold_still_misfires_on_homogeneous_fleet():
-    """Documents why the sketch variant exists: the dense max-cosine
-    statistic still collapses honest accuracy on the same fleet."""
-    _, _, acc_off, _ = _run("none", 0)
-    _, _, acc_dense, _ = _run("foolsgold", 0)
-    assert acc_dense < acc_off - 0.1
+    """Documents why the sketch variant exists: on the same all-honest
+    fleet the dense max-cosine statistic crushes the honest aggregation
+    weights (most to exactly zero), where the sketch keeps them near 1.
+
+    The misfire is pinned on the weights, not on accuracy: which handful
+    of clients survive the collapse follows the init stream, and so does
+    the accuracy they reach in 6 rounds (legacy threefry: 0.42 vs 0.78
+    with the defense off; the partitionable default: 0.96 vs 0.70) —
+    while 118-122 of 128 honest weights are exactly 0 under both."""
+    eng_d, st_d, _, _ = _run("foolsgold", 0)
+    eng_s, st_s, _, _ = _run("foolsgold_sketch", 0)
+    everyone = jnp.ones(N, bool)
+    w_dense = np.asarray(eng_d.defense.weights(st_d.fg_history, everyone))
+    w_sketch = np.asarray(eng_s.defense.weights(st_s.fg_history, everyone))
+    assert np.median(w_dense) < 0.1
+    assert (w_dense == 0.0).sum() > N // 2
+    assert w_sketch.min() > 0.5
 
 
 def test_cluster_sketch_downweights_sybil_clique():

@@ -26,18 +26,24 @@ ROUNDS = 5
 SHARDS = 4  # 12 clients / 4 shards
 
 # --- committed reference values (float64 prints of the fp32 state) -------
+# Re-pinned for JAX 0.9, whose default PRNG is the partitionable threefry
+# stream: the initial params (``init_mnist``'s normal draws) and every
+# per-round key come out of a different bit stream, so the whole trajectory
+# moves.  Under the legacy stream (JAX_THREEFRY_PARTITIONABLE=0) the engine
+# still lands on the previous pins (sum 68.70524917283183) within these
+# bands — the round math itself did not change.
 GOLDEN_DIM = 25450
-GOLDEN_SUM = 68.70524917283183
-GOLDEN_L2 = 9.585758314927695
+GOLDEN_SUM = 34.67829782890112
+GOLDEN_L2 = 9.597171282616392
 GOLDEN_PROBES = np.array([
-    0.019304556772112846, -0.06349218636751175, 0.05108308419585228,
-    0.032346710562705994, 0.04970241338014603, 0.06573082506656647,
-    -0.1014396920800209, 0.05873619019985199,
+    0.051780179142951965, -0.10490161925554276, 0.08841317147016525,
+    0.0021263263188302517, 0.02496766857802868, -0.059768807142972946,
+    0.005593731999397278, 0.03903869912028313,
 ])
 GOLDEN_TRUST = np.array(
     [90.0, 55.0, 55.0, 55.0, 90.0, 90.0, 90.0, 90.0, 50.0, 50.0, 90.0, 55.0]
 )
-GOLDEN_FG_HIST_L2 = 10.212746620178223
+GOLDEN_FG_HIST_L2 = 10.340286229434085
 
 # fp32 accumulation over 5 rounds x 15 local steps: reduction-order noise
 # stays well under these bands, a numerics regression does not
@@ -94,17 +100,19 @@ def test_golden_sharded():
 # --- gated + bucketed hot path: its own pinned trajectory ----------------
 # N=12 digits/quantity_skew (seed 7, 60 samples/client), 5 rounds of fedar +
 # foolsgold_sketch with select_frac=0.5 over the packed (quantum=20) layout.
-GATED_SUM = 92.49541523193693
-GATED_L2 = 10.314037802900431
+# Re-pinned for the partitionable threefry default, as above (legacy-stream
+# pin: sum 92.49541523193693, still reproduced under the legacy stream).
+GATED_SUM = 33.584440031547274
+GATED_L2 = 10.450699959572368
 GATED_PROBES = np.array([
-    -0.013791415840387344, -0.061055414378643036, 0.06815582513809204,
-    0.042934220284223557, 0.04195379838347435, 0.11835479736328125,
-    -0.10140914469957352, 0.046867094933986664,
+    0.12363096326589584, -0.0856688991189003, 0.09945178031921387,
+    -0.014505666680634022, 0.02983429655432701, -0.01660073734819889,
+    0.003230014815926552, -0.0025894069112837315,
 ])
 GATED_TRUST = np.array(
     [90.0, 55.0, 55.0, 55.0, 90.0, 90.0, 90.0, 90.0, 50.0, 50.0, 90.0, 55.0]
 )
-GATED_FG_L2 = 8.843296871281623
+GATED_FG_L2 = 9.322696013000405
 
 
 def _run_gated_packed(mesh_shape=None, **fed_kw):
@@ -172,18 +180,26 @@ def test_golden_gated_packed_fused_ragged_kernel():
 # The default-path goldens above double as the compress="none" bit-identity
 # pin: FedConfig.compress defaults to "none", so any leakage of the
 # compression machinery into the uncompressed round body breaks THEM.
-QSGD_SUM = 69.01208786378629
-QSGD_L2 = 9.585405891872805
+# Re-pinned for JAX 0.9.  Two causes, both outside the round math:
+#   * the partitionable threefry default (as above) moves the trajectory;
+#   * the previous pin (sum 69.01208786378629) is not reproduced even under
+#     the legacy stream, nor at the commit that pinned it (69.076308 there):
+#     stochastic rounding turns ulp-level drift of XLA's CPU kernels between
+#     JAX releases into flipped codes.  Scaling the encoder input by
+#     (1 + 3e-7), about two ulps, moves the final sum by 2.4e-4 relative on
+#     this config, so this pin is only as stable as XLA's CPU arithmetic.
+QSGD_SUM = 34.46900024070055
+QSGD_L2 = 9.597337158202446
 QSGD_PROBES = np.array([
-    0.01865065097808838, -0.06364136189222336, 0.0508258081972599,
-    0.03253442049026489, 0.049707189202308655, 0.06594192236661911,
-    -0.1013520210981369, 0.05862641707062721,
+    0.05204898864030838, -0.10477588325738907, 0.08817274123430252,
+    0.0014773530419915915, 0.025014609098434448, -0.05985404551029205,
+    0.00497193681076169, 0.03910137340426445,
 ])
 QSGD_TRUST = np.array(
     [90.0, 55.0, 55.0, 55.0, 90.0, 90.0, 90.0, 90.0, 50.0, 50.0, 90.0, 55.0]
 )
-QSGD_FG_L2 = 10.211340131551674
-QSGD_RESIDUAL_L2 = 0.09969845297580801
+QSGD_FG_L2 = 10.335655778872411
+QSGD_RESIDUAL_L2 = 0.11810030032934266
 
 
 def test_golden_qsgd_compressed():

@@ -91,6 +91,27 @@ def test_sharded_async_buffer_state_matches():
                                       np.asarray(getattr(s8, f)))
 
 
+def test_sharded_rounds_compile_once():
+    """Round inputs are laid out on the mesh before the jitted round, so
+    the sharded state a round returns matches the layout the first round
+    compiled for (no second compile), and prepared data lands in shards."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro import make_federated
+
+    _, e8 = _engines("fedar", n=64)
+    data = e8.prepare_data(
+        make_federated("digits", 64, samples_per_client=20)
+    )
+    x = data["x"]
+    assert x.sharding == NamedSharding(e8.mesh, P("clients"))
+    state = e8.init_state()
+    for _ in range(3):
+        state, _ = e8.step(state, data)
+    assert e8._step._cache_size() == 1
+
+
 def test_sharded_foolsgold_gathered_product_matches():
     """FoolsGold's gathered block similarity == the dense (N, N) matrix."""
     e1, e8 = _engines("fedar", n=64, foolsgold=True)

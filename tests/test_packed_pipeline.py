@@ -223,6 +223,21 @@ def test_packed_engine_bit_identical_property(scenario, seed, samples,
     _assert_states_equal(s0, s1)
 
 
+def test_packed_lone_row_bucket_bit_identical():
+    """A bucket holding a single client (here the 21-sample client of
+    quantity_skew seed 16) trains bit-identically to the same client inside
+    the dense block: XLA lowers a size-1 vmap without its batch axis, which
+    reorders the gradient sums, so the engine runs a lone row batched."""
+    ds = make_federated("digits", 8, scenario="quantity_skew",
+                        samples_per_client=8, seed=16)
+    packed = ds.packed_arrays(quantum=None)
+    assert min(x.shape[0] for x in packed["packed"]["x"]) == 1
+    engine = _engine(8, local_epochs=1)
+    s0, _ = _run(engine, ds.arrays(), rounds=2)
+    s1, _ = _run(engine, packed, rounds=2)
+    _assert_states_equal(s0, s1)
+
+
 # ------------------------------------------------------- selection gating
 
 @pytest.mark.parametrize("frac", [0.5, 1.0])
@@ -300,6 +315,58 @@ def test_engine_sgd_kernel_routing_matches_xla():
                  ds.packed_arrays(), rounds=2)
     np.testing.assert_allclose(np.asarray(s0.params), np.asarray(s1.params),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl,layout,route", [
+    ("einsum", "dense", "xla"),
+    ("einsum", "packed", "xla"),
+    ("kernel", "dense", "fused"),
+    ("kernel", "packed", "fused_ragged"),
+])
+def test_sgd_route_recorded(impl, layout, route):
+    """The local-SGD route is decided host-side from the data's layout and
+    recorded on the engine (and in ``kernel_routes``) before tracing."""
+    ds = make_federated("digits", 6, scenario="quantity_skew",
+                        samples_per_client=20, seed=3)
+    data = ds.arrays() if layout == "dense" else ds.packed_arrays()
+    engine = _engine(6, local_epochs=1, sgd_impl=impl)
+    assert engine.sgd_route == ("xla" if impl == "einsum" else None)
+    engine.step(engine.init_state(), jax.tree.map(jnp.asarray, data))
+    assert engine.sgd_route == route
+    assert engine.kernel_routes()["sgd"] == route
+
+
+def test_sgd_route_auto_follows_backend():
+    """``auto`` takes the kernels only where they compile (a TPU backend),
+    the XLA path everywhere else."""
+    from repro.data.federated import scaled_fleet
+    from repro.kernels.ops import interpret_mode
+
+    engine = _engine(6, local_epochs=1)
+    data = scaled_fleet(6, samples_per_client=20)
+    engine.step(engine.init_state(), jax.tree.map(jnp.asarray, data))
+    routes = engine.kernel_routes()
+    if interpret_mode():
+        assert routes["sgd"] == "xla"
+        assert set(routes.values()) <= {"xla", "einsum", "none"}
+    else:
+        assert routes["sgd"] == "fused" and routes["agg"] == "kernel"
+
+
+def test_explicit_kernel_too_wide_raises():
+    """An explicit ``sgd_impl="kernel"`` whose client block is wider than
+    the fused kernel's compiled VMEM limit admits raises before tracing,
+    instead of silently running the XLA path."""
+    from repro.kernels.local_sgd import fused_fits_vmem
+
+    width = 4000
+    assert not fused_fits_vmem(width, 784, 8, 10, batch=20)
+    engine = _engine(2, local_epochs=1, sgd_impl="kernel")
+    data = {"x": np.zeros((2, width, 784), np.float32),
+            "y": np.zeros((2, width), np.int32),
+            "activations": np.zeros(2, np.int32)}
+    with pytest.raises(ValueError, match="does not fit the fused local-SGD"):
+        engine.step(engine.init_state(), data)
 
 
 def test_select_frac_validation():
