@@ -65,12 +65,17 @@ def _tile_bytes(rows: int, cols: int) -> int:
     return 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
 
 
-def fused_vmem_bytes(n: int, batch: int, input_dim: int, hidden: int,
-                     classes: int) -> int:
-    """Padded VMEM footprint of one grid step of ``local_sgd_fused``: the
-    double-buffered (nb, B, I) sample slab and its (nb, B, 1) label / mask
-    columns, the double-buffered in/out parameter tiles, and the batch
-    body's parameter-sized temporaries (loaded weights and gradients)."""
+def fused_fits_vmem(n: int, input_dim: int, hidden: int, classes: int,
+                    batch: int = 20, limit: int = VMEM_LIMIT_BYTES) -> bool:
+    """Whether one client's ``local_sgd_fused`` grid step — n samples in
+    batches of ``batch`` — fits the scoped VMEM limit the kernel compiles
+    under.  The engine decides its local-SGD route from this, host-side,
+    before tracing (``FedAREngine.sgd_route``).
+
+    The padded footprint counted: the double-buffered (nb, B, I) sample
+    slab and its (nb, B, 1) label / mask columns, the double-buffered
+    in/out parameter tiles, and the batch body's parameter-sized
+    temporaries (loaded weights and gradients)."""
     nb = -(-n // batch)
     slab = nb * (_tile_bytes(batch, input_dim) + 2 * _tile_bytes(batch, 1))
     params = (
@@ -78,16 +83,7 @@ def fused_vmem_bytes(n: int, batch: int, input_dim: int, hidden: int,
         + _tile_bytes(hidden, classes) + _tile_bytes(1, classes)
     )
     temps = 2 * (_tile_bytes(input_dim, hidden) + _tile_bytes(hidden, classes))
-    return 2 * slab + 4 * params + temps
-
-
-def fused_fits_vmem(n: int, input_dim: int, hidden: int, classes: int,
-                    batch: int = 20, limit: int = VMEM_LIMIT_BYTES) -> bool:
-    """Whether one client's ``local_sgd_fused`` grid step — n samples in
-    batches of ``batch`` — fits the scoped VMEM limit the kernel compiles
-    under.  The engine decides its local-SGD route from this, host-side,
-    before tracing (``FedAREngine.sgd_route``)."""
-    return fused_vmem_bytes(n, batch, input_dim, hidden, classes) <= limit
+    return 2 * slab + 4 * params + temps <= limit
 
 
 def _compiler_params():
