@@ -1,0 +1,10 @@
+"""Device-to-host copies per round: the sum of the ``copies`` stat on the
+program's ``fedar.fetch`` spans (``spanreduce``)."""
+
+
+def read(r):
+    spans = getattr(r, "spans", None)
+    fetch = spans.stats.get("fedar.fetch", {}) if spans else {}
+    if "copies" not in fetch or not r.rounds:
+        return None
+    return fetch["copies"] / r.rounds

@@ -53,17 +53,6 @@ P1_ACC_FLOOR = 0.8
 MESH_TRUST_ATOL = 1e-4
 MESH_PARAM_TOL = 1e-3
 
-# XLA compilations so far, persistent-cache loads included; a steady round
-# must add none, or its time is a compile's
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_COMPILES = [0]
-
-
-def _count_compile(event, _secs, **_):
-    if event == COMPILE_EVENT:
-        _COMPILES[0] += 1
-
-
 def check_device(devices, chips: int) -> dict:
     """The device record of the final line; anything but ``chips`` TPU
     devices is refused (there is no CPU path)."""
@@ -131,13 +120,16 @@ def _server(num_clients, impl="auto", **fed_kw):
 def _steady_round(server, data, eval_set, reps=3):
     """Median seconds of an already-compiled round, on a throwaway copy of
     the resident state (the run's own state is untouched), and the
-    compilations those rounds triggered."""
+    compilations those rounds triggered (a steady round must add none, or
+    its time is a compile's)."""
+    from repro.common.tracing import compile_count
+
     engine, state = server.engine, server.state
-    before, times = _COMPILES[0], []
+    before, times = compile_count(), []
     for _ in range(reps):
         _, dt = _timed(lambda: engine.step(state, data, eval_set=eval_set))
         times.append(dt)
-    return statistics.median(times), _COMPILES[0] - before
+    return statistics.median(times), compile_count() - before
 
 
 def run_paper_fleet(impl: str, *, rounds: int = 10, samples: int = 300):
@@ -198,13 +190,15 @@ def _rounds_by_step(server, data, rounds, eval_set):
     """``rounds`` (>= 2) jitted rounds through ``run_round`` -> (first-round
     seconds, median seconds of the later rounds, compilations the later
     rounds triggered)."""
+    from repro.common.tracing import compile_count
+
     times = []
     for r in range(rounds):
         if r == 1:
-            before = _COMPILES[0]
+            before = compile_count()
         _, dt = _timed(lambda: server.run_round(data, eval_set=eval_set))
         times.append(dt)
-    return times[0], statistics.median(times[1:]), _COMPILES[0] - before
+    return times[0], statistics.median(times[1:]), compile_count() - before
 
 
 def run_resident(*, clients=2048, samples=100, rounds=3, impl="auto",
@@ -358,7 +352,6 @@ def main(argv=None) -> int:
 
     device = check_device(jax.devices(), args.chips)
     cache = enable_compile_cache()
-    jax.monitoring.register_event_duration_secs_listener(_count_compile)
     print(json.dumps({"compile_cache": cache}), flush=True)
     phases = ([phase_m2, phase_m3] if args.chips == 4
               else [phase_p1, phase_p2, phase_p3])

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.config import FedConfig
+from repro.common.tracing import phase
 from repro.kernels import ops
 
 __all__ = ["CompressionStrategy", "NoCompression", "QSGDCompression",
@@ -90,15 +91,16 @@ class CompressionStrategy:
         """The engine's one call: encode/decode ``deltas + residual`` and
         apply error feedback, gated on the shard-local ``transmit`` mask.
         Returns ``(decoded, new_residual, payload)`` where non-transmitting
-        rows decode to exact zeros and keep their residual untouched."""
-        payload, res = self.encode(deltas, residual, keys)
-        dec = self.decode(payload, deltas.shape[-1])
+        rows decode to exact zeros and keep their residual untouched.  The
+        two halves run under the ``codec.encode`` / ``codec.decode`` phase
+        scopes (``common/tracing.py``)."""
         m = transmit[:, None]
-        return (
-            jnp.where(m, dec, 0.0),
-            jnp.where(m, res, residual),
-            payload,
-        )
+        with phase("codec.encode"):
+            payload, res = self.encode(deltas, residual, keys)
+            res = jnp.where(m, res, residual)
+        with phase("codec.decode"):
+            dec = jnp.where(m, self.decode(payload, deltas.shape[-1]), 0.0)
+        return dec, res, payload
 
 
 class NoCompression(CompressionStrategy):

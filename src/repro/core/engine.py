@@ -76,7 +76,9 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.common import tracing
 from repro.common.config import FedConfig
+from repro.common.tracing import phase, span
 from repro.configs.fedar_mnist import MnistConfig
 from repro.core import aggregation as agg
 from repro.core.compress import client_keys as compress_keys
@@ -160,6 +162,8 @@ class RoundOutputs(NamedTuple):
     round_time: jnp.ndarray  # () virtual seconds this round cost
     loss: jnp.ndarray  # () eval loss (nan when no eval set)
     acc: jnp.ndarray  # () eval accuracy (nan when no eval set)
+    codec_rows_sent: jnp.ndarray  # () int32 rows the codec transmitted
+    #                               (0 with compression off)
 
 
 class FedAREngine:
@@ -211,6 +215,9 @@ class FedAREngine:
         self.dim = flatten(self.template).shape[0]
         self.defense = make_defense(fed, self.dim)
         self.compression = make_compression(fed, self.dim)
+        # rows the uplink codec encodes a round: every client's, sent or
+        # not (RoundOutputs.codec_rows_sent counts the sent ones)
+        self.codec_rows = fed.num_clients if self.compression.active else 0
         self.faults = make_faults(fed)
         self.resources0, self.poison_mask = make_fleet(
             fed.num_clients,
@@ -321,8 +328,7 @@ class FedAREngine:
         return specs
 
     def _round_out_specs(self) -> RoundOutputs:
-        Pr = replicated_spec()
-        return RoundOutputs(Pr, Pr, Pr, Pr, Pr, Pr)
+        return RoundOutputs(*[replicated_spec()] * len(RoundOutputs._fields))
 
     def _in_specs(self, data, eval_set, force_straggler):
         Pr = replicated_spec()
@@ -641,6 +647,9 @@ class FedAREngine:
         *dense* sample width so the physical layout (packed or padded)
         cannot shift straggler numerics.
 
+        Each phase of the round runs under its ``tracing.phase`` scope
+        (``faults`` ... ``eval``), which names its ops in a profile.
+
         Under mesh comms this body executes per-shard: the sample arrays
         (or the packed buckets), ``state.fg_history`` and
         ``state.pending_delta`` hold this shard's client block; everything
@@ -656,10 +665,11 @@ class FedAREngine:
         # never moves, and faults="none" draws nothing at all
         fdraw = None
         if self.faults.active:
-            fdraw = self.faults.draw(
-                key, jnp.arange(fed.num_clients, dtype=jnp.int32),
-                state.round_idx,
-            )
+            with phase("faults"):
+                fdraw = self.faults.draw(
+                    key, jnp.arange(fed.num_clients, dtype=jnp.int32),
+                    state.round_idx,
+                )
 
         # --- Algorithm 2 lines 6-10: CheckResource + trust sort + sample
         # (global (N,) math, replicated across shards).  In cohort mode
@@ -667,99 +677,112 @@ class FedAREngine:
         # client store (selection.sample_cohort) and every gathered row IS
         # a participant — ``cohort_valid`` marks the genuinely selected
         # slots (underfill slots are inert: all-False mask, zero weight).
-        if "cohort_valid" in data:
-            selected = ok = data["cohort_valid"]
-            if fdraw is not None:
-                # flapping / battery-dead clients fail CheckResource even
-                # though the host sampled them before the fault draw
-                selected = ok = selected & ~fdraw.unavailable
-        else:
-            res_sel = state.resources
-            if fdraw is not None:
-                # an offline window reads as a dead battery to
-                # CheckResource; the persistent battery column is untouched
-                res_sel = res_sel._replace(
-                    battery=jnp.where(fdraw.unavailable, 0.0,
-                                      res_sel.battery)
+        with phase("select"):
+            if "cohort_valid" in data:
+                selected = ok = data["cohort_valid"]
+                if fdraw is not None:
+                    # flapping / battery-dead clients fail CheckResource
+                    # even though the host sampled them before the draw
+                    selected = ok = selected & ~fdraw.unavailable
+            else:
+                res_sel = state.resources
+                if fdraw is not None:
+                    # an offline window reads as a dead battery to
+                    # CheckResource; the persistent battery column is
+                    # untouched
+                    res_sel = res_sel._replace(
+                        battery=jnp.where(fdraw.unavailable, 0.0,
+                                          res_sel.battery)
+                    )
+                selected, ok = select_clients(
+                    k_sel, state.trust, res_sel, self.req, fed
                 )
-            selected, ok = select_clients(
-                k_sel, state.trust, res_sel, self.req, fed
-            )
 
         g_flat = state.params
         locals_c = cohort = None  # compact gated-cohort view, when gating
-        if "packed" in data:
-            # --- lines 16-21 (ClientUpdate), padding-free bucketed path
-            locals_flat, locals_c, cohort = self._packed_locals(
-                g_flat, data["packed"], selected, state.round_idx
-            )
-        else:
-            # --- ragged / drifting shards: resolve this round's sample mask
-            sample_mask = data.get("mask")
-            if "round_mask" in data:
-                rm = data["round_mask"]
-                active_window = jax.lax.dynamic_index_in_dim(
-                    rm, jnp.remainder(state.round_idx, rm.shape[0]), 0,
-                    keepdims=False,
+        with phase("local_sgd"):
+            if "packed" in data:
+                # --- lines 16-21 (ClientUpdate), padding-free bucketed path
+                locals_flat, locals_c, cohort = self._packed_locals(
+                    g_flat, data["packed"], selected, state.round_idx
                 )
-                sample_mask = (
-                    active_window if sample_mask is None
-                    else sample_mask & active_window
-                )
-
-            # --- lines 16-21 (ClientUpdate): local SGD vmapped over this
-            # shard's client block (or its gated cohort); non-participants
-            # are masked out of the aggregate
-            fields = {k: data[k] for k in self.model.data_keys}
-            if self.cohort_cap is None:
-                locals_flat = self._block_sgd(g_flat, fields, sample_mask)
             else:
-                sel_loc = comms.local(selected)
-                idx, locals_c, valid = self._gated_block_locals(
-                    g_flat, fields, sample_mask, sel_loc
-                )
-                cohort = (idx, valid)
-                locals_flat = self._expand_cohort(
-                    locals_c, idx, valid, sel_loc.shape[0], g_flat
-                )
-        deltas = locals_flat - g_flat[None, :]  # (N_loc, D)
-        # compact deltas: deviation + the fedar/fedavg reduction only touch
-        # cohort rows (the rest are exact zeros), so with the defense off
-        # XLA drops the canonical expansion from the gated hot path
-        delta_c = None if locals_c is None else locals_c - g_flat[None, :]
+                # --- ragged / drifting shards: resolve this round's sample
+                # mask
+                sample_mask = data.get("mask")
+                if "round_mask" in data:
+                    rm = data["round_mask"]
+                    active_window = jax.lax.dynamic_index_in_dim(
+                        rm, jnp.remainder(state.round_idx, rm.shape[0]), 0,
+                        keepdims=False,
+                    )
+                    sample_mask = (
+                        active_window if sample_mask is None
+                        else sample_mask & active_window
+                    )
+
+                # --- lines 16-21 (ClientUpdate): local SGD vmapped over
+                # this shard's client block (or its gated cohort); non-
+                # participants are masked out of the aggregate
+                fields = {k: data[k] for k in self.model.data_keys}
+                if self.cohort_cap is None:
+                    locals_flat = self._block_sgd(g_flat, fields,
+                                                  sample_mask)
+                else:
+                    sel_loc = comms.local(selected)
+                    idx, locals_c, valid = self._gated_block_locals(
+                        g_flat, fields, sample_mask, sel_loc
+                    )
+                    cohort = (idx, valid)
+                    locals_flat = self._expand_cohort(
+                        locals_c, idx, valid, sel_loc.shape[0], g_flat
+                    )
+            deltas = locals_flat - g_flat[None, :]  # (N_loc, D)
+            # compact deltas: deviation + the fedar/fedavg reduction only
+            # touch cohort rows (the rest are exact zeros), so with the
+            # defense off XLA drops the canonical expansion from the gated
+            # hot path
+            delta_c = (None if locals_c is None
+                       else locals_c - g_flat[None, :])
         crashed = None
         if fdraw is not None:
             # mid-round crash: the client trained (battery burns below) but
             # its uplink never reaches the server this round
-            crashed = selected & fdraw.crash
+            with phase("faults"):
+                crashed = selected & fdraw.crash
             # corruption and quarantine rewrite canonical rows, so the
             # compact gated shortcut is invalid under an active schedule
             delta_c = cohort = None
 
         # --- virtual time: latency per client, straggler = late vs timeout
-        model_bytes = self.dim * 4.0
-        lat = round_latency(
-            state.resources,
-            train_flops=train_flops,
-            model_bytes=model_bytes,
-            key=k_lat,
-        )
-        if force_straggler is not None:
-            lat = jnp.where(jnp.asarray(force_straggler), fed.timeout * 3.0, lat)
-        on_time = lat <= fed.timeout
-        if crashed is not None:
-            # crash-aware straggler masking: a crashed client reads as a
-            # missed deadline (trust failure band), never as an arrival
-            on_time = on_time & ~crashed
-        # the rows the server can ever receive this round (== selected on
-        # the fault-free path, so every mask below is bit-identical there)
-        uplinked = selected if crashed is None else selected & ~crashed
-        # rows actually visible server-side per mode: fedavg waits for
-        # stragglers and async buffers them; fedar/async_seq skip on timeout
-        if fed.aggregation in ("fedavg", "async"):
-            seen = uplinked
-        else:
-            seen = uplinked & on_time
+        with phase("latency"):
+            model_bytes = self.dim * 4.0
+            lat = round_latency(
+                state.resources,
+                train_flops=train_flops,
+                model_bytes=model_bytes,
+                key=k_lat,
+            )
+            if force_straggler is not None:
+                lat = jnp.where(jnp.asarray(force_straggler),
+                                fed.timeout * 3.0, lat)
+            on_time = lat <= fed.timeout
+            if crashed is not None:
+                # crash-aware straggler masking: a crashed client reads as
+                # a missed deadline (trust failure band), never as an
+                # arrival
+                on_time = on_time & ~crashed
+            # the rows the server can ever receive this round (== selected
+            # on the fault-free path, so every mask below is bit-identical
+            # there)
+            uplinked = selected if crashed is None else selected & ~crashed
+            # rows actually visible server-side per mode: fedavg waits for
+            # stragglers and async buffers them; fedar/async_seq skip on
+            # timeout
+            if fed.aggregation in ("fedavg", "async"):
+                seen = uplinked
+            else:
+                seen = uplinked & on_time
 
         # --- uplink compression (core/compress.py): transmitting clients
         # send the encoded payload; the server decodes it and everything
@@ -768,32 +791,40 @@ class FedAREngine:
         # exact zeros and keep their error-feedback residual untouched.
         residual = state.compress_residual
         deltas_raw = transmit_g = None
+        codec_rows_sent = jnp.zeros((), jnp.int32)
         if self.compression.active:
-            # per-mode transmit window: fedavg waits for stragglers, so
-            # they transmit too; fedar's timeout-skipped clients never
-            # upload; async transmits exactly when the buffer has a slot to
-            # admit into (a free slot or an on-time supersede — the
-            # client-side-knowable superset of _buffered_async's admit
-            # gate, so error feedback is consumed iff the row can land)
-            if fed.aggregation == "fedavg":
-                transmit_g = uplinked
-            elif fed.aggregation == "async":
-                lag0 = jnp.floor(lat / fed.timeout).astype(jnp.int32) == 0
-                transmit_g = uplinked & (lag0 | ~state.pending_valid)
-            else:
-                transmit_g = uplinked & on_time
-            transmit = comms.local(transmit_g)
+            with phase("codec.encode"):
+                # per-mode transmit window: fedavg waits for stragglers, so
+                # they transmit too; fedar's timeout-skipped clients never
+                # upload; async transmits exactly when the buffer has a
+                # slot to admit into (a free slot or an on-time supersede —
+                # the client-side-knowable superset of _buffered_async's
+                # admit gate, so error feedback is consumed iff the row can
+                # land)
+                if fed.aggregation == "fedavg":
+                    transmit_g = uplinked
+                elif fed.aggregation == "async":
+                    lag0 = (jnp.floor(lat / fed.timeout).astype(jnp.int32)
+                            == 0)
+                    transmit_g = uplinked & (lag0 | ~state.pending_valid)
+                else:
+                    transmit_g = uplinked & on_time
+                transmit = comms.local(transmit_g)
+                codec_rows_sent = jnp.sum(transmit_g, dtype=jnp.int32)
+                # stochastic codes keyed on the CANONICAL client id so
+                # 1-device and sharded runs quantize bit-identically (the
+                # round key's 3-way split above stays untouched for golden
+                # stability)
+                keys = compress_keys(
+                    jax.random.fold_in(key, _COMPRESS_KEY_FOLD),
+                    comms.local(jnp.arange(fed.num_clients,
+                                           dtype=jnp.int32)),
+                )
             # the gated compact view is a compute shortcut; post-decode the
             # canonical rows are what every downstream op must see
             delta_c = cohort = None
-            # stochastic codes keyed on the CANONICAL client id so 1-device
-            # and sharded runs quantize bit-identically (the round key's
-            # 3-way split above stays untouched for golden stability)
-            keys = compress_keys(
-                jax.random.fold_in(key, _COMPRESS_KEY_FOLD),
-                comms.local(jnp.arange(fed.num_clients, dtype=jnp.int32)),
-            )
             deltas_raw = deltas
+            # roundtrip opens the codec.encode / codec.decode scopes itself
             deltas, residual, payload = self.compression.roundtrip(
                 deltas, residual, transmit, keys
             )
@@ -803,12 +834,13 @@ class FedAREngine:
         # RECEIVES (post-decode, pre-quarantine) — exactly what a flipped
         # bit or truncated payload on the wire would produce
         if fdraw is not None:
-            corrupt_g = fdraw.corrupt & (
-                transmit_g if transmit_g is not None else seen
-            )
-            c_loc = comms.local(corrupt_g)[:, None]
-            deltas = jnp.where(c_loc, comms.local(fdraw.fill)[:, None],
-                               deltas)
+            with phase("faults"):
+                corrupt_g = fdraw.corrupt & (
+                    transmit_g if transmit_g is not None else seen
+                )
+                c_loc = comms.local(corrupt_g)[:, None]
+                deltas = jnp.where(c_loc, comms.local(fdraw.fill)[:, None],
+                                   deltas)
 
         # --- non-finite quarantine at the decode boundary (ALWAYS on): a
         # NaN/Inf — or, past the configured magnitude cap, any garbage —
@@ -818,66 +850,70 @@ class FedAREngine:
         # one fused (N_loc, D) pass: the magnitude test rides the same
         # reduction as the finiteness test (a second max-abs reduction cost
         # ~13% of the round at N=128 — the fault win condition's budget)
-        row_ok = jnp.isfinite(deltas)
-        cap = fed.resolved_quarantine_cap
-        if cap is not None:
-            row_ok = row_ok & (jnp.abs(deltas) <= cap)
-        q_loc = ~jnp.all(row_ok, axis=-1)
-        deltas = jnp.where(q_loc[:, None], 0.0, deltas)
-        if cohort is not None:
-            delta_c = jnp.where(q_loc[cohort[0]][:, None], 0.0, delta_c)
-        if self.compression.active:
-            # dropped-uplink retry: a quarantined transmission consumed its
-            # error-feedback residual for nothing — put the FULL raw value
-            # (delta + pre-round residual) back in the residual so the next
-            # transmission carries it (PR 9's telescoping invariant extends
-            # to faults).  A non-finite raw value is unrecoverable; fall
-            # back to the pre-round residual so the carry is never poisoned.
-            v = deltas_raw + state.compress_residual
-            v_el = jnp.isfinite(v)
+        with phase("quarantine"):
+            row_ok = jnp.isfinite(deltas)
+            cap = fed.resolved_quarantine_cap
             if cap is not None:
-                v_el = v_el & (jnp.abs(v) <= cap)
-            v_ok = jnp.all(v_el, axis=-1)
-            retry = q_loc & comms.local(transmit_g)
-            residual = jnp.where(
-                retry[:, None],
-                jnp.where(v_ok[:, None], v, state.compress_residual),
-                residual,
-            )
-        quarantined = comms.all_gather(q_loc)  # (N,) replicated
+                row_ok = row_ok & (jnp.abs(deltas) <= cap)
+            q_loc = ~jnp.all(row_ok, axis=-1)
+            deltas = jnp.where(q_loc[:, None], 0.0, deltas)
+            if cohort is not None:
+                delta_c = jnp.where(q_loc[cohort[0]][:, None], 0.0, delta_c)
+            if self.compression.active:
+                # dropped-uplink retry: a quarantined transmission consumed
+                # its error-feedback residual for nothing — put the FULL raw
+                # value (delta + pre-round residual) back in the residual so
+                # the next transmission carries it (error feedback's
+                # telescoping invariant extends to faults).  A non-finite raw value is
+                # unrecoverable; fall back to the pre-round residual so the
+                # carry is never poisoned.
+                v = deltas_raw + state.compress_residual
+                v_el = jnp.isfinite(v)
+                if cap is not None:
+                    v_el = v_el & (jnp.abs(v) <= cap)
+                v_ok = jnp.all(v_el, axis=-1)
+                retry = q_loc & comms.local(transmit_g)
+                residual = jnp.where(
+                    retry[:, None],
+                    jnp.where(v_ok[:, None], v, state.compress_residual),
+                    residual,
+                )
+            quarantined = comms.all_gather(q_loc)  # (N,) replicated
 
         # --- line 11: deviation ban + robust-defense weights
-        if fed.aggregation == "async":
-            # no-wait: every (non-crashed) participant's update eventually
-            # lands, so screen all of them
-            active = uplinked
-        else:
-            active = selected & on_time
-        # quarantined rows are zeroed — keep them out of the deviation
-        # statistics (a zero row would drag the population mean) and brand
-        # them deviated instead: exact-zero aggregation weight plus the
-        # trust ban, the same fate as a caught poisoner
-        screen = active & ~quarantined
-        if cohort is None:
-            deviated = agg.deviation_mask(
-                deltas, screen, fed.deviation_gamma, comms=comms
-            )
-        else:
-            deviated = agg.deviation_mask(
-                delta_c, screen, fed.deviation_gamma, comms=comms,
-                cohort=cohort,
-            )
-        deviated = deviated | (seen & quarantined)
-        contributing = active & ~deviated
+        with phase("deviation"):
+            if fed.aggregation == "async":
+                # no-wait: every (non-crashed) participant's update
+                # eventually lands, so screen all of them
+                active = uplinked
+            else:
+                active = selected & on_time
+            # quarantined rows are zeroed — keep them out of the deviation
+            # statistics (a zero row would drag the population mean) and
+            # brand them deviated instead: exact-zero aggregation weight
+            # plus the trust ban, the same fate as a caught poisoner
+            screen = active & ~quarantined
+            if cohort is None:
+                deviated = agg.deviation_mask(
+                    deltas, screen, fed.deviation_gamma, comms=comms
+                )
+            else:
+                deviated = agg.deviation_mask(
+                    delta_c, screen, fed.deviation_gamma, comms=comms,
+                    cohort=cohort,
+                )
+            deviated = deviated | (seen & quarantined)
+            contributing = active & ~deviated
         weights = data["sizes"].astype(jnp.float32)
         # pluggable defense (core/defense.py): the strategy owns its carried
         # history block (dense, sketched, or empty) and its weight statistic
-        fg_history = self.defense.update_history(
-            state.fg_history, deltas, contributing, comms=comms
-        )
-        fgw = self.defense.weights(fg_history, contributing, comms=comms)
-        if fgw is not None:
-            weights = weights * fgw
+        with phase("defense"):
+            fg_history = self.defense.update_history(
+                state.fg_history, deltas, contributing, comms=comms
+            )
+            fgw = self.defense.weights(fg_history, contributing, comms=comms)
+            if fgw is not None:
+                weights = weights * fgw
 
         # --- lines 13-14: aggregate
         pending = dict(
@@ -888,49 +924,52 @@ class FedAREngine:
             valid=state.pending_valid,
         )
         agg_rows = deltas if cohort is None else delta_c
-        if fed.aggregation == "fedavg":
-            # synchronous: waits for everyone whose upload can still land
-            # (stragglers included; crashed clients never arrive)
-            sync_active = uplinked & ~deviated
-            g_new = agg.fedavg_aggregate(
-                g_flat, agg_rows, weights, sync_active, impl=fed.agg_impl,
-                comms=comms, cohort=cohort,
-            )
-            round_time = jnp.max(jnp.where(uplinked, lat, 0.0))
-        elif fed.aggregation == "async":
-            g_new, pending = self._buffered_async(
-                g_flat, deltas, weights, contributing, lat, pending,
-                state.round_idx,
-            )
-            round_time = jnp.full((), fed.timeout)
-        elif fed.aggregation == "async_seq":
-            order = jnp.argsort(jnp.where(contributing, lat, jnp.inf))
-            g_new = agg.async_aggregate(
-                g_flat, locals_flat, weights, contributing, order, fed,
-                comms=comms,
-            )
-            round_time = jnp.full((), fed.timeout)
-        else:  # fedar (timeout skip)
-            g_new = agg.fedavg_aggregate(
-                g_flat, agg_rows, weights, contributing, impl=fed.agg_impl,
-                comms=comms, cohort=cohort,
-            )
-            round_time = jnp.full((), fed.timeout)
+        with phase("aggregate"):
+            if fed.aggregation == "fedavg":
+                # synchronous: waits for everyone whose upload can still
+                # land (stragglers included; crashed clients never arrive)
+                sync_active = uplinked & ~deviated
+                g_new = agg.fedavg_aggregate(
+                    g_flat, agg_rows, weights, sync_active,
+                    impl=fed.agg_impl, comms=comms, cohort=cohort,
+                )
+                round_time = jnp.max(jnp.where(uplinked, lat, 0.0))
+            elif fed.aggregation == "async":
+                g_new, pending = self._buffered_async(
+                    g_flat, deltas, weights, contributing, lat, pending,
+                    state.round_idx,
+                )
+                round_time = jnp.full((), fed.timeout)
+            elif fed.aggregation == "async_seq":
+                order = jnp.argsort(jnp.where(contributing, lat, jnp.inf))
+                g_new = agg.async_aggregate(
+                    g_flat, locals_flat, weights, contributing, order, fed,
+                    comms=comms,
+                )
+                round_time = jnp.full((), fed.timeout)
+            else:  # fedar (timeout skip)
+                g_new = agg.fedavg_aggregate(
+                    g_flat, agg_rows, weights, contributing,
+                    impl=fed.agg_impl, comms=comms, cohort=cohort,
+                )
+                round_time = jnp.full((), fed.timeout)
 
         # --- line 15 + Algorithm 1: trust and battery evolution
-        trust = update_trust(
-            state.trust,
-            fed,
-            selected=selected,
-            on_time=on_time,
-            deviated=deviated,
-            interested=ok,
-        )
-        resources = drain_battery(state.resources, selected)
+        with phase("trust"):
+            trust = update_trust(
+                state.trust,
+                fed,
+                selected=selected,
+                on_time=on_time,
+                deviated=deviated,
+                interested=ok,
+            )
+            resources = drain_battery(state.resources, selected)
 
         if eval_set is not None:
-            params_tree = unflatten(g_new, self.template)
-            loss, acc = self.model.metrics(params_tree, eval_set)
+            with phase("eval"):
+                params_tree = unflatten(g_new, self.template)
+                loss, acc = self.model.metrics(params_tree, eval_set)
         else:
             loss = acc = jnp.full((), jnp.nan)
 
@@ -954,6 +993,7 @@ class FedAREngine:
             round_time=round_time,
             loss=loss,
             acc=acc,
+            codec_rows_sent=codec_rows_sent,
         )
         return new_state, outputs
 
@@ -1169,12 +1209,22 @@ class FedAREngine:
                                 train_flops=self._train_flops(data))
 
     def step(self, state, data, *, eval_set=None, force_straggler=None):
-        """One jitted communication round -> (state, RoundOutputs)."""
-        self._prepare_call(data)
-        return self._step(
-            *self._placed(state, data, eval_set, force_straggler),
-            train_flops=self._train_flops(data),
-        )
+        """One jitted communication round -> (state, RoundOutputs).  Host
+        spans ``fedar.prepare`` and ``fedar.dispatch`` (stat ``compiles``:
+        the compilations the call triggered); the call returns before the
+        device finishes."""
+        with span("fedar.prepare"):
+            self._prepare_call(data)
+            args = self._placed(state, data, eval_set, force_straggler)
+            train_flops = self._train_flops(data)
+        with span("fedar.dispatch") as s:
+            traced = tracing.enabled()
+            if traced:
+                before = tracing.compile_count()
+            out = self._step(*args, train_flops=train_flops)
+            if traced:
+                s.set_metadata(compiles=tracing.compile_count() - before)
+        return out
 
     def run(self, state, data, *, rounds: int, eval_set=None,
             force_straggler=None):
@@ -1296,6 +1346,7 @@ class CohortEngine:
         self.dim = self.engine.dim
         self.mesh = self.engine.mesh
         self.compression = self.engine.compression
+        self.codec_rows = self.engine.codec_rows
         self.faults = self.engine.faults
         self.store = ClientStore(
             fed,
@@ -1320,44 +1371,50 @@ class CohortEngine:
         jit-boundary pytree is shaped by K alone (the memory-independence
         contract — N never appears in a device shape)."""
         r = int(self.store.round_idx)
-        idx, valid, elig = sample_cohort(
-            self.store.score,
-            self.store.resources_view(),
-            self.req,
-            self.fed,
-            cohort_size=self.fed.cohort_size,
-            round_idx=r,
-        )
-        data = jax.tree.map(jnp.asarray, fleet.cohort_arrays(idx, valid))
-        rows = self.store.gather(idx)
-        state = self._state0._replace(
-            params=jnp.asarray(self.params),
-            trust=TrustState(
-                jnp.asarray(rows["score"]),
-                jnp.asarray(rows["participations"]),
-                jnp.asarray(rows["failures"]),
-            ),
-            resources=ResourceState(
-                jnp.asarray(rows["memory"]),
-                jnp.asarray(rows["bandwidth"]),
-                jnp.asarray(rows["battery"]),
-                jnp.asarray(rows["compute"]),
-            ),
-            fg_history=jnp.asarray(rows["history"]),
-            compress_residual=jnp.asarray(rows["residual"]),
-            round_idx=jnp.asarray(r, jnp.int32),
-        )
-        if self.store.pending_dim:
-            # the cohort's in-flight async slots ride along; issue/arrival
-            # tags are absolute rounds, so an update whose client sat out a
-            # few rounds delivers (staleness-discounted) when it rejoins
-            state = state._replace(
-                pending_delta=jnp.asarray(rows["pending_delta"]),
-                pending_weight=jnp.asarray(rows["pending_weight"]),
-                pending_issued=jnp.asarray(rows["pending_issued"]),
-                pending_arrival=jnp.asarray(rows["pending_arrival"]),
-                pending_valid=jnp.asarray(rows["pending_valid"]),
+        with span("cohort.sample"):
+            idx, valid, elig = sample_cohort(
+                self.store.score,
+                self.store.resources_view(),
+                self.req,
+                self.fed,
+                cohort_size=self.fed.cohort_size,
+                round_idx=r,
             )
+        with span("cohort.arrays"):
+            arrays = fleet.cohort_arrays(idx, valid)
+        with span("cohort.gather"):
+            rows = self.store.gather(idx)
+        with span("cohort.h2d"):
+            data = jax.tree.map(jnp.asarray, arrays)
+            state = self._state0._replace(
+                params=jnp.asarray(self.params),
+                trust=TrustState(
+                    jnp.asarray(rows["score"]),
+                    jnp.asarray(rows["participations"]),
+                    jnp.asarray(rows["failures"]),
+                ),
+                resources=ResourceState(
+                    jnp.asarray(rows["memory"]),
+                    jnp.asarray(rows["bandwidth"]),
+                    jnp.asarray(rows["battery"]),
+                    jnp.asarray(rows["compute"]),
+                ),
+                fg_history=jnp.asarray(rows["history"]),
+                compress_residual=jnp.asarray(rows["residual"]),
+                round_idx=jnp.asarray(r, jnp.int32),
+            )
+            if self.store.pending_dim:
+                # the cohort's in-flight async slots ride along; issue/
+                # arrival tags are absolute rounds, so an update whose
+                # client sat out a few rounds delivers (staleness-
+                # discounted) when it rejoins
+                state = state._replace(
+                    pending_delta=jnp.asarray(rows["pending_delta"]),
+                    pending_weight=jnp.asarray(rows["pending_weight"]),
+                    pending_issued=jnp.asarray(rows["pending_issued"]),
+                    pending_arrival=jnp.asarray(rows["pending_arrival"]),
+                    pending_valid=jnp.asarray(rows["pending_valid"]),
+                )
         return state, data, idx, valid, elig
 
     def kernel_routes(self) -> dict:
@@ -1376,28 +1433,35 @@ class CohortEngine:
         cohort-indexed (row j belongs to fleet client ``idx[j]`` where
         ``valid[j]``)."""
         state, data, idx, valid, elig = self._build_round_inputs(fleet)
-        state2, out = self.engine.step(state, data, eval_set=eval_set)
+        with span("cohort.step"):
+            state2, out = self.engine.step(state, data, eval_set=eval_set)
         self.params = state2.params
-        self.store.scatter_round(
-            idx,
-            valid,
-            trust=TrustState(
-                np.asarray(state2.trust.score),
-                np.asarray(state2.trust.participations),
-                np.asarray(state2.trust.failures),
-            ),
-            battery=np.asarray(state2.resources.battery),
-            history=np.asarray(state2.fg_history),
-            residual=np.asarray(state2.compress_residual),
-            pending=None if not self.store.pending_dim else dict(
-                pending_delta=np.asarray(state2.pending_delta),
-                pending_weight=np.asarray(state2.pending_weight),
-                pending_issued=np.asarray(state2.pending_issued),
-                pending_arrival=np.asarray(state2.pending_arrival),
-                pending_valid=np.asarray(state2.pending_valid),
-            ),
-        )
-        self.store.finish_round(idx, valid, elig)
+        with span("fedar.wait"):
+            jax.block_until_ready((state2, out))
+        with span("cohort.scatter") as s:
+            back = dict(
+                trust=TrustState(
+                    np.asarray(state2.trust.score),
+                    np.asarray(state2.trust.participations),
+                    np.asarray(state2.trust.failures),
+                ),
+                battery=np.asarray(state2.resources.battery),
+                history=np.asarray(state2.fg_history),
+                residual=np.asarray(state2.compress_residual),
+                pending=None if not self.store.pending_dim else dict(
+                    pending_delta=np.asarray(state2.pending_delta),
+                    pending_weight=np.asarray(state2.pending_weight),
+                    pending_issued=np.asarray(state2.pending_issued),
+                    pending_arrival=np.asarray(state2.pending_arrival),
+                    pending_valid=np.asarray(state2.pending_valid),
+                ),
+            )
+            if tracing.enabled():
+                s.set_metadata(bytes=sum(
+                    a.nbytes for a in jax.tree.leaves(back)))
+            self.store.scatter_round(idx, valid, **back)
+        with span("cohort.finish"):
+            self.store.finish_round(idx, valid, elig)
         return idx, valid, out
 
     def run(self, fleet, *, rounds: int, eval_set=None):

@@ -21,10 +21,13 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Any, Dict, List
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import tracing
 from repro.common.config import FedConfig
+from repro.common.tracing import span
 from repro.core.engine import (
     CohortEngine,
     FedAREngine,
@@ -35,6 +38,10 @@ from repro.core.engine import (
 from repro.core.resources import TaskRequirement
 
 __all__ = ["FedARServer", "flatten", "unflatten"]
+
+# the per-round outputs the history keeps
+_HISTORY_FIELDS = ("trust", "selected", "on_time", "round_time", "loss",
+                   "acc")
 
 
 @dataclass
@@ -120,15 +127,43 @@ class FedARServer:
         return int(self.state.round_idx)
 
     # ------------------------------------------------------------------
-    def _append(self, out: RoundOutputs, rounds: int, with_eval: bool):
-        """Host bookkeeping: fold stacked (or single-round) outputs into the
-        seed-format history dict."""
-        trust = np.atleast_2d(np.asarray(out.trust))
-        selected = np.atleast_2d(np.asarray(out.selected))
-        on_time = np.atleast_2d(np.asarray(out.on_time))
-        round_time = np.reshape(np.asarray(out.round_time), (rounds,))
-        loss = np.reshape(np.asarray(out.loss), (rounds,))
-        acc = np.reshape(np.asarray(out.acc), (rounds,))
+    def _fetch(self, out: RoundOutputs, rounds: int) -> dict:
+        """The history's outputs copied to the host, each distinct array
+        once, under the ``fedar.fetch`` span (stats ``copies`` and
+        ``bytes``).  With a trace running and the codec on, the codec's
+        count rides along: stats ``codec_rows_sent`` and
+        ``codec_rows_encoded``, the rows the codec transmitted and the rows
+        it encoded."""
+        names = list(_HISTORY_FIELDS)
+        traced = tracing.enabled()
+        codec = traced and self.engine.codec_rows > 0
+        if codec:
+            names.append("codec_rows_sent")
+        arrays = {n: getattr(out, n) for n in names}
+        distinct = {id(a): a for a in arrays.values()}
+        with span("fedar.fetch") as s:
+            copied = {k: np.asarray(a) for k, a in distinct.items()}
+            host = {n: copied[id(a)] for n, a in arrays.items()}
+            if traced:
+                stats = {"copies": len(distinct),
+                         "bytes": sum(a.nbytes for a in distinct.values())}
+                if codec:
+                    stats["codec_rows_sent"] = int(
+                        host.pop("codec_rows_sent").sum())
+                    stats["codec_rows_encoded"] = (self.engine.codec_rows
+                                                   * rounds)
+                s.set_metadata(**stats)
+        return host
+
+    def _append(self, host: dict, rounds: int, with_eval: bool):
+        """Host bookkeeping: fold stacked (or single-round) host outputs
+        into the seed-format history dict."""
+        trust = np.atleast_2d(host["trust"])
+        selected = np.atleast_2d(host["selected"])
+        on_time = np.atleast_2d(host["on_time"])
+        round_time = np.reshape(host["round_time"], (rounds,))
+        loss = np.reshape(host["loss"], (rounds,))
+        acc = np.reshape(host["acc"], (rounds,))
         for r in range(rounds):
             self.history["trust"].append(trust[r])
             self.history["selected"].append(selected[r])
@@ -153,26 +188,40 @@ class FedARServer:
         """One communication round (one jitted dispatch + host sync).
         ``data``: dict with stacked per-client arrays x (N, n, 784), y (N, n),
         sizes (N,), activations (N,) int32 (0=relu, 1=softmax, Table II) —
-        or, in cohort mode, a fleet object exposing ``cohort_arrays``."""
-        if self.cohort_mode:
-            if force_straggler is not None:
-                raise ValueError(
-                    "force_straggler is a resident-engine test hook; the "
-                    "cohort engine has no stable client axis to force"
+        or, in cohort mode, a fleet object exposing ``cohort_arrays``.
+
+        The round is the host span ``fedar.round``; its children are the
+        engine's spans, ``fedar.wait`` (the device finishing the round),
+        ``fedar.fetch`` and ``fedar.history``."""
+        with span("fedar.round"):
+            if self.cohort_mode:
+                if force_straggler is not None:
+                    raise ValueError(
+                        "force_straggler is a resident-engine test hook; "
+                        "the cohort engine has no stable client axis to "
+                        "force"
+                    )
+                idx, valid, out = self.engine.run_round(data,
+                                                        eval_set=eval_set)
+            else:
+                with span("fedar.prepare"):
+                    data = self._resident_data(data)
+                    force = (None if force_straggler is None
+                             else jnp.asarray(force_straggler))
+                self.state, out = self.engine.step(
+                    self.state, data, eval_set=eval_set,
+                    force_straggler=force,
                 )
-            idx, valid, out = self.engine.run_round(data, eval_set=eval_set)
-            self._append(out, 1, eval_set is not None)
-            self.history["cohort"].append(
-                (np.asarray(idx), np.asarray(valid))
-            )
-            return np.asarray(out.selected), np.asarray(out.on_time)
-        data = self._resident_data(data)
-        force = None if force_straggler is None else jnp.asarray(force_straggler)
-        self.state, out = self.engine.step(
-            self.state, data, eval_set=eval_set, force_straggler=force
-        )
-        self._append(out, 1, eval_set is not None)
-        return np.asarray(out.selected), np.asarray(out.on_time)
+                with span("fedar.wait"):
+                    jax.block_until_ready(out)
+            host = self._fetch(out, 1)
+            with span("fedar.history"):
+                self._append(host, 1, eval_set is not None)
+                if self.cohort_mode:
+                    self.history["cohort"].append(
+                        (np.asarray(idx), np.asarray(valid))
+                    )
+            return host["selected"], host["on_time"]
 
     def run(self, data, rounds: int, eval_set=None, force_straggler=None,
             driver: str = "scan"):
@@ -204,5 +253,6 @@ class FedARServer:
             self.state, data, rounds=rounds, eval_set=eval_set,
             force_straggler=force,
         )
-        self._append(outs, rounds, eval_set is not None)
+        self._append(self._fetch(outs, rounds), rounds,
+                     eval_set is not None)
         return self.history
