@@ -260,8 +260,9 @@ class FedConfig:
     compress: str = "none"
     compress_bits: int = 8  # qsgd levels = 2^(bits-1) - 1; 4 or 8
     compress_k: Optional[int] = None  # topk coordinates kept; None -> D // 32
-    # pack/unpack backend: auto (Pallas kernel on TPU, einsum elsewhere) |
-    # kernel | einsum — mirrors ``agg_impl``/``defense_impl``
+    # qsgd's pack/unpack backend: auto (Pallas kernel on TPU, einsum
+    # elsewhere) | kernel | einsum — mirrors ``agg_impl``/``defense_impl``;
+    # top-k runs no kernel
     compress_impl: str = "auto"
     # --- fault injection + quarantine (core/faults.py) ---
     # faults: named deterministic fault schedule, keyed on (seed, round,

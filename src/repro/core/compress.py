@@ -18,6 +18,9 @@ and the encode/decode pair applied at the client->aggregator boundary:
   ``topk`` -- magnitude top-``compress_k`` sparsification: the k largest-
               |v| coordinates ship as (value, index) pairs — 8*k bytes per
               client.  Biased, so error feedback is what makes it sound.
+              The round decodes by a kept mask over the encode's input
+              (``kept_topk``), never scattering the pairs;
+              ``decode(payload)`` is the decoder of a received payload.
 
 Error feedback (EF-SGD): each client compresses ``delta + residual`` and
 carries ``residual' = (delta + residual) - decode(payload)`` to the next
@@ -49,10 +52,10 @@ import jax.numpy as jnp
 
 from repro.common.config import FedConfig
 from repro.common.tracing import phase
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 __all__ = ["CompressionStrategy", "NoCompression", "QSGDCompression",
-           "TopKCompression", "make_compression"]
+           "TopKCompression", "kept_topk", "make_compression"]
 
 
 class CompressionStrategy:
@@ -173,7 +176,9 @@ class TopKCompression(CompressionStrategy):
     """Magnitude top-``compress_k``: ship the k largest-|v| coordinates as
     (value, index) pairs.  ``k == D`` is an exact identity; ``k`` defaults
     to ``D // 32`` when ``FedConfig.compress_k`` is unset.  Biased — the
-    engine's error feedback carries what was dropped into the next round."""
+    engine's error feedback carries what was dropped into the next round.
+    The round decodes by the kept mask (``kept_topk``) and runs no kernel,
+    so ``compress_impl`` does not apply."""
 
     name = "topk"
     active = True
@@ -189,7 +194,6 @@ class TopKCompression(CompressionStrategy):
                 f"1 <= k <= D (k == D is the exact-identity degenerate case)"
             )
         self.k = int(k)
-        self.impl = fed.compress_impl
 
     def residual_dim(self, model_dim: int) -> int:
         return model_dim
@@ -197,17 +201,56 @@ class TopKCompression(CompressionStrategy):
     def payload_nbytes(self, model_dim: int) -> int:
         return 8 * self.k  # fp32 value + int32 index per kept coordinate
 
-    def encode(self, deltas, residual, keys):
+    def _encode(self, deltas, residual):
+        """``v = deltas + residual``, its payload — ``lax.top_k``'s
+        (value, index) pairs by magnitude, ties to the lower index — and
+        the decoded rows, taken from ``v`` by the kept mask."""
         v = (deltas + residual).astype(jnp.float32)
         _, idx = jax.lax.top_k(jnp.abs(v), self.k)
         vals = jnp.take_along_axis(v, idx, axis=-1)
         payload = {"vals": vals, "idx": idx.astype(jnp.int32)}
-        return payload, v - self.decode(payload, v.shape[-1])
+        return payload, v, kept_topk(v, payload["idx"][:, -1:])
+
+    def encode(self, deltas, residual, keys):
+        payload, v, dec = self._encode(deltas, residual)
+        return payload, v - dec
 
     def decode(self, payload, model_dim: int):
-        use_pallas = ops.resolve_impl(self.impl, "compress") == "kernel"
-        return ops.topk_decode(payload["vals"], payload["idx"], model_dim,
-                               use_pallas=use_pallas)
+        """The decoder of a received payload: scatter-add the pairs into
+        zero rows.  The round itself never scatters (``roundtrip``)."""
+        return ref.topk_decode_ref(payload["vals"], payload["idx"],
+                                   model_dim)
+
+    def roundtrip(self, deltas, residual, transmit, keys):
+        """``CompressionStrategy.roundtrip`` with no scatter: client and
+        server run in one program, so the decoded rows are the encode's
+        own kept rows.  Bit-identical to decoding the payload, and the
+        payload is still built for the wire's accounting.  The scopes
+        split as for every codec: the encode and the error feedback under
+        ``codec.encode``, the masked decoded rows under ``codec.decode``."""
+        m = transmit[:, None]
+        with phase("codec.encode"):
+            payload, v, dec = self._encode(deltas, residual)
+            res = jnp.where(m, v - dec, residual)
+        with phase("codec.decode"):
+            dec = jnp.where(m, dec, 0.0)
+        return dec, res, payload
+
+
+def kept_topk(v, last):
+    """``v`` with every coordinate outside its top-k set replaced by +0.0,
+    given ``last``, the ``(n, 1)`` index of the k-th largest ``|v|`` that
+    ``lax.top_k`` returned.  The int32 view of a non-negative float orders
+    exactly as the float total order does (NaN above +inf), and ``top_k``
+    breaks ties toward the lower index, so the kept set is the keys above
+    the k-th key plus the k-th key's ties at or before ``last``."""
+    key = jax.lax.bitcast_convert_type(jnp.abs(v), jnp.int32)
+    thr = jnp.take_along_axis(key, last, axis=-1)
+    cols = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    kept = (key > thr) | ((key == thr) & (cols <= last))
+    # a kept zero decodes to +0.0 as 0.0 + (-0.0) does; XLA would fold a
+    # trailing ``+ 0.0`` away, so the zero test does it
+    return jnp.where(kept & (v != 0.0), v, 0.0)
 
 
 _STRATEGIES = {

@@ -1189,7 +1189,8 @@ class FedAREngine:
         """The backend each hot op takes: ``sgd`` is the decided
         ``sgd_route`` (None until ``step``/``run`` has seen data); ``agg``,
         ``defense`` and ``compress`` are ``"kernel"`` (Pallas) or
-        ``"einsum"`` (XLA), or ``"none"`` where the subsystem is off."""
+        ``"einsum"`` (XLA), or ``"none"`` where the subsystem is off; a
+        top-k ``compress`` is ``"mask"`` (the kept mask, no kernel)."""
         fed = self.fed
         return {
             "sgd": self.sgd_route,
@@ -1197,8 +1198,9 @@ class FedAREngine:
                     else resolve_impl(fed.agg_impl, "agg")),
             "defense": ("none" if self.defense.name == "none"
                         else resolve_impl(fed.defense_impl, "defense")),
-            "compress": (resolve_impl(fed.compress_impl, "compress")
-                         if self.compression.active else "none"),
+            "compress": ("none" if not self.compression.active
+                         else "mask" if self.compression.name == "topk"
+                         else resolve_impl(fed.compress_impl, "compress")),
         }
 
     def lower_step(self, state, data, *, eval_set=None):

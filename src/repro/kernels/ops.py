@@ -15,7 +15,6 @@ import jax
 
 from repro.kernels import ref
 from repro.kernels.compress import pack_codes as _pack_codes_kernel
-from repro.kernels.compress import topk_decode as _topk_decode_kernel
 from repro.kernels.compress import unpack_codes as _unpack_codes_kernel
 from repro.kernels.fedavg_agg import fedavg_agg as _fedavg_agg_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
@@ -80,15 +79,6 @@ def unpack_codes(packed, *, bits: int, dim: int, use_pallas: bool = True,
         return ref.unpack_codes_ref(packed, bits=bits, dim=dim)
     itp = interpret_mode() if interpret is None else interpret
     return _unpack_codes_kernel(packed, bits=bits, dim=dim, interpret=itp)
-
-
-def topk_decode(vals, idx, dim: int, *, use_pallas: bool = True,
-                interpret: bool | None = None):
-    """Sparse top-k (vals, idx) -> dense (N, dim) float32 scatter-add."""
-    if not use_pallas:
-        return ref.topk_decode_ref(vals, idx, dim)
-    itp = interpret_mode() if interpret is None else interpret
-    return _topk_decode_kernel(vals, idx, dim, interpret=itp)
 
 
 def local_sgd(w1, b1, w2, b2, x, y, act, mask, *, lr: float, batch_size: int,

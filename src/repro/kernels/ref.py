@@ -79,7 +79,8 @@ def unpack_codes_ref(packed, *, bits: int, dim: int):
 
 def topk_decode_ref(vals, idx, dim: int):
     """Sparse (n, k) value/index pairs -> dense (n, dim) float32 via
-    scatter-ADD (duplicate indices accumulate, matching the kernel)."""
+    scatter-ADD (duplicate indices accumulate): ``TopKCompression.decode``
+    of a received payload."""
     n, k = vals.shape
     if k == 0:
         return jnp.zeros((n, dim), jnp.float32)

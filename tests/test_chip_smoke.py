@@ -62,6 +62,17 @@ def _final_line_printed(proc) -> bool:
         return False
 
 
+@pytest.mark.parametrize("route,ok", [("kernel", True), ("mask", True),
+                                      ("none", True), ("einsum", False)])
+def test_route_check_takes_the_top_k_mask(route, ok):
+    """A phase passes its route check with the codec on a kernel, on
+    top-k's kept mask or off, and fails it on the XLA path."""
+    line = {"routes": {"sgd": "fused_ragged", "agg": "kernel",
+                       "defense": "kernel", "compress": route},
+            "kernels_in_program": 2}
+    assert _chip_smoke()._all_kernel(line) is ok
+
+
 def test_script_exits_nonzero_on_cpu():
     proc = _run_script(ROOT)
     assert proc.returncode != 0

@@ -166,6 +166,141 @@ def test_non_transmitting_rows_keep_residual_and_send_zero():
     np.testing.assert_array_equal(np.asarray(res)[3], np.asarray(res0)[3])
 
 
+# ------------------------------------------ top-k kept mask, bit for bit
+
+_INF = np.float32(np.inf)
+_NAN = np.float32(np.nan)
+_NEG_NAN = np.array(0xFFC00000, np.uint32).view(np.float32)
+SPECIALS = np.array([0.0, -0.0, 0.5, -0.5, 1.0, -1.0, 2.0, -2.0,
+                     _INF, -_INF, _NAN, _NEG_NAN], np.float32)
+
+
+def _scatter_roundtrip(k, deltas, residual, transmit):
+    """The top-k roundtrip decoded from its payload: ``lax.top_k``'s
+    pairs, scatter-added into zeros (``ref.topk_decode_ref``), ``v - dec``,
+    then the transmit masks of ``CompressionStrategy.roundtrip``.  Also
+    returns the unmasked residual ``v - dec`` that ``encode`` gives."""
+    from repro.kernels.ref import topk_decode_ref
+
+    m = transmit[:, None]
+    v = (deltas + residual).astype(jnp.float32)
+    _, idx = jax.lax.top_k(jnp.abs(v), k)
+    payload = {"vals": jnp.take_along_axis(v, idx, axis=-1), "idx": idx}
+    dec = topk_decode_ref(payload["vals"], idx, v.shape[-1])
+    return ((jnp.where(m, dec, 0.0), jnp.where(m, v - dec, residual),
+             payload), v - dec)
+
+
+def _assert_bitwise(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g).view(np.int32),
+                                      np.asarray(w).view(np.int32))
+
+
+def _assert_kept_mask_bitwise(deltas, residual, transmit, k):
+    """The jitted roundtrip (as the round runs it) and encode against the
+    scatter decode: decoded rows, residuals and payload equal as int32
+    views, so signed zeros and NaN bits count."""
+    deltas = jnp.asarray(deltas, jnp.float32)
+    residual = jnp.asarray(residual, jnp.float32)
+    transmit = jnp.asarray(transmit, bool)
+    c = _strategy("topk", dim=deltas.shape[-1], compress_k=k)
+    want, want_res = _scatter_roundtrip(k, deltas, residual, transmit)
+    _assert_bitwise(jax.jit(c.roundtrip)(deltas, residual, transmit, None),
+                    want)
+    payload, res = jax.jit(c.encode)(deltas, residual, None)
+    _assert_bitwise((payload, res), (want[2], want_res))
+
+
+_MZ = -0.0
+KEPT_MASK_CASES = {
+    # three 2s straddle k = 3: the lower-index ties are kept
+    "ties_straddle_k": ([[0.5, 2.0, 1.0, 2.0, 2.0, 2.0, 0.5, 3.0]], 3),
+    # magnitude ties of opposite sign at the k-th place
+    "opposite_sign_ties": ([[-2.0, 2.0, -2.0, 2.0, 1.0, -1.0, 1.0, -1.0]], 3),
+    # every coordinate ties; the first k are kept
+    "all_equal": ([[1.5] * 8, [-1.5] * 8], 4),
+    # kept and dropped zeros of both signs
+    "signed_zeros": ([[_MZ, 0.0, _MZ, 1.0, _MZ, 0.0, -1.0, _MZ],
+                      [_MZ] * 8], 6),
+    # infinities rank above every finite value, NaN above infinity
+    "inf_and_nan": ([[1.0, _INF, -2.0, -_INF, _NAN, 3.0, _NEG_NAN, 0.5],
+                     [_NAN, _NEG_NAN, _NAN, 1.0, _INF, -_INF, 0.0, _MZ]], 3),
+    "all_zero": ([[0.0] * 8, [_MZ] * 8], 2),
+    "k_is_1": ([[0.5, -3.0, 3.0, 1.0, _MZ, -_INF, 2.0, 0.0]], 1),
+    "k_is_D": ([[0.5, -3.0, 3.0, _MZ, _NAN, -_INF, 2.0, 0.0]], 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEPT_MASK_CASES))
+@pytest.mark.parametrize("residual", ["minus_zero", "drawn"])
+def test_topk_kept_mask_matches_scatter_decode_bitwise(case, residual):
+    """Fixed rows: ties at the k-th magnitude, +-0, +-inf, +-NaN, all-zero
+    rows, k = 1 and k = D; the first row transmits, later ones do not."""
+    rows, k = KEPT_MASK_CASES[case]
+    deltas = np.asarray(rows, np.float32)
+    n, d = deltas.shape
+    if residual == "minus_zero":  # v == deltas, bit for bit
+        res = np.full((n, d), _MZ, np.float32)
+    else:
+        rng = np.random.default_rng(sorted(KEPT_MASK_CASES).index(case))
+        res = rng.choice(SPECIALS, size=(n, d))
+    transmit = np.arange(n) == 0
+    _assert_kept_mask_bitwise(deltas, res, transmit, k)
+    if n > 1:
+        _assert_kept_mask_bitwise(deltas, res, np.ones(n, bool), k)
+
+
+@settings(max_examples=25, deadline=None)
+@given(
+    n=st.integers(1, 5),
+    d=st.integers(1, 24),
+    k_frac=st.floats(0.0, 1.0),
+    seed=st.integers(0, 2**31 - 1),
+)
+def test_topk_kept_mask_matches_scatter_decode_drawn(n, d, k_frac, seed):
+    """Drawn rows of special values (heavy exact ties, +-0, +-inf, +-NaN),
+    a drawn residual and a drawn transmit mask."""
+    rng = np.random.default_rng(seed)
+    k = 1 + int(k_frac * (d - 1))
+    _assert_kept_mask_bitwise(rng.choice(SPECIALS, size=(n, d)),
+                              rng.choice(SPECIALS, size=(n, d)),
+                              rng.random(n) < 0.7, k)
+
+
+@pytest.mark.parametrize("N,k,D", [(12, 795, 25450), (3, 1, 97), (1, 8, 8),
+                                   (5, 16, 1000)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_topk_kept_mask_matches_scatter_decode_at_width(N, k, D, dtype):
+    """Wide rows of normal deltas; bfloat16 deltas tie often at every
+    magnitude, the k-th included."""
+    key = jax.random.PRNGKey(N * 7 + k)
+    deltas = jax.random.normal(key, (N, D), dtype)
+    residual = jax.random.normal(jax.random.fold_in(key, 1), (N, D), dtype)
+    _assert_kept_mask_bitwise(deltas, residual, np.arange(N) % 3 != 1, k)
+
+
+def test_topk_decode_received_payload_accumulates_duplicates():
+    """``decode`` of a received payload scatter-ADDs: a repeated index
+    sums its values, and zero-valued pairs decode to exact zeros."""
+    c = _strategy("topk", dim=8, compress_k=3)
+    got = c.decode({"vals": jnp.array([[1.0, 2.0, 3.0], [0.0, 0.0, 0.0]]),
+                    "idx": jnp.array([[5, 5, 2], [1, 4, 7]], jnp.int32)}, 8)
+    want = np.zeros((2, 8), np.float32)
+    want[0, 5], want[0, 2] = 3.0, 3.0
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_topk_decode_received_payload_inverts_encode():
+    """A received payload decodes to the rows the round kept."""
+    c = _strategy("topk", compress_k=7)
+    deltas, residual = _rows(3, 4), _rows(4, 4, scale=0.1)
+    payload, _ = c.encode(deltas, residual, None)
+    dec, _, _ = c.roundtrip(deltas, residual, jnp.ones(4, bool), None)
+    np.testing.assert_array_equal(np.asarray(c.decode(payload, D)),
+                                  np.asarray(dec))
+
+
 # ------------------------------------------------------------- determinism
 
 @pytest.mark.parametrize(
@@ -265,3 +400,22 @@ def test_engine_runs_buffered_async_with_compression():
     assert np.isfinite(np.asarray(state.compress_residual)).all()
     # the model actually moved — compression didn't zero the uplink
     assert float(jnp.abs(state.params - eng.init_state().params).sum()) > 0
+
+
+@pytest.mark.parametrize("compress,impl,route", [
+    ("none", "kernel", "none"),
+    ("topk", "auto", "mask"),
+    ("topk", "kernel", "mask"),
+    ("qsgd", "kernel", "kernel"),
+    ("qsgd", "einsum", "einsum"),
+])
+def test_kernel_routes_name_the_codec_path(compress, impl, route):
+    """``kernel_routes`` reports what the round runs: top-k decodes by the
+    kept mask whatever ``compress_impl`` says; qsgd takes the knob."""
+    from repro.configs.fedar_mnist import fleet_fed, small_model
+    from repro.core.engine import FedAREngine
+    from repro.core.resources import TaskRequirement
+
+    fed = fleet_fed(6, compress=compress, compress_impl=impl, defense="none")
+    eng = FedAREngine(small_model(8), fed, TaskRequirement())
+    assert eng.kernel_routes()["compress"] == route

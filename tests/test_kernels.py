@@ -7,7 +7,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.compress import pack_codes, topk_decode, unpack_codes
+from repro.kernels.compress import pack_codes, unpack_codes
 from repro.kernels.defense_sim import sketch_similarity
 from repro.kernels.fedavg_agg import fedavg_agg
 from repro.kernels.flash_attention import flash_attention
@@ -411,7 +411,7 @@ def test_ssm_decay_zero_state_passthrough():
 
 
 # ---------------------------------------------------------------------------
-# compression pack / unpack / topk_decode (kernels/compress.py)
+# compression pack / unpack (kernels/compress.py)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("bits", [4, 8])
@@ -446,39 +446,3 @@ def test_pack_small_block_padded_tail():
     np.testing.assert_array_equal(
         np.asarray(got), np.asarray(ref.pack_codes_ref(codes, bits=4))
     )
-
-
-@pytest.mark.parametrize("N,k,D", [(12, 795, 25450), (3, 1, 97), (1, 8, 8),
-                                   (5, 16, 1000)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_topk_decode_matches_ref(N, k, D, dtype):
-    key = jax.random.PRNGKey(N * 7 + k)
-    vals = jax.random.normal(key, (N, k), dtype)
-    idx = jax.random.randint(jax.random.fold_in(key, 1), (N, k), 0, D)
-    got = topk_decode(vals, idx, D, interpret=True)
-    want = ref.topk_decode_ref(vals, idx, D)
-    tol = 0 if dtype == jnp.float32 else 1e-2
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
-
-
-def test_topk_decode_duplicate_indices_accumulate():
-    """Duplicate indices scatter-ADD in both the kernel and the oracle (the
-    property that keeps them bit-equal when top_k ties repeat an index)."""
-    vals = jnp.array([[1.0, 2.0, 3.0]])
-    idx = jnp.array([[5, 5, 2]])
-    got = topk_decode(vals, idx, 8, interpret=True)
-    want = ref.topk_decode_ref(vals, idx, 8)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert np.asarray(got)[0, 5] == 3.0 and np.asarray(got)[0, 2] == 3.0
-
-
-def test_topk_decode_degenerate_k0_and_masked_rows():
-    """k=0 short-circuits to zeros; an all-masked client row (vals zeroed
-    upstream by the transmit mask) decodes to exact zeros."""
-    z = topk_decode(jnp.zeros((3, 0)), jnp.zeros((3, 0), jnp.int32), 64,
-                    interpret=True)
-    np.testing.assert_array_equal(np.asarray(z), np.zeros((3, 64)))
-    vals = jnp.zeros((2, 5))
-    idx = jax.random.randint(jax.random.PRNGKey(2), (2, 5), 0, 64)
-    out = topk_decode(vals, idx, 64, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), np.zeros((2, 64)))

@@ -13,13 +13,14 @@ module is imported: only one process at a time may load the TPU compiler
 library, and under pytest-xdist only the worker that runs this file does.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.compress import pack_codes, topk_decode, unpack_codes
+from repro.kernels.compress import pack_codes, unpack_codes
 from repro.kernels.defense_sim import sketch_similarity
 from repro.kernels.fedavg_agg import fedavg_agg
 from repro.kernels.local_sgd import (
@@ -149,11 +150,63 @@ def test_pack_unpack_codes_4bit_compile(spec):
     assert "tpu_custom_call" in text
 
 
+def _row_sorts(text, rows):
+    """The ``(values, indices)`` sorts over whole ``(rows, D)`` rows."""
+    return re.findall(
+        rf"= \(f32\[{rows},{D}\]\S* s32\[{rows},{D}\]\S*\) sort\(", text
+    )
+
+
 @pytest.mark.parametrize("rows", [K, N_RESIDENT])
-def test_topk_decode_compiles(spec, rows):
-    """The default top-k (D // 32 kept coordinates) at cohort and resident
-    fleet scale."""
-    k = D // 32
-    text = _compile(lambda v, i: topk_decode(v, i, D),
-                    spec((rows, k)), spec((rows, k), i32))
-    assert "tpu_custom_call" in text
+def test_topk_codec_compiles_without_kernel(spec, rows):
+    """The default top-k codec (D // 32 kept coordinates) at cohort and
+    resident fleet scale: one sort over the rows and the kept mask, with
+    no kernel and no scatter."""
+    import dataclasses
+
+    from repro.common.config import FedConfig
+    from repro.core.compress import make_compression
+
+    fed = dataclasses.replace(FedConfig(), compress="topk", defense="none")
+    codec = make_compression(fed, D)
+    text = jax.jit(codec.roundtrip).lower(
+        spec((rows, D)), spec((rows, D)), spec((rows,), jnp.bool_), None
+    ).compile().as_text()
+    assert "tpu_custom_call" not in text and " scatter(" not in text
+    assert len(_row_sorts(text, rows)) == 1
+
+
+def test_topk_round_decodes_by_kept_mask(spec, monkeypatch):
+    """A top-k round of 32 published-width clients compiled for the chip
+    decodes its uplink by the kept mask: no ``topk_decode`` kernel, and
+    the encode's ``top_k`` is the one sort over ``(rows, D)``."""
+    import numpy as np
+
+    import repro.core.aggregation
+    import repro.core.foolsgold
+    import repro.models.mnist
+    from repro.configs.fedar_mnist import CONFIG, fleet_fed
+    from repro.core.engine import FedAREngine
+    from repro.core.resources import TaskRequirement
+    from repro.data.federated import scaled_fleet
+    from repro.kernels import ops
+
+    # the backend here is the CPU; the round is traced as the chip runs it
+    for mod in (ops, repro.models.mnist, repro.core.foolsgold,
+                repro.core.aggregation):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    rows = 32
+    fed = fleet_fed(rows, local_epochs=1, compress="topk", defense="none")
+    engine = FedAREngine(CONFIG, fed, TaskRequirement())
+    assert engine.dim == D
+    data = scaled_fleet(rows, samples_per_client=40)
+
+    def described(a):
+        return spec(np.shape(a), jnp.asarray(a).dtype)
+
+    lowered = engine.lower_step(jax.tree.map(described, engine.init_state()),
+                                jax.tree.map(described, data))
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and "codec.decode" in text
+    assert "topk_decode" not in text
+    assert len(_row_sorts(text, rows)) == 1
