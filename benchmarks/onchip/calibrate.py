@@ -19,9 +19,6 @@ import json
 import sys
 from pathlib import Path
 
-import numpy as np
-
-import fleetgen
 import harness
 
 FAULTS = ("half_clients", "client_flipped")
@@ -30,9 +27,7 @@ PRECISIONS = ("fp8", "bfloat16")
 
 def readings(cell, seed, kinds):
     spec, config = cell["spec"], cell["system"]
-    fleet = fleetgen.make_fleet(cell["traffic"], seed)
-    weights = fleetgen.init_weights(seed, spec["model"])
-    weights0 = {k: np.asarray(v) for k, v in weights.items()}
+    fleet, weights, weights0 = harness.draw(cell, seed)
     system = config.build(spec, fleet, weights)
     prog = system.checked(harness.CHECKED_ROUNDS)
     system.close()

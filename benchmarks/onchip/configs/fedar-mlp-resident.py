@@ -1,9 +1,18 @@
 """The system under test and its reference, for ``fedar-mlp-resident``.
 
 The harness finds this file beside the configuration's ``.json`` and uses
-only what follows, so a configuration that runs another engine, another
-aggregation or another codec brings a file of its own:
+only what follows, so a configuration of another client family, engine,
+aggregation or codec brings a file of its own:
 
+* ``make_fleet(traffic, seed, spec)``: the fleet, drawn from the traffic
+  file and the seed (``spec`` gives the sizes a sample needs, such as a
+  vocabulary).  The harness reads two things of it, ``sizes`` (each
+  client's samples; for a language model, its sequences) and
+  ``num_clients``; the rest is for ``build`` and ``reference``;
+* ``init_weights(seed, spec)``: the initial global model, any pytree of
+  arrays, drawn from the seed;
+* ``counts(spec)``: the operation and byte counts its per-layer readers
+  take (``counting.Counts``);
 * ``build(spec, fleet, weights)``: the program built for the cell, an
   object with ``round()`` -- the one call the window times; it returns the
   mask of clients whose samples went through local SGD --, ``checked(n)``
@@ -15,14 +24,39 @@ aggregation or another codec brings a file of its own:
   (``fedref.Reference``);
 * ``compare(prog, ref, weights0)``: the numbers ``correct`` compares.
 
-Here the program is ``FedARServer`` over the resident ``FedAREngine``:
-every client's data and state on the device, one ``run_round`` a round.
+Its ``.json`` may name ``phases`` (program phase scopes) and ``layers``
+(layer -> op-name patterns) of its own, beside ``scopes.json``'s and
+``layers.json``'s; this one has none.
+
+Here the clients are the paper's MLP (``fleetgen``'s class-prototype
+samples, He-initialised weights, ``counting``'s MLP counts) and the
+program is ``FedARServer`` over the resident ``FedAREngine``: every
+client's data and state on the device, one ``run_round`` a round.
 """
+import functools
+
 import numpy as np
 
+import counting
 import fedref
+import fleetgen
 
 compare = fedref.compare
+
+
+def make_fleet(traffic: dict, seed: int, spec: dict):
+    return fleetgen.make_fleet(traffic, seed)
+
+
+def init_weights(seed: int, spec: dict):
+    return fleetgen.init_weights(seed, spec["model"])
+
+
+def counts(spec: dict) -> counting.Counts:
+    model = spec["model"]
+    return counting.Counts(
+        counting.flops_per_sample_epoch(model),
+        functools.partial(counting.local_sgd_work, model=model))
 
 
 class Resident:

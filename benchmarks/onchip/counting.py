@@ -2,11 +2,13 @@
 
 Kept with the benchmark so that no change to the program can move them:
 the real work of a round, the operations and bytes the local-SGD
-algorithm needs for it, and the published peaks of each chip.
+algorithm needs for it, and the published peaks of each chip.  A
+configuration gives its readers its own counts (``Counts``); the MLP's are
+here.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
@@ -28,6 +30,18 @@ PEAKS = {
         source='Google Cloud documentation, "TPU v5e"',
     ),
 }
+
+
+class Counts(NamedTuple):
+    """A configuration's operation and byte counts, as its
+    ``counts(spec)`` gives them to the per-layer readers."""
+
+    # one sample (for a language model, one sequence) through one epoch
+    # of local SGD
+    flops_per_sample_epoch: float
+    # ``(clients, sample_epochs) -> (flops, bytes)`` of the fused
+    # local-SGD kernel, for a configuration that runs it
+    local_sgd_work: Optional[Callable[[int, int], tuple]] = None
 
 
 def chip_peaks(device_kind: str) -> ChipPeaks:

@@ -5,7 +5,10 @@ turns it into arrays.  What sets the amount of work -- how many clients,
 how many samples each, their hidden activation, which are poisoners or
 resource-starved, and their resources -- depends on the file alone, so
 every seed runs the same work.  The seed draws what the work is done on:
-the sample values, the labels and the initial weights.
+the sample values, the labels and the initial weights.  ``layout`` is the
+seed-independent part alone, on which a configuration with samples of
+another kind (token sequences, say) draws its own; ``make_fleet`` and
+``init_weights`` draw the MLP's (``configs/fedar-mlp-resident.py``).
 
 Two ways to describe clients:
 
@@ -25,6 +28,7 @@ from __future__ import annotations
 
 import statistics
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
@@ -103,9 +107,21 @@ def _resources(n: int, starved: np.ndarray,
     return {key: v.astype(np.float32) for key, v in rows.items()}
 
 
-def _layout(traffic: dict):
-    """The seed-independent part of the fleet: per-client sizes, label
-    sets, activations, poisoner and starved masks, and resources."""
+class Layout(NamedTuple):
+    """The seed-independent part of a fleet, one entry per client.  Any
+    configuration's fleet may be drawn on it: ``sizes`` counts whatever its
+    samples are (MLP rows, token sequences)."""
+
+    sizes: np.ndarray  # (N,) int64
+    labels: list  # the client's label set, or None for all classes
+    activations: np.ndarray  # (N,) int32, 0 = ReLU, 1 = softmax
+    poison: np.ndarray  # (N,) bool
+    starved: np.ndarray  # (N,) bool
+    resources: dict  # memory, bandwidth, battery, compute: (N,) float32
+
+
+def layout(traffic: dict) -> Layout:
+    """The fleet's layout that the traffic file fixes, whatever the seed."""
     rng = np.random.default_rng(int(traffic.get("fleet_seed", 0)))
     if "profiles" in traffic:
         prof = traffic["profiles"]
@@ -126,8 +142,8 @@ def _layout(traffic: dict):
         n_starved = round(n * traffic.get("starved_share", 0.0))
         poison = np.isin(np.arange(n), order[:n_poison])
         starved = np.isin(np.arange(n), order[n_poison:n_poison + n_starved])
-    res = _resources(n, starved, rng)
-    return sizes, labels, acts, poison, res
+    return Layout(sizes, labels, acts, poison, starved,
+                  _resources(n, starved, rng))
 
 
 def _draw(key, owner, classes, counts, poison, flip, n_eval):
@@ -167,7 +183,7 @@ def make_fleet(traffic: dict, seed: int) -> Fleet:
     import jax
     import jax.numpy as jnp
 
-    sizes, labels, acts, poison, res = _layout(traffic)
+    sizes, labels, acts, poison, _, res = layout(traffic)
     n = len(sizes)
     classes = np.zeros((n, NUM_CLASSES), np.int32)
     counts = np.zeros(n, np.float32)

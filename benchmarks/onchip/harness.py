@@ -2,18 +2,25 @@
 
 ``BENCHMARK.json`` names each cell's configuration and traffic.  The
 configuration is its ``file`` (``configs/<config>.json``) and, beside it,
-``configs/<config>.py``, which builds the program for the cell and holds
-its reference (see ``configs/fedar-mlp-resident.py`` for what it
-provides).  The traffic is ``traffic/<traffic>.json``, the limits of the
-check ``limits/<cell>.json``, and each per-layer metric is read by
-``metrics/<metric>.py``.  Nothing here names a cell, a configuration or an
-engine, so a new cell, configuration or metric is new files and entries
-only.
+``configs/<config>.py``, which draws the cell's fleet and initial weights,
+gives the work counts its readers take, builds the program and holds its
+reference (``configs/fedar-mlp-resident.py`` lists what it provides).  Of
+a fleet the harness reads ``sizes`` (each client's samples: rows, token
+sequences, whatever the configuration's unit of local SGD is) and
+``num_clients``; of the weights, that they are a pytree of arrays.  The
+traffic is ``traffic/<traffic>.json``, the limits of the check
+``limits/<cell>.json``, and each per-layer metric is read by
+``metrics/<metric>.py``.  Device ops map to layers by the name patterns of
+``layers.json``, and the program's phase scopes are ``scopes.json``'s; a
+configuration's ``.json`` may add ``layers`` and ``phases`` of its own,
+which count for its cells alone.  Nothing here names a cell, a
+configuration, a model or an engine, so a new cell, configuration or
+metric is new files and entries only.
 
 A run:
 
-1. draws the fleet and the initial weights from the seed (``fleetgen``)
-   and has the configuration build the program from them;
+1. has the configuration draw the fleet and the initial weights from the
+   seed, and build the program from them;
 2. drives three rounds through the program's round call -- they compile
    the round -- and keeps what each produced for the check;
 3. drives the same call back to back for ``seconds`` (a closed loop: the
@@ -21,13 +28,23 @@ A run:
    real sample-epochs of each round and the compilations in the window,
    under the profiler when ``trace`` is on;
 4. reads the device's peak memory, frees the program, runs the
-   configuration's reference over the same three rounds and compares.
+   configuration's reference over the same three rounds and compares;
+5. traced, reduces the trace to layers (``tracereduce``) and to the
+   program's phases, spans and counters (``spanreduce``), and has each
+   per-layer metric's reader read them.
+
+A traced run (``--trace 1``) has XLA dump every compiled module's HLO
+text, with JAX's persistent compilation cache off: a v5e trace names an op
+by its HLO text without the ``op_name`` that holds its phase, so
+``spanreduce`` finds it in the dump, and a program loaded from the cache
+is never dumped.  An untraced run does neither.
 """
 from __future__ import annotations
 
 import gc
 import importlib.util
 import json
+import os
 import re
 import shutil
 import sys
@@ -39,7 +56,7 @@ from pathlib import Path
 import numpy as np
 
 import counting
-import fleetgen
+import spanreduce
 import tracereduce
 
 HERE = Path(__file__).resolve().parent
@@ -64,7 +81,7 @@ class CompileCounter:
 # -- finding a cell by name ------------------------------------------------
 def load_cell(root: Path, workload: str, here: Path = HERE) -> dict:
     """The cell ``workload`` of ``root/BENCHMARK.json``, with its
-    configuration, traffic, limits and metric lists."""
+    configuration, traffic, limits, metric lists, layers and phases."""
     bench = json.loads((Path(root) / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -77,6 +94,9 @@ def load_cell(root: Path, workload: str, here: Path = HERE) -> dict:
     traffic = json.loads((here / "traffic" / f"{cell['traffic']}.json")
                          .read_text())
     limits = json.loads((here / "limits" / f"{workload}.json").read_text())
+    layers, scopes = with_own_layers_and_phases(
+        cell["config"], config, tracereduce.load_layers(),
+        spanreduce.load_scopes())
 
     def mine(metrics):
         return [m for m in metrics
@@ -92,8 +112,28 @@ def load_cell(root: Path, workload: str, here: Path = HERE) -> dict:
         "limits": limits,
         "end_to_end": mine(bench["end_to_end"]),
         "per_layer": mine(bench["per_layer"]),
+        "layers": layers,
+        "scopes": scopes,
         "here": here,
     }
+
+
+def with_own_layers_and_phases(name: str, config: dict, layers: dict,
+                               scopes: dict):
+    """``layers.json`` and ``scopes.json`` with the configuration's own
+    ``layers`` (layer -> op-name patterns) and ``phases`` added after
+    theirs.  A layer or phase they already name is an error: a
+    configuration adds names, it never redefines one."""
+    own_layers = config.get("layers", {})
+    own_phases = config.get("phases", [])
+    clash = sorted(
+        (set(own_layers) & {*layers["layers"], layers["rest"]})
+        | (set(own_phases) & set(scopes["phases"])))
+    if clash:
+        raise ValueError(f"configuration {name!r} names layers or phases "
+                         f"the benchmark already has: {clash}")
+    return ({**layers, "layers": {**layers["layers"], **own_layers}},
+            {**scopes, "phases": [*scopes["phases"], *own_phases]})
 
 
 def cell_spec(config: dict, traffic: dict) -> dict:
@@ -188,19 +228,30 @@ def round_times(ends) -> dict:
 
 
 # -- the run ---------------------------------------------------------------
+def draw(cell: dict, seed: int):
+    """The fleet and the initial weights, as the cell's configuration draws
+    them from ``seed``, and a host copy of the weights for the reference
+    (the program may take over the device's)."""
+    import jax
+
+    config = cell["system"]
+    fleet = config.make_fleet(cell["traffic"], seed, cell["spec"])
+    weights = config.init_weights(seed, cell["spec"])
+    return fleet, weights, jax.tree_util.tree_map(np.asarray, weights)
+
+
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              device: dict, t_start: float, counter: CompileCounter,
-             log=lambda s: print(s, file=sys.stderr, flush=True)) -> dict:
-    """One run; returns the contract line as a dict.  A per-layer reader
-    gets the window's rounds, rate, sample-epochs and selected clients,
-    the model, the chips, their peaks and the reduced trace."""
+             log=lambda s: print(s, file=sys.stderr, flush=True),
+             hlo_dir: Path | None = None) -> dict:
+    """One run; returns the contract line as a dict.  Traced, the per-layer
+    metrics are read from ``readings``, with the op names of the modules
+    XLA dumped to ``hlo_dir``."""
     import jax
 
     spec, config = cell["spec"], cell["system"]
     marks = {"start": time.perf_counter() - t_start}
-    fleet = fleetgen.make_fleet(cell["traffic"], seed)
-    weights = fleetgen.init_weights(seed, spec["model"])
-    weights0 = {k: np.asarray(v) for k, v in weights.items()}
+    fleet, weights, weights0 = draw(cell, seed)
     marks["fleet"] = time.perf_counter() - t_start
     system = config.build(spec, fleet, weights)
     del weights
@@ -219,7 +270,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     setup_s = win["start"] - t_start
     peak = memory_peak_bytes()
     log(json.dumps({"memory_peak_bytes": peak, "rounds": win["rounds"],
-                    "window_s": win["elapsed_s"],
+                    "window_s": win["elapsed_s"], "setup_s": setup_s,
                     "round_times": round_times(win["ends"])}))
     system.close()
     del system
@@ -232,38 +283,81 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     numbers["window_compiles"] = win["compiles"]
     limits = cell["limits"]
     failed = failed_checks(numbers, limits)
-    samples_per_s = win["sample_epochs"] / win["elapsed_s"]
     device = {**device, "memory_peak_bytes": peak}
     line = {"correct": not failed, "attempted": win["rounds"],
             "failed": len(failed)}
     if not trace:
-        values = {"samples_per_s": samples_per_s, "setup_s": setup_s}
+        values = {"samples_per_s": win["sample_epochs"] / win["elapsed_s"],
+                  "setup_s": setup_s}
         line["metrics"] = {
             m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in cell["end_to_end"]}
         line["device"] = device
     else:
-        reduced = tracereduce.reduce(tracereduce.load_planes(trace_dir),
-                                     tracereduce.load_layers())
+        r = readings(cell, win, device, tracereduce.load_planes(trace_dir),
+                     compiled_op_names(hlo_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
-        readings = types.SimpleNamespace(
-            rounds=win["rounds"], samples_per_s=samples_per_s,
-            sample_epochs=win["sample_epochs"], clients=win["clients"],
-            model=spec["model"], chips=cell["chips"],
-            peaks=counting.chip_peaks(device["kind"]), trace=reduced)
-        metrics = {}
-        for m in cell["per_layer"]:
-            value = load_reader(cell["here"], m["name"])(readings)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        line["metrics"] = metrics
-        line["device"] = {**device, "busy_s": reduced.mean_busy_s,
-                          "window_s": reduced.window_s}
-        line["breakdown"] = {"device_ops": reduced.top_ops,
-                             "idle_gaps": reduced.idle_by_host}
+        log(json.dumps({"spans_per_round": r.spans.per_round(r.rounds)}))
+        line["metrics"] = per_layer_metrics(cell, r)
+        line["device"] = {**device, "busy_s": r.trace.mean_busy_s,
+                          "window_s": r.trace.window_s}
+        line["breakdown"] = {"device_ops": r.trace.top_ops,
+                             "idle_gaps": r.trace.idle_by_host}
     line["checks"] = {k: {"value": numbers[k], "limit": limits[k]}
                       for k in limits}
     return line
+
+
+def readings(cell: dict, win: dict, device: dict, planes,
+             op_names: dict | None = None) -> types.SimpleNamespace:
+    """What a per-layer reader reads of a traced run: the window's
+    ``rounds``, real ``sample_epochs`` and selected ``clients``, the
+    configuration's ``counts``, the ``chips`` and their ``peaks``, the
+    trace reduced to the cell's layers (``trace``, ``tracereduce``) and to
+    the program's phases, spans and counters (``spans``, ``spanreduce``;
+    ``op_names`` map a v5e trace's ops to their phases)."""
+    planes = list(planes)
+    return types.SimpleNamespace(
+        rounds=win["rounds"], sample_epochs=win["sample_epochs"],
+        clients=win["clients"], counts=cell["system"].counts(cell["spec"]),
+        chips=cell["chips"], peaks=counting.chip_peaks(device["kind"]),
+        trace=tracereduce.reduce(planes, cell["layers"]),
+        spans=spanreduce.reduce(planes, cell["scopes"], op_names=op_names))
+
+
+def per_layer_metrics(cell: dict, r) -> dict:
+    """Each of the cell's per-layer metrics whose reader finds something
+    in ``r``; one that finds nothing (``None``) is left out."""
+    metrics = {}
+    for m in cell["per_layer"]:
+        value = load_reader(cell["here"], m["name"])(r)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
+
+
+def dump_compiled_hlo() -> Path:
+    """Have XLA write every module it compiles, as optimized HLO text, to a
+    new directory, and turn JAX's persistent compilation cache off (a
+    program loaded from it is never dumped).  Before JAX is imported: both
+    are read as it starts."""
+    if "jax" in sys.modules:
+        raise RuntimeError("the HLO dump is set up before JAX is imported")
+    hlo_dir = Path(tempfile.mkdtemp(prefix="onchip_hlo_"))
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS"), f"--xla_dump_to={hlo_dir}",
+        "--xla_dump_hlo_as_text"]))
+    os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    return hlo_dir
+
+
+def compiled_op_names(hlo_dir: Path | None) -> dict:
+    """``spanreduce.hlo_op_names`` of the optimized modules XLA dumped to
+    ``hlo_dir``; none without a dump."""
+    if hlo_dir is None:
+        return {}
+    dumps = sorted(Path(hlo_dir).glob("*after_optimizations.txt"))
+    return spanreduce.hlo_op_names(f.read_text() for f in dumps)
 
 
 def failed_checks(numbers: dict, limits: dict) -> list:
@@ -287,20 +381,26 @@ def main(argv, t_start: float) -> int:
     src = root / "src"
     if not (src / "repro").is_dir():
         raise SystemExit(f"no program source under {src}")
-    sys.path.insert(0, str(src))
-    cell = load_cell(root, args.workload)
-    device = check_device(cell["chips"])
+    hlo_dir = dump_compiled_hlo() if args.trace else None
+    try:
+        sys.path.insert(0, str(src))
+        cell = load_cell(root, args.workload)
+        device = check_device(cell["chips"])
 
-    import repro
-    from repro.common.compile_cache import enable_compile_cache
+        import repro
+        from repro.common.compile_cache import enable_compile_cache
 
-    if not Path(repro.__file__).resolve().is_relative_to(src.resolve()):
-        raise SystemExit(f"the program imported from {repro.__file__}, "
-                         f"not from {src}")
-    enable_compile_cache()
-    counter = CompileCounter()
-    line = run_cell(cell, args.seed, args.seconds, bool(args.trace), device,
-                    t_start, counter)
+        if not Path(repro.__file__).resolve().is_relative_to(src.resolve()):
+            raise SystemExit(f"the program imported from {repro.__file__}, "
+                             f"not from {src}")
+        if not args.trace:
+            enable_compile_cache()
+        counter = CompileCounter()
+        line = run_cell(cell, args.seed, args.seconds, bool(args.trace),
+                        device, t_start, counter, hlo_dir=hlo_dir)
+    finally:
+        if hlo_dir is not None:
+            shutil.rmtree(hlo_dir, ignore_errors=True)
     for name, c in line["checks"].items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

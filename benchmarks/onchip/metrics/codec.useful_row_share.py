@@ -4,8 +4,7 @@ program's ``fedar.fetch`` spans (``spanreduce``)."""
 
 
 def read(r):
-    spans = getattr(r, "spans", None)
-    fetch = spans.stats.get("fedar.fetch", {}) if spans else {}
+    fetch = r.spans.stats.get("fedar.fetch", {})
     encoded = fetch.get("codec_rows_encoded", 0)
     if encoded <= 0:
         return None

@@ -5,8 +5,8 @@ import spanreduce
 
 
 def read(r):
-    spans = getattr(r, "spans", None)
-    if not spans or not spans.self_s or spans.window_s <= 0:
+    spans = r.spans
+    if not spans.self_s or spans.window_s <= 0:
         return None
     idle = spans.idle_s.get(spanreduce.UNSPANNED, 0.0)
     return 100.0 * idle / spans.window_s

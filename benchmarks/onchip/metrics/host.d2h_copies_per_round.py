@@ -3,8 +3,7 @@ program's ``fedar.fetch`` spans (``spanreduce``)."""
 
 
 def read(r):
-    spans = getattr(r, "spans", None)
-    fetch = spans.stats.get("fedar.fetch", {}) if spans else {}
+    fetch = r.spans.stats.get("fedar.fetch", {})
     if "copies" not in fetch or not r.rounds:
         return None
     return fetch["copies"] / r.rounds

@@ -4,7 +4,6 @@ chips."""
 
 
 def read(r):
-    spans = getattr(r, "spans", None)
-    if not spans or not spans.phase_s or not r.rounds:
+    if not r.spans.phase_s or not r.rounds:
         return None
-    return 1000.0 * spans.unscoped_s / r.rounds
+    return 1000.0 * r.spans.unscoped_s / r.rounds

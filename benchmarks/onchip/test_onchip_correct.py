@@ -8,10 +8,8 @@ import time
 from pathlib import Path
 
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-import fleetgen
 import harness
 
 HERE = Path(__file__).resolve().parent
@@ -50,9 +48,7 @@ def test_sound_run_is_correct():
 
 def _program_and_reference(cell):
     spec, config = cell["spec"], cell["system"]
-    fleet = fleetgen.make_fleet(TINY, SEED)
-    weights = fleetgen.init_weights(SEED, spec["model"])
-    w0 = {k: np.asarray(v) for k, v in weights.items()}
+    fleet, weights, w0 = harness.draw(cell, SEED)
     system = config.build(spec, fleet, weights)
     prog = system.checked(harness.CHECKED_ROUNDS)
     system.close()

@@ -56,7 +56,7 @@ def test_every_seed_runs_the_same_work(traffic):
     spec = json.loads((HERE / "traffic" / f"{traffic}.json").read_text())
     if "clients" in spec:
         spec = {**spec, "clients": 64}
-    a, b = fleetgen._layout(spec), fleetgen._layout(spec)
+    a, b = fleetgen.layout(spec), fleetgen.layout(spec)
     for x, y in zip(a, b):
         if isinstance(x, dict):
             assert all(np.array_equal(x[k], y[k]) for k in x)

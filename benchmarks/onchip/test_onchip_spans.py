@@ -1,9 +1,10 @@
-"""The program-span reduction (``spanreduce``) and the metrics that read
-it, on a synthesised trace: device ops with ``op_name`` stats in two
+"""The program-span reduction (``spanreduce``) and the metrics the harness
+reads with it, on a synthesised trace: device ops with ``op_name`` stats in two
 modules, nested program spans with stats, and Python-frame events over an
 idle gap."""
-import types
+import json
 from collections import namedtuple
+from pathlib import Path
 
 import pytest
 
@@ -14,6 +15,10 @@ Plane = namedtuple("Plane", "name lines")
 Line = namedtuple("Line", "name events")
 Event = namedtuple("Event", "name start_ns duration_ns stats")
 SCOPES = spanreduce.load_scopes()
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+PER_LAYER = {m["name"] for m in BENCH["per_layer"]}
+DEVICE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
 ROUND = "jit(_step_fn)"
 
 
@@ -168,16 +173,24 @@ def test_idle_goes_to_the_innermost_program_span():
                         "fedar.fetch": pytest.approx(0.003)}
 
 
-def _read(name, spans, rounds=2):
-    readings = types.SimpleNamespace(rounds=rounds, spans=spans)
-    return harness.load_reader(harness.HERE, name)(readings)
+def _metrics(planes):
+    """The per-layer metrics of ``BENCHMARK.json`` that the harness reads
+    off ``planes`` in the benchmark's cell, two rounds of 1,000
+    sample-epochs."""
+    cell = harness.load_cell(ROOT, "resident-qskew2k")
+    win = {"rounds": 2, "sample_epochs": 1000, "clients": 4}
+    r = harness.readings(cell, win, DEVICE, planes)
+    return {k: v["value"]
+            for k, v in harness.per_layer_metrics(cell, r).items()}
 
 
 def test_the_span_metrics():
-    r = spanreduce.reduce(_trace(OPS), SCOPES)
-    got = {m["name"]: _read(m["name"], r) for m in SCOPES["metrics"]}
+    got = _metrics(_trace(OPS))
+    assert set(got) | {"local_sgd_roofline", "local_sgd.ms_per_round",
+                       "defense.ms_per_round"} == PER_LAYER
     assert got == {
-        "codec.encode_ms_per_round": pytest.approx(1.25),
+        # encode 2 + 0.5 ms (the innermost scope), decode 1 ms, 2 rounds
+        "codec.ms_per_round": pytest.approx(1.75),
         "codec.useful_row_share": pytest.approx(50.0),
         "round_body.unscoped_ms_per_round": pytest.approx(0.25),
         # self time of all program spans but the wait: 9 + 8 ms of
@@ -185,23 +198,32 @@ def test_the_span_metrics():
         "host.busy_ms_per_round": pytest.approx(5.5),
         "host.d2h_copies_per_round": pytest.approx(7.0),
         "device.idle_unspanned_share": pytest.approx(40.0),
+        # and by op names (``layers.json``): busy [2, 7] and [15, 17]
+        "device.idle_share": pytest.approx(65.0),
+        "round.mfu": pytest.approx(100 * 1000 * 409_088 / (0.020 * 197e12)),
+        "agg.ms_per_round": pytest.approx(1.0),
+        "round_body.xla_ms_per_round": pytest.approx(2.5),
     }
 
 
 def test_the_span_metrics_are_silent_without_program_spans():
-    """A program with no scopes, spans or counters gives a trace these
-    metrics find nothing in: each reads None, and none raises."""
+    """A program with no scopes, spans or counters gives a trace the span
+    metrics find nothing in: each is left out, and none raises."""
     planes = _trace([_op(2, 4, "%sort.8")])
     planes[0] = Plane("/host:CPU", [Line("python", [
         e for e in planes[0].lines[0].events
         if e.name.startswith("bench.")])])
-    r = spanreduce.reduce(planes, SCOPES)
-    for m in SCOPES["metrics"]:
-        assert _read(m["name"], r) is None, m["name"]
-    assert _read("codec.encode_ms_per_round", None) is None
+    assert set(_metrics(planes)) == {"device.idle_share", "round.mfu",
+                                     "round_body.xla_ms_per_round"}
 
 
 def test_scope_file_names_the_program_phases():
+    """The program's phases are ``scopes.json``'s and those each
+    configuration names in its own file, each once."""
     from repro.common.tracing import PHASES
 
-    assert tuple(SCOPES["phases"]) == PHASES
+    own = [p for c in BENCH["configs"]
+           for p in json.loads((ROOT / c["file"]).read_text())
+           .get("phases", [])]
+    assert sorted(PHASES) == sorted([*SCOPES["phases"], *own])
+    assert len(set(PHASES)) == len(PHASES)

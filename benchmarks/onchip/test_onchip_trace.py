@@ -78,7 +78,7 @@ def test_layer_file_patterns_classify_the_kernels():
     # op events are named by their HLO instruction, as a v5e trace has them
     sgd = "%local_sgd_fused_ragged.1 = (f32[1024,784,128]) custom-call(...)"
     assert tracereduce.classify(sgd, layers) == "local_sgd"
-    assert tracereduce.classify("%topk_decode.2 = f32[2048,102400] custom-"
+    assert tracereduce.classify("%pack_codes.2 = u8[2048,25600] custom-"
                                 "call(...)", layers) == "codec"
-    assert tracereduce.classify("%fusion.12 = f32[8] fusion(%topk_decode.2)",
+    assert tracereduce.classify("%fusion.12 = f32[8] fusion(%pack_codes.2)",
                                 layers) == layers["rest"]
