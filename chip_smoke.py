@@ -8,9 +8,8 @@ Run from the repository root, in one process that owns the chip:
 Every phase goes through the user entry points (``FedARServer`` ->
 ``FedAREngine`` / ``CohortEngine``) with the paper's client model at full
 width (``MnistConfig()``, 784-128-10) and the ``*_impl`` knobs on ``auto``,
-so on a TPU local SGD, aggregation, the defense and qsgd's uplink codec
-run as compiled Pallas kernels (a top-k uplink is decoded by its kept mask,
-which needs none).  All data comes from the repository's seeded
+so on a TPU local SGD, aggregation, the defense and the uplink codec
+(qsgd's pack/unpack, top-k's selection) run as compiled Pallas kernels.  All data comes from the repository's seeded
 synthetic sources.
 
   P1  the paper's 12 robots (Table II fleet, 300 samples each, B=20, E=5,
@@ -20,8 +19,8 @@ synthetic sources.
       L2, final accuracy to ``P1_ACC_TOL``, and reach ``P1_ACC_FLOOR``.
   P2  the resident engine at 2,048 quantity-skewed clients (100 samples
       each, packed layout, ``select_frac=0.5``, top-k uplink), 3 rounds:
-      the fused ragged local-SGD kernel, the uplink decoded by the top-k
-      kept mask (no codec kernel).
+      the fused ragged local-SGD kernel, the top-k selection kernel and the
+      uplink decoded by its kept mask.
   P3  the host-store cohort engine over a 1,000,000-client virtual fleet
       (K=512 per round, async aggregation, 4-bit qsgd, sketched FoolsGold,
       chaos faults), 3 rounds: ``fedavg_agg``, ``sketch_similarity`` and
@@ -324,8 +323,7 @@ def phase_m3(**kw) -> dict:
 
 
 def _all_kernel(line) -> bool:
-    """Every hot op took its Pallas kernel (the defense/codec may be off;
-    a top-k codec runs the kept mask, which has no kernel)."""
+    """Every hot op took its Pallas kernel (the defense/codec may be off)."""
     parts = [line[k] for k in ("kernel", "one_device", "mesh4") if k in line]
     for part in parts or [line]:
         routes = part["routes"]
@@ -334,7 +332,7 @@ def _all_kernel(line) -> bool:
         if any(routes[k] not in ("kernel", "none")
                for k in ("agg", "defense")):
             return False
-        if routes["compress"] not in ("kernel", "mask", "none"):
+        if routes["compress"] not in ("kernel", "none"):
             return False
         if part["kernels_in_program"] < 1:
             return False

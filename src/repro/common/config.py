@@ -260,9 +260,10 @@ class FedConfig:
     compress: str = "none"
     compress_bits: int = 8  # qsgd levels = 2^(bits-1) - 1; 4 or 8
     compress_k: Optional[int] = None  # topk coordinates kept; None -> D // 32
-    # qsgd's pack/unpack backend: auto (Pallas kernel on TPU, einsum
-    # elsewhere) | kernel | einsum — mirrors ``agg_impl``/``defense_impl``;
-    # top-k runs no kernel
+    # the codec's backend (qsgd's pack/unpack, top-k's selection): auto
+    # (Pallas kernel on TPU, einsum elsewhere) | kernel | einsum — mirrors
+    # ``agg_impl``/``defense_impl``; ``auto`` takes top-k's einsum path for
+    # rows too wide for the selection kernel's VMEM, ``kernel`` raises
     compress_impl: str = "auto"
     # --- fault injection + quarantine (core/faults.py) ---
     # faults: named deterministic fault schedule, keyed on (seed, round,

@@ -18,9 +18,11 @@ and the encode/decode pair applied at the client->aggregator boundary:
   ``topk`` -- magnitude top-``compress_k`` sparsification: the k largest-
               |v| coordinates ship as (value, index) pairs — 8*k bytes per
               client.  Biased, so error feedback is what makes it sound.
-              The round decodes by a kept mask over the encode's input
-              (``kept_topk``), never scattering the pairs;
-              ``decode(payload)`` is the decoder of a received payload.
+              The round selects each row's exact k-th magnitude
+              (``kernels/topk_select.py``) and decodes by a kept mask
+              over the encode's input (``kept_topk``), never sorting or
+              scattering; ``decode(payload)`` is the decoder of a
+              received payload.
 
 Error feedback (EF-SGD): each client compresses ``delta + residual`` and
 carries ``residual' = (delta + residual) - decode(payload)`` to the next
@@ -53,6 +55,7 @@ import jax.numpy as jnp
 from repro.common.config import FedConfig
 from repro.common.tracing import phase
 from repro.kernels import ops, ref
+from repro.kernels.topk_select import topk_select_fits_vmem
 
 __all__ = ["CompressionStrategy", "NoCompression", "QSGDCompression",
            "TopKCompression", "kept_topk", "make_compression"]
@@ -73,6 +76,9 @@ class CompressionStrategy:
                            the post-encode residual for every row.  The
                            engine masks both on the transmit mask.
     ``decode``          -- payload pytree -> (n, D) fp32 decoded deltas.
+    ``route``           -- the backend the codec's hot op takes at width D:
+                           ``"kernel"`` (Pallas), ``"einsum"`` (XLA) or
+                           ``"none"`` (``FedAREngine.kernel_routes``).
     """
 
     name = "none"
@@ -83,6 +89,9 @@ class CompressionStrategy:
 
     def payload_nbytes(self, model_dim: int) -> int:
         return 4 * model_dim  # dense fp32
+
+    def route(self, model_dim: int) -> str:
+        return "none"
 
     def encode(self, deltas, residual, keys) -> Tuple[dict, jnp.ndarray]:
         raise NotImplementedError
@@ -145,8 +154,8 @@ class QSGDCompression(CompressionStrategy):
     def payload_nbytes(self, model_dim: int) -> int:
         return math.ceil(model_dim * self.bits / 8) + 4  # codes + fp32 scale
 
-    def _use_pallas(self) -> bool:
-        return ops.resolve_impl(self.impl, "compress") == "kernel"
+    def route(self, model_dim: int) -> str:
+        return ops.resolve_impl(self.impl, "compress")
 
     def encode(self, deltas, residual, keys):
         v = (deltas + residual).astype(jnp.float32)
@@ -159,15 +168,16 @@ class QSGDCompression(CompressionStrategy):
         q = (low + (unif < u - low)).astype(jnp.int32)  # stochastic round
         q = jnp.where(scale > 0.0, q * jnp.sign(v).astype(jnp.int32), 0)
         codes = (q + self.levels).astype(jnp.int32)  # offset to [0, 2L]
-        packed = ops.pack_codes(codes, bits=self.bits,
-                                use_pallas=self._use_pallas())
+        packed = ops.pack_codes(
+            codes, bits=self.bits,
+            use_pallas=self.route(v.shape[-1]) == "kernel")
         payload = {"codes": packed, "scale": scale.astype(jnp.float32)}
         return payload, v - self.decode(payload, v.shape[-1])
 
     def decode(self, payload, model_dim: int):
         codes = ops.unpack_codes(payload["codes"], bits=self.bits,
                                  dim=model_dim,
-                                 use_pallas=self._use_pallas())
+                                 use_pallas=self.route(model_dim) == "kernel")
         q = codes.astype(jnp.float32) - float(self.levels)
         return q * payload["scale"] / float(self.levels)
 
@@ -177,8 +187,11 @@ class TopKCompression(CompressionStrategy):
     (value, index) pairs.  ``k == D`` is an exact identity; ``k`` defaults
     to ``D // 32`` when ``FedConfig.compress_k`` is unset.  Biased — the
     engine's error feedback carries what was dropped into the next round.
-    The round decodes by the kept mask (``kept_topk``) and runs no kernel,
-    so ``compress_impl`` does not apply."""
+    The kept set comes from each row's exact k-th key and last kept tie
+    (``ops.topk_select``: the ``kernels/topk_select.py`` kernel where
+    ``compress_impl`` resolves to it and a block of the row width fits its
+    VMEM, else the ``jax.numpy`` bisection), and the round decodes by the
+    kept mask (``kept_topk``), sorting and scattering nothing."""
 
     name = "topk"
     active = True
@@ -193,7 +206,15 @@ class TopKCompression(CompressionStrategy):
                 f"compress='topk' with model_dim={model_dim} — need "
                 f"1 <= k <= D (k == D is the exact-identity degenerate case)"
             )
+        if fed.compress_impl == "kernel" and not topk_select_fits_vmem(
+                model_dim):
+            raise ValueError(
+                f'compress_impl="kernel": a block of width {model_dim} does '
+                f"not fit the top-k selection kernel's compiled VMEM limit; "
+                f'use compress_impl="auto" or "einsum" for this model'
+            )
         self.k = int(k)
+        self.impl = fed.compress_impl
 
     def residual_dim(self, model_dim: int) -> int:
         return model_dim
@@ -201,19 +222,32 @@ class TopKCompression(CompressionStrategy):
     def payload_nbytes(self, model_dim: int) -> int:
         return 8 * self.k  # fp32 value + int32 index per kept coordinate
 
-    def _encode(self, deltas, residual):
-        """``v = deltas + residual``, its payload — ``lax.top_k``'s
-        (value, index) pairs by magnitude, ties to the lower index — and
-        the decoded rows, taken from ``v`` by the kept mask."""
-        v = (deltas + residual).astype(jnp.float32)
+    def route(self, model_dim: int) -> str:
+        """The selection's backend: ``"kernel"`` where ``compress_impl``
+        resolves to it and a block of width ``model_dim`` fits the
+        kernel's VMEM (an explicit ``"kernel"`` that does not fit was
+        refused at construction), else ``"einsum"``."""
+        impl = ops.resolve_impl(self.impl, "compress")
+        if impl == "kernel" and not topk_select_fits_vmem(model_dim):
+            return "einsum"
+        return impl
+
+    def _payload(self, v):
+        """``lax.top_k``'s (value, index) pairs by magnitude, ties to the
+        lower index: what crosses the wire."""
         _, idx = jax.lax.top_k(jnp.abs(v), self.k)
-        vals = jnp.take_along_axis(v, idx, axis=-1)
-        payload = {"vals": vals, "idx": idx.astype(jnp.int32)}
-        return payload, v, kept_topk(v, payload["idx"][:, -1:])
+        return {"vals": jnp.take_along_axis(v, idx, axis=-1),
+                "idx": idx.astype(jnp.int32)}
+
+    def _kept(self, v):
+        """``v`` with every coordinate outside its top-k set zeroed."""
+        thr, last = ops.topk_select(
+            v, k=self.k, use_pallas=self.route(v.shape[-1]) == "kernel")
+        return kept_topk(v, thr, last)
 
     def encode(self, deltas, residual, keys):
-        payload, v, dec = self._encode(deltas, residual)
-        return payload, v - dec
+        v = (deltas + residual).astype(jnp.float32)
+        return self._payload(v), v - self._kept(v)
 
     def decode(self, payload, model_dim: int):
         """The decoder of a received payload: scatter-add the pairs into
@@ -222,30 +256,32 @@ class TopKCompression(CompressionStrategy):
                                    model_dim)
 
     def roundtrip(self, deltas, residual, transmit, keys):
-        """``CompressionStrategy.roundtrip`` with no scatter: client and
-        server run in one program, so the decoded rows are the encode's
-        own kept rows.  Bit-identical to decoding the payload, and the
-        payload is still built for the wire's accounting.  The scopes
-        split as for every codec: the encode and the error feedback under
+        """``CompressionStrategy.roundtrip`` with no sort and no scatter:
+        client and server run in one program, so the decoded rows are the
+        encode's own kept rows, bit-identical to decoding the payload.
+        The payload is returned as its ``jax.eval_shape`` structure, for
+        the wire's accounting.  The scopes split as for every codec: the
+        selection, the kept mask and the error feedback under
         ``codec.encode``, the masked decoded rows under ``codec.decode``."""
         m = transmit[:, None]
         with phase("codec.encode"):
-            payload, v, dec = self._encode(deltas, residual)
+            v = (deltas + residual).astype(jnp.float32)
+            dec = self._kept(v)
             res = jnp.where(m, v - dec, residual)
         with phase("codec.decode"):
             dec = jnp.where(m, dec, 0.0)
-        return dec, res, payload
+        return dec, res, jax.eval_shape(self._payload, v)
 
 
-def kept_topk(v, last):
+def kept_topk(v, thr, last):
     """``v`` with every coordinate outside its top-k set replaced by +0.0,
-    given ``last``, the ``(n, 1)`` index of the k-th largest ``|v|`` that
-    ``lax.top_k`` returned.  The int32 view of a non-negative float orders
-    exactly as the float total order does (NaN above +inf), and ``top_k``
-    breaks ties toward the lower index, so the kept set is the keys above
-    the k-th key plus the k-th key's ties at or before ``last``."""
+    given each row's k-th largest key ``thr`` and the column ``last`` of
+    its last kept tie, both ``(n, 1)`` (``ops.topk_select``).  The int32
+    view of a non-negative float orders exactly as the float total order
+    does (NaN above +inf), and ``top_k`` breaks ties toward the lower
+    index, so the kept set is the keys above ``thr`` plus the ties at
+    ``thr`` up to ``last``."""
     key = jax.lax.bitcast_convert_type(jnp.abs(v), jnp.int32)
-    thr = jnp.take_along_axis(key, last, axis=-1)
     cols = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
     kept = (key > thr) | ((key == thr) & (cols <= last))
     # a kept zero decodes to +0.0 as 0.0 + (-0.0) does; XLA would fold a

@@ -80,9 +80,10 @@ class ClientComms:
 
     def record_uplink(self, payload) -> None:
         """Record a compression payload pytree's leaf shapes/dtypes (the
-        per-shard uplink that crosses the client->aggregator boundary)."""
+        per-shard uplink that crosses the client->aggregator boundary),
+        from arrays or ``jax.ShapeDtypeStruct`` leaves alike."""
         self.uplink_payload_shapes.append(tuple(
-            (tuple(leaf.shape), jnp.asarray(leaf).dtype.name)
+            (tuple(leaf.shape), leaf.dtype.name)
             for leaf in jax.tree.leaves(payload)
         ))
 

@@ -1190,7 +1190,7 @@ class FedAREngine:
         ``sgd_route`` (None until ``step``/``run`` has seen data); ``agg``,
         ``defense`` and ``compress`` are ``"kernel"`` (Pallas) or
         ``"einsum"`` (XLA), or ``"none"`` where the subsystem is off; a
-        top-k ``compress`` is ``"mask"`` (the kept mask, no kernel)."""
+        top-k ``compress`` names its selection's backend."""
         fed = self.fed
         return {
             "sgd": self.sgd_route,
@@ -1198,9 +1198,7 @@ class FedAREngine:
                     else resolve_impl(fed.agg_impl, "agg")),
             "defense": ("none" if self.defense.name == "none"
                         else resolve_impl(fed.defense_impl, "defense")),
-            "compress": ("none" if not self.compression.active
-                         else "mask" if self.compression.name == "topk"
-                         else resolve_impl(fed.compress_impl, "compress")),
+            "compress": self.compression.route(self.dim),
         }
 
     def lower_step(self, state, data, *, eval_set=None):

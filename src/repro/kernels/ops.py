@@ -20,6 +20,7 @@ from repro.kernels.fedavg_agg import fedavg_agg as _fedavg_agg_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.local_sgd import local_sgd_fused as _local_sgd_kernel
 from repro.kernels.ssm_scan import ssm_scan as _ssm_kernel
+from repro.kernels.topk_select import topk_select as _topk_select_kernel
 
 _IMPL_KINDS = ("sgd", "agg", "defense", "compress")
 _IMPL_VALUES = ("auto", "kernel", "einsum")
@@ -79,6 +80,16 @@ def unpack_codes(packed, *, bits: int, dim: int, use_pallas: bool = True,
         return ref.unpack_codes_ref(packed, bits=bits, dim=dim)
     itp = interpret_mode() if interpret is None else interpret
     return _unpack_codes_kernel(packed, bits=bits, dim=dim, interpret=itp)
+
+
+def topk_select(v, *, k: int, use_pallas: bool = True,
+                interpret: bool | None = None):
+    """Each row's top-k threshold ``(thr, last)``, (n, 1) int32 each (the
+    top-k codec's selection)."""
+    if not use_pallas:
+        return ref.topk_select_ref(v, k)
+    itp = interpret_mode() if interpret is None else interpret
+    return _topk_select_kernel(v, k=k, interpret=itp)
 
 
 def local_sgd(w1, b1, w2, b2, x, y, act, mask, *, lr: float, batch_size: int,

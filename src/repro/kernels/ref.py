@@ -89,6 +89,35 @@ def topk_decode_ref(vals, idx, dim: int):
     return out.at[rows, idx].add(vals.astype(jnp.float32))
 
 
+def topk_select_ref(v, k: int):
+    """(n, D) -> ``(thr, last)``, each (n, 1) int32: the k-th largest key
+    ``bitcast_int32(|v|)`` of each row and the column of the last tie at
+    it that ``lax.top_k(|v|, k)`` keeps, by the bisection the
+    ``topk_select`` kernel runs (each step a count over the whole row)."""
+    key = jax.lax.bitcast_convert_type(jnp.abs(v.astype(jnp.float32)),
+                                       jnp.int32)
+    n, dim = key.shape
+    zero = jnp.zeros((n, 1), jnp.int32)
+
+    def count(hit):
+        return jnp.sum(hit, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def key_step(b, thr):
+        cand = thr | jnp.left_shift(1, 30 - b)
+        return jnp.where(count(key >= cand) >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 31, key_step, zero)
+    r = k - count(key > thr)
+    cols = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    nbits = (dim - 1).bit_length()
+
+    def col_step(b, last):
+        cand = last | jnp.left_shift(1, nbits - 1 - b)
+        return jnp.where(count((key == thr) & (cols < cand)) < r, cand, last)
+
+    return thr, jax.lax.fori_loop(0, nbits, col_step, zero)
+
+
 def sketch_similarity_ref(unit_loc, unit_full):
     """Defense similarity block: (M, K) @ (N, K).T -> (M, N) float32."""
     return jnp.einsum(

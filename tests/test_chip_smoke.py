@@ -62,11 +62,12 @@ def _final_line_printed(proc) -> bool:
         return False
 
 
-@pytest.mark.parametrize("route,ok", [("kernel", True), ("mask", True),
+@pytest.mark.parametrize("route,ok", [("kernel", True), ("mask", False),
                                       ("none", True), ("einsum", False)])
 def test_route_check_takes_the_top_k_mask(route, ok):
-    """A phase passes its route check with the codec on a kernel, on
-    top-k's kept mask or off, and fails it on the XLA path."""
+    """A phase passes its route check with the codec on a kernel (top-k's
+    selection or qsgd's pack) or off, and fails it on the XLA path or on
+    the kernel-less ``mask`` route the top-k codec no longer reports."""
     line = {"routes": {"sgd": "fused_ragged", "agg": "kernel",
                        "defense": "kernel", "compress": route},
             "kernels_in_program": 2}

@@ -197,19 +197,31 @@ def _assert_bitwise(got, want):
                                       np.asarray(w).view(np.int32))
 
 
-def _assert_kept_mask_bitwise(deltas, residual, transmit, k):
+def _assert_kept_mask_bitwise(deltas, residual, transmit, k, impl="auto"):
     """The jitted roundtrip (as the round runs it) and encode against the
-    scatter decode: decoded rows, residuals and payload equal as int32
-    views, so signed zeros and NaN bits count."""
+    scatter decode, each separately: the roundtrip's decoded rows and
+    residuals, and its payload's shapes and dtypes; encode's payload and
+    residual.  Equal as int32 views, so signed zeros and NaN bits count."""
     deltas = jnp.asarray(deltas, jnp.float32)
     residual = jnp.asarray(residual, jnp.float32)
     transmit = jnp.asarray(transmit, bool)
-    c = _strategy("topk", dim=deltas.shape[-1], compress_k=k)
+    c = _strategy("topk", dim=deltas.shape[-1], compress_k=k,
+                  compress_impl=impl)
     want, want_res = _scatter_roundtrip(k, deltas, residual, transmit)
-    _assert_bitwise(jax.jit(c.roundtrip)(deltas, residual, transmit, None),
-                    want)
+    shapes = []
+
+    def run(*args):
+        dec, res, payload = c.roundtrip(*args)
+        shapes.append(payload)
+        return dec, res
+
+    _assert_bitwise(jax.jit(run)(deltas, residual, transmit, None),
+                    want[:2])
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), shapes[0]) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), want[2])
     payload, res = jax.jit(c.encode)(deltas, residual, None)
-    _assert_bitwise((payload, res), (want[2], want_res))
+    _assert_bitwise(payload, want[2])
+    _assert_bitwise(res, want_res)
 
 
 _MZ = -0.0
@@ -229,14 +241,17 @@ KEPT_MASK_CASES = {
     "all_zero": ([[0.0] * 8, [_MZ] * 8], 2),
     "k_is_1": ([[0.5, -3.0, 3.0, 1.0, _MZ, -_INF, 2.0, 0.0]], 1),
     "k_is_D": ([[0.5, -3.0, 3.0, _MZ, _NAN, -_INF, 2.0, 0.0]], 8),
+    # nine rows (one past a row block) of a width off the 128-lane tile,
+    # quantised to five magnitudes of both signs
+    "nine_rows_odd_width": ([[((r * 7 + c * 3) % 5) * 0.5 * (-1) ** c
+                              for c in range(131)] for r in range(9)], 4),
+    # 100 tied ones from column 1000 on, 60 kept: the last kept tie lies
+    # past the first 1,024 columns
+    "ties_past_a_chunk": ([[0.5] * 1000 + [1.0] * 100], 60),
 }
 
 
-@pytest.mark.parametrize("case", sorted(KEPT_MASK_CASES))
-@pytest.mark.parametrize("residual", ["minus_zero", "drawn"])
-def test_topk_kept_mask_matches_scatter_decode_bitwise(case, residual):
-    """Fixed rows: ties at the k-th magnitude, +-0, +-inf, +-NaN, all-zero
-    rows, k = 1 and k = D; the first row transmits, later ones do not."""
+def _kept_mask_case(case, residual, impl):
     rows, k = KEPT_MASK_CASES[case]
     deltas = np.asarray(rows, np.float32)
     n, d = deltas.shape
@@ -246,9 +261,28 @@ def test_topk_kept_mask_matches_scatter_decode_bitwise(case, residual):
         rng = np.random.default_rng(sorted(KEPT_MASK_CASES).index(case))
         res = rng.choice(SPECIALS, size=(n, d))
     transmit = np.arange(n) == 0
-    _assert_kept_mask_bitwise(deltas, res, transmit, k)
+    _assert_kept_mask_bitwise(deltas, res, transmit, k, impl)
     if n > 1:
-        _assert_kept_mask_bitwise(deltas, res, np.ones(n, bool), k)
+        _assert_kept_mask_bitwise(deltas, res, np.ones(n, bool), k, impl)
+
+
+@pytest.mark.parametrize("case", sorted(KEPT_MASK_CASES))
+@pytest.mark.parametrize("residual", ["minus_zero", "drawn"])
+def test_topk_kept_mask_matches_scatter_decode_bitwise(case, residual):
+    """Fixed rows: ties at the k-th magnitude, +-0, +-inf, +-NaN, all-zero
+    rows, k = 1 and k = D, nine rows of an odd width; the first row
+    transmits, later ones do not.  The selection runs its ``jax.numpy``
+    bisection."""
+    _kept_mask_case(case, residual, "einsum")
+
+
+@pytest.mark.parametrize("case", sorted(KEPT_MASK_CASES))
+@pytest.mark.parametrize("residual", ["minus_zero", "drawn"])
+def test_topk_kept_mask_kernel_route_matches_scatter_decode_bitwise(
+        case, residual):
+    """The same rows with the selection on its Pallas kernel
+    (``compress_impl="kernel"``, interpreted off the chip)."""
+    _kept_mask_case(case, residual, "kernel")
 
 
 @settings(max_examples=25, deadline=None)
@@ -404,14 +438,14 @@ def test_engine_runs_buffered_async_with_compression():
 
 @pytest.mark.parametrize("compress,impl,route", [
     ("none", "kernel", "none"),
-    ("topk", "auto", "mask"),
-    ("topk", "kernel", "mask"),
+    ("topk", "auto", "einsum"),
+    ("topk", "kernel", "kernel"),
     ("qsgd", "kernel", "kernel"),
     ("qsgd", "einsum", "einsum"),
 ])
 def test_kernel_routes_name_the_codec_path(compress, impl, route):
-    """``kernel_routes`` reports what the round runs: top-k decodes by the
-    kept mask whatever ``compress_impl`` says; qsgd takes the knob."""
+    """``kernel_routes`` reports what the round runs: top-k's selection
+    and qsgd's pack take the knob (``auto`` is XLA off the chip)."""
     from repro.configs.fedar_mnist import fleet_fed, small_model
     from repro.core.engine import FedAREngine
     from repro.core.resources import TaskRequirement

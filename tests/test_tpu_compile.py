@@ -28,6 +28,7 @@ from repro.kernels.local_sgd import (
     local_sgd_fused,
     local_sgd_fused_ragged,
 )
+from repro.kernels.topk_select import ROWS, topk_select, topk_select_fits_vmem
 
 I, H, C = 784, 128, 10  # MnistConfig(): the paper's client MLP
 D = I * H + H + H * C + C  # 101,770 flat params
@@ -158,28 +159,55 @@ def _row_sorts(text, rows):
 
 
 @pytest.mark.parametrize("rows", [K, N_RESIDENT])
-def test_topk_codec_compiles_without_kernel(spec, rows):
+def test_topk_codec_compiles_with_selection_kernel(spec, rows, monkeypatch):
     """The default top-k codec (D // 32 kept coordinates) at cohort and
-    resident fleet scale: one sort over the rows and the kept mask, with
-    no kernel and no scatter."""
+    resident fleet scale: the selection kernel and the kept mask, with no
+    sort over the rows and no scatter."""
+    import dataclasses
+
+    from repro.common.config import FedConfig
+    from repro.core.compress import make_compression
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    fed = dataclasses.replace(FedConfig(), compress="topk", defense="none",
+                              compress_impl="kernel")
+    codec = make_compression(fed, D)
+    text = jax.jit(lambda *a: codec.roundtrip(*a)[:2]).lower(
+        spec((rows, D)), spec((rows, D)), spec((rows,), jnp.bool_), None
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and " scatter(" not in text
+    assert _row_sorts(text, rows) == []
+
+
+def test_topk_select_vmem_budget_matches_compiler(spec):
+    """The widest row the selection kernel admits compiles for the chip,
+    and an explicit ``compress_impl="kernel"`` one block wider raises,
+    where ``auto`` takes the ``jax.numpy`` bisection."""
     import dataclasses
 
     from repro.common.config import FedConfig
     from repro.core.compress import make_compression
 
-    fed = dataclasses.replace(FedConfig(), compress="topk", defense="none")
-    codec = make_compression(fed, D)
-    text = jax.jit(codec.roundtrip).lower(
-        spec((rows, D)), spec((rows, D)), spec((rows,), jnp.bool_), None
-    ).compile().as_text()
-    assert "tpu_custom_call" not in text and " scatter(" not in text
-    assert len(_row_sorts(text, rows)) == 1
+    wide = max(d for d in range(1024, 1 << 20, 1024)
+               if topk_select_fits_vmem(d))
+    text = _compile(lambda v: topk_select(v, k=wide // 32),
+                    spec((ROWS, wide)))
+    assert "tpu_custom_call" in text
+    fed = dataclasses.replace(FedConfig(), compress="topk", defense="none",
+                              compress_impl="kernel")
+    assert not topk_select_fits_vmem(wide + 1)
+    with pytest.raises(ValueError, match="VMEM"):
+        make_compression(fed, wide + 1)
+    auto = make_compression(dataclasses.replace(fed, compress_impl="auto"),
+                            wide + 1)
+    assert auto.route(wide + 1) == "einsum"
 
 
 def test_topk_round_decodes_by_kept_mask(spec, monkeypatch):
     """A top-k round of 32 published-width clients compiled for the chip
-    decodes its uplink by the kept mask: no ``topk_decode`` kernel, and
-    the encode's ``top_k`` is the one sort over ``(rows, D)``."""
+    decodes its uplink by the kept mask: no ``topk_decode`` kernel, no
+    sort over ``(rows, D)``, and the selection kernel in its text."""
     import numpy as np
 
     import repro.core.aggregation
@@ -209,4 +237,5 @@ def test_topk_round_decodes_by_kept_mask(spec, monkeypatch):
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text and "codec.decode" in text
     assert "topk_decode" not in text
-    assert len(_row_sorts(text, rows)) == 1
+    assert _row_sorts(text, rows) == []
+    assert re.search(r"%topk_select\S* = .* custom-call\(", text)
