@@ -2,8 +2,9 @@
 rounds/sec scaling of the mesh-sharded engine over fake host devices.
 
 Measures communication rounds/sec at fleet sizes N in {12, 128, 512, 2048}
-for (a) the seed-style python loop — one eager dispatch per round with host
-round-trips for the history rows — and (b) the ``lax.scan`` engine, which
+for (a) the per-round python driver — one jitted ``step`` dispatch per
+round, each round's outputs copied to the host before the next, as
+``FedARServer`` drives it — and (b) the ``lax.scan`` engine, which
 compiles once and keeps all R rounds on-device.  Compile time is reported
 separately (``compile_sec``) from steady-state rounds/sec: the first run
 (compile + warm-up) is excluded from the timed repeats, and the steady
@@ -68,6 +69,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.fedar_mnist import fleet_fed, small_model
 from repro.core.engine import FedAREngine
@@ -144,11 +146,16 @@ def _make(n: int, *, mesh_shape: int | None = None, defense: str = "none",
 
 
 def _time_python(engine, data, rounds: int) -> float:
-    state = engine.init_state()
-    # one untimed round absorbs first-touch costs (weight init transfers)
-    state, _ = engine.run_python_loop(state, data, rounds=1)
+    def one_round(state):
+        state, out = engine.step(state, data)
+        jax.tree.map(np.asarray, out)  # the per-round host round-trip
+        return state
+
+    # one untimed round absorbs compile and first-touch costs
+    state = one_round(engine.init_state())
     t0 = time.perf_counter()
-    engine.run_python_loop(state, data, rounds=rounds)
+    for _ in range(rounds):
+        state = one_round(state)
     return (time.perf_counter() - t0) / rounds
 
 

@@ -19,12 +19,13 @@ synthetic sources.
       L2, final accuracy to ``P1_ACC_TOL``, and reach ``P1_ACC_FLOOR``.
   P2  the resident engine at 2,048 quantity-skewed clients (100 samples
       each, packed layout, ``select_frac=0.5``, top-k uplink), 3 rounds:
-      the fused ragged local-SGD kernel, the top-k selection kernel and the
-      uplink decoded by its kept mask.
+      the local-SGD kernel over the width buckets, the top-k selection
+      kernel and the uplink decoded by its kept mask.
   P3  the host-store cohort engine over a 1,000,000-client virtual fleet
       (K=512 per round, async aggregation, 4-bit qsgd, sketched FoolsGold,
-      chaos faults), 3 rounds: ``fedavg_agg``, ``sketch_similarity`` and
-      the 4-bit pack/unpack kernels.
+      chaos faults), 3 rounds: the local-SGD kernel over the dense cohort
+      block, ``fedavg_agg``, ``sketch_similarity`` and the 4-bit
+      pack/unpack kernels.
   M2  (``--chips 4``) P2 on a 4-way ``clients`` mesh vs one device.
   M3  (``--chips 4``) P3 on a 4-way mesh with the tree reduce vs one device.
 

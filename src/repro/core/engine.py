@@ -61,8 +61,9 @@ with every aggregation mode, defense and the mesh.
 The hot aggregation path goes through the Pallas ``fedavg_agg`` kernel
 (trust-weighted + staleness-decayed in one pass) when running on TPU
 (``FedConfig.agg_impl``); local SGD itself routes through the fused Pallas
-``local_sgd`` kernel (``FedConfig.sgd_impl``) that runs each client's whole
-masked epochs x batches loop in one ``pallas_call``.
+``local_sgd`` kernel (``FedConfig.sgd_impl``): one ``pallas_call`` runs
+every client's whole masked epochs x batches loop, for the dense block and
+for every packed bucket alike (``FedAREngine._client_update``).
 """
 from __future__ import annotations
 
@@ -193,23 +194,8 @@ class FedAREngine:
         self.model = model
         self.cfg = getattr(model, "cfg", None)
         self.fed, self.req, self.lr = fed, req, lr
-        # the local-SGD backend request: the fused Pallas kernel only
-        # applies to families that ship one — an explicit ``"kernel"``
-        # request on any other family runs the vmapped XLA path, loudly.
-        # The route itself (``sgd_route``) is decided host-side from the
-        # data's shapes on every ``step`` / ``run`` call.
-        self._sgd_kernel = (
-            resolve_impl(fed.sgd_impl, "sgd") == "kernel"
-            and model.supports_fused
-        )
-        if fed.sgd_impl == "kernel" and not model.supports_fused:
-            warnings.warn(
-                f'sgd_impl="kernel" requests the fused Pallas local-SGD '
-                f"kernel, but model family {model.family!r} does not ship "
-                f"one; falling back to the vmapped XLA path",
-                stacklevel=2,
-            )
-        self.sgd_route = None if self._sgd_kernel else "xla"
+        # the local-SGD route, decided once, host-side, from the config
+        self.sgd_route = self._decide_sgd_route()
         key = jax.random.PRNGKey(fed.seed)
         self.template = model.init(key)
         self.dim = flatten(self.template).shape[0]
@@ -364,20 +350,84 @@ class FedAREngine:
         return jax.device_put(tree, shardings)
 
     # ---------------------------------------------------- ClientUpdate
-    def _block_sgd(self, g_flat, fields, m):
-        """Local SGD over one block of clients -> stacked flat local params
-        (rows, D).  ``fields`` is the dict of stacked per-client sample
-        arrays keyed by ``self.model.data_keys`` (client axis leading).
-        On the ``"fused"`` route (``sgd_route``) the model's
-        ``fused_block_update`` runs the whole masked epochs x batches loop
-        per client inside one ``pallas_call``; otherwise the XLA path vmaps
-        the model's ``client_update`` (the seed-exact reference)."""
-        fed = self.fed
-        if self.sgd_route == "fused":
-            return self.model.fused_block_update(
-                g_flat, fields, m, lr=self.lr,
-                batch_size=fed.local_batch_size, epochs=fed.local_epochs,
+    def _client_update(self, g_flat, data, selected, round_idx):
+        """ClientUpdate (Algorithm 2 lines 16-21) over this shard's clients
+        -> ``(locals_flat, locals_c, cohort)``: the canonical (N_loc, D)
+        post-SGD flat params, plus — with ``select_frac`` set — the compact
+        cohort rows and their ``(canon, valid)`` map, so deviation and
+        aggregation can skip the known-zero rows (``None, None`` ungated).
+
+        Every layout reaches local SGD through ``_ragged_block_sgd`` as a
+        list of ``(fields, sample_mask)`` blocks: the dense sample block
+        (or its gated cohort) is one block; the packed layout
+        (``FederatedDataset.packed_arrays``) gives one block per size
+        bucket — cost tracks the bucket widths (<= 2x the real samples)
+        instead of N * n_max — restored to canonical order by one gather
+        through the precomputed inverse permutation (dummy pad rows carry
+        an all-False mask, and ``inv`` never points at them), or gated
+        through the two-pass global cohort (``_packed_gated_locals``).
+        Gated, unselected clients gather the untouched global params, so
+        their delta is exactly zero."""
+        sel_loc = self.comms.local(selected)
+        if "packed" in data:
+            packed = data["packed"]
+            if self.cohort_cap is None:
+                blocks = [
+                    (dict(zip(self.model.data_keys,
+                              (packed["x"][b], packed["y"][b],
+                               packed["act"][b]))),
+                     self._bucket_mask(packed, b, round_idx))
+                    for b in range(len(packed["x"]))
+                ]
+                locals_cat = self._ragged_block_sgd(g_flat, blocks)
+                return locals_cat[packed["inv"]], None, None
+            locals_c, cohort = self._packed_gated_locals(
+                g_flat, packed, sel_loc, round_idx
             )
+        else:
+            fields = {k: data[k] for k in self.model.data_keys}
+            mask = self._round_window(data.get("mask"),
+                                      data.get("round_mask"), round_idx)
+            if self.cohort_cap is None:
+                return (self._ragged_block_sgd(g_flat, [(fields, mask)]),
+                        None, None)
+            # stable argsort: selected rows first, in canonical order, up to
+            # the static cohort cap
+            cap = min(sel_loc.shape[0], self.cohort_cap)
+            idx = jnp.argsort(jnp.where(sel_loc, 0, 1))[:cap]
+            fields_c = {k: v[idx] for k, v in fields.items()}
+            m_c = None if mask is None else mask[idx]
+            locals_c = self._ragged_block_sgd(g_flat, [(fields_c, m_c)])
+            cohort = (idx, sel_loc[idx])
+        locals_flat = self._expand_cohort(
+            locals_c, cohort[0], cohort[1], sel_loc.shape[0], g_flat
+        )
+        return locals_flat, locals_c, cohort
+
+    def _ragged_block_sgd(self, g_flat, blocks):
+        """Local SGD over a list of rectangular ``(fields, sample_mask)``
+        client blocks, of any widths -> concatenated (sum rows, D) flat
+        local params, in block order.  On the ``"fused_ragged"`` route the
+        model's ``fused_ragged_update`` runs ALL blocks inside ONE
+        ragged-grid ``pallas_call``; the XLA route keeps one vmap per block
+        (``_block_sgd``; XLA cannot fuse across the differing widths)."""
+        if self.sgd_route == "fused_ragged":
+            return self.model.fused_ragged_update(
+                g_flat, blocks, lr=self.lr,
+                batch_size=self.fed.local_batch_size,
+                epochs=self.fed.local_epochs,
+            )
+        return jnp.concatenate(
+            [self._block_sgd(g_flat, f, m) for f, m in blocks]
+        )
+
+    def _block_sgd(self, g_flat, fields, m):
+        """The XLA route's local SGD over one block of clients -> stacked
+        flat local params (rows, D): the model's ``client_update`` (the
+        seed-exact reference) vmapped over the block.  ``fields`` is the
+        dict of stacked per-client sample arrays keyed by
+        ``self.model.data_keys`` (client axis leading)."""
+        fed = self.fed
 
         def client_update(p_flat, f, m=None):
             p = unflatten(p_flat, self.template)
@@ -406,26 +456,6 @@ class FedAREngine:
             g_flat, fields, m
         )
 
-    def _gated_block_locals(self, g_flat, fields, m, sel_rows):
-        """Selection-gated ClientUpdate over one client block: gather the
-        (statically capped) selected rows and run local SGD over that
-        cohort only.  Returns ``(idx, locals_c, valid)`` — the block rows
-        each cohort slot came from, the cohort's post-SGD flat params, and
-        which slots hold a genuinely selected client; the caller expands
-        back with the untouched global params as the fill row, so selected
-        clients' local params (and therefore deltas) are bit-identical to
-        the full-block vmap and unselected deltas are exact zeros."""
-        rows = sel_rows.shape[0]
-        cap = min(rows, self.cohort_cap)
-        # stable argsort: selected rows first, in canonical order
-        order = jnp.argsort(jnp.where(sel_rows, 0, 1))
-        idx = order[:cap]
-        valid = sel_rows[idx]
-        m_c = None if m is None else m[idx]
-        fields_c = {k: v[idx] for k, v in fields.items()}
-        locals_c = self._block_sgd(g_flat, fields_c, m_c)
-        return idx, locals_c, valid
-
     @staticmethod
     def _expand_cohort(vals, canon, valid, rows, fill_row):
         """(cap, D) cohort rows -> (rows, D) canonical block: one int32
@@ -440,23 +470,24 @@ class FedAREngine:
         ].set(jnp.arange(cap, dtype=jnp.int32), mode="drop")
         return aug[inv]
 
-    def _ragged_block_sgd(self, g_flat, blocks):
-        """Local SGD over a list of rectangular client blocks of differing
-        widths -> concatenated (sum rows, D) flat local params, in block
-        order.  On the ``"fused_ragged"`` route the model's
-        ``fused_ragged_update`` runs ALL blocks inside ONE ragged-grid
-        ``pallas_call`` (a single launch for the whole bucketed layout —
-        no per-bucket dispatch); the XLA route keeps one vmap per block
-        (XLA cannot fuse across the differing widths)."""
-        if self.sgd_route == "fused_ragged":
-            return self.model.fused_ragged_update(
-                g_flat, blocks, lr=self.lr,
-                batch_size=self.fed.local_batch_size,
-                epochs=self.fed.local_epochs,
-            )
-        return jnp.concatenate(
-            [self._block_sgd(g_flat, f, m) for f, m in blocks]
+    @staticmethod
+    def _round_window(mask, round_mask, round_idx):
+        """This round's effective sample mask: the static ``mask`` & the
+        drift schedule's active window ``round_mask[round_idx mod W]``
+        (either may be ``None``)."""
+        if round_mask is None:
+            return mask
+        win = jax.lax.dynamic_index_in_dim(
+            round_mask, jnp.remainder(round_idx, round_mask.shape[0]), 0,
+            keepdims=False,
         )
+        return win if mask is None else mask & win
+
+    def _bucket_mask(self, packed, b, round_idx):
+        """Packed bucket ``b``'s sample mask for this round."""
+        rm = packed.get("round_mask")
+        return self._round_window(packed["mask"][b],
+                                  None if rm is None else rm[b], round_idx)
 
     @staticmethod
     def _desc_order(packed) -> list:
@@ -489,22 +520,6 @@ class FedAREngine:
         out["packed"] = packed
         return out
 
-    def _packed_round_masks(self, packed, round_idx, order):
-        """This round's effective per-bucket sample masks (static mask &
-        the drift schedule's active window), in ``order``."""
-        masks = []
-        for b in order:
-            m = packed["mask"][b]
-            if "round_mask" in packed:
-                rm = packed["round_mask"][b]
-                win = jax.lax.dynamic_index_in_dim(
-                    rm, jnp.remainder(round_idx, rm.shape[0]), 0,
-                    keepdims=False,
-                )
-                m = m & win
-            masks.append(m)
-        return masks
-
     def _packed_cohort_plan(self, widths, rows) -> list:
         """Static slot plan of the two-pass global cohort: ONE allocation
         of ``min(cohort_cap, sum rows)`` slots across all buckets, widest
@@ -528,31 +543,23 @@ class FedAREngine:
         the selected prefix is ordered widest-first.  Pass 2 (one capped
         gather): the static slot plan (``_packed_cohort_plan``) slices that
         prefix into per-width slot groups and gathers each group's samples
-        from the flat descending-width buffer (clamped reads past a row's
-        own storage are masked off, and a narrower client inside a wider
-        slot just runs extra all-masked batches — exact no-ops), so gated
-        compute tracks the top-C bucket widths instead of summing
-        per-bucket caps toward N.  Returns ``(locals_c, cohort)``."""
+        from the flat descending-width buffer ``packed["flat"]``
+        (``_with_flat_packed``; clamped reads past a row's own storage are
+        masked off, and a narrower client inside a wider slot just runs
+        extra all-masked batches — exact no-ops), so gated compute tracks
+        the top-C bucket widths instead of summing per-bucket caps toward
+        N.  Returns ``(locals_c, cohort)``."""
         desc = self._desc_order(packed)
         widths = [packed["x"][b].shape[1] for b in desc]
         rows = [packed["x"][b].shape[0] for b in desc]
         perm_d = jnp.concatenate([packed["perm"][b] for b in desc])
         valid_d = jnp.concatenate([packed["valid"][b] for b in desc])
         act_d = jnp.concatenate([packed["act"][b] for b in desc])
-        masks = self._packed_round_masks(packed, round_idx, desc)
-        mf = jnp.concatenate([m.reshape(-1) for m in masks])
-        flat = packed.get("flat")
-        if flat is None:  # entry points hoist this; direct calls build it
-            dim = packed["x"][0].shape[2]
-            flat = (
-                jnp.concatenate(
-                    [packed["x"][b].reshape(-1, dim) for b in desc]
-                ),
-                jnp.concatenate(
-                    [packed["y"][b].reshape(-1) for b in desc]
-                ),
-            )
-        xf, yf = flat
+        mf = jnp.concatenate(
+            [self._bucket_mask(packed, b, round_idx).reshape(-1)
+             for b in desc]
+        )
+        xf, yf = packed["flat"]
         # static per-row storage geometry of the descending concat
         row_w = np.repeat(widths, rows).astype(np.int32)
         row_off = np.concatenate(
@@ -584,51 +591,6 @@ class FedAREngine:
         cohort = (perm_d[slots], sel_rows[slots])
         return locals_c, cohort
 
-    def _packed_locals(self, g_flat, packed, selected, round_idx):
-        """ClientUpdate over the bucketed packed layout
-        (``FederatedDataset.packed_arrays``) -> (N_loc, D) post-SGD flat
-        local params in canonical order: block SGD per size bucket (ONE
-        fused ragged-grid launch on the kernel route) — cost tracks the
-        bucket widths (<= 2x the real samples) instead of N * n_max —
-        concatenated in packed order and restored by a single gather
-        through the precomputed inverse permutation.  Dummy pad rows carry
-        an all-False mask (and ``inv`` never points at them); with
-        ``select_frac`` set the two-pass global cohort
-        (``_packed_gated_locals``) gates SGD down to one globally-capped
-        slot set and unselected clients gather the untouched global params
-        (delta exactly zero).
-
-        Returns ``(locals_flat, locals_c, cohort)``: the canonical
-        (N_loc, D) post-SGD params, plus — in gated mode — the compact
-        cohort rows and their ``(canon, valid)`` map so deviation and
-        aggregation can skip the known-zero rows (``None, None``
-        ungated)."""
-        sel_loc = self.comms.local(selected)
-        n_loc = sel_loc.shape[0]
-        if self.cohort_cap is None:
-            masks = self._packed_round_masks(
-                packed, round_idx, range(len(packed["x"]))
-            )
-            blocks = [
-                (
-                    dict(zip(
-                        self.model.data_keys,
-                        (packed["x"][b], packed["y"][b], packed["act"][b]),
-                    )),
-                    masks[b],
-                )
-                for b in range(len(packed["x"]))
-            ]
-            locals_cat = self._ragged_block_sgd(g_flat, blocks)
-            return locals_cat[packed["inv"]], None, None
-        locals_c, cohort = self._packed_gated_locals(
-            g_flat, packed, sel_loc, round_idx
-        )
-        locals_flat = self._expand_cohort(
-            locals_c, cohort[0], cohort[1], n_loc, g_flat
-        )
-        return locals_flat, locals_c, cohort
-
     # ------------------------------------------------------------------
     def _round_step(self, state: EngineState, data, eval_set,
                     force_straggler, train_flops):
@@ -642,7 +604,7 @@ class FedAREngine:
         a drift schedule — round t trains on window ``t mod W`` (``sizes``
         stays the static n_u aggregation weight).  Alternatively
         ``data["packed"]`` holds the bucketed packed layout (see
-        ``_packed_locals``).  ``train_flops`` is the static per-client FLOP
+        ``_client_update``).  ``train_flops`` is the static per-client FLOP
         count of the virtual-latency model — computed host-side from the
         *dense* sample width so the physical layout (packed or padded)
         cannot shift straggler numerics.
@@ -699,44 +661,13 @@ class FedAREngine:
                 )
 
         g_flat = state.params
-        locals_c = cohort = None  # compact gated-cohort view, when gating
         with phase("local_sgd"):
-            if "packed" in data:
-                # --- lines 16-21 (ClientUpdate), padding-free bucketed path
-                locals_flat, locals_c, cohort = self._packed_locals(
-                    g_flat, data["packed"], selected, state.round_idx
-                )
-            else:
-                # --- ragged / drifting shards: resolve this round's sample
-                # mask
-                sample_mask = data.get("mask")
-                if "round_mask" in data:
-                    rm = data["round_mask"]
-                    active_window = jax.lax.dynamic_index_in_dim(
-                        rm, jnp.remainder(state.round_idx, rm.shape[0]), 0,
-                        keepdims=False,
-                    )
-                    sample_mask = (
-                        active_window if sample_mask is None
-                        else sample_mask & active_window
-                    )
-
-                # --- lines 16-21 (ClientUpdate): local SGD vmapped over
-                # this shard's client block (or its gated cohort); non-
-                # participants are masked out of the aggregate
-                fields = {k: data[k] for k in self.model.data_keys}
-                if self.cohort_cap is None:
-                    locals_flat = self._block_sgd(g_flat, fields,
-                                                  sample_mask)
-                else:
-                    sel_loc = comms.local(selected)
-                    idx, locals_c, valid = self._gated_block_locals(
-                        g_flat, fields, sample_mask, sel_loc
-                    )
-                    cohort = (idx, valid)
-                    locals_flat = self._expand_cohort(
-                        locals_c, idx, valid, sel_loc.shape[0], g_flat
-                    )
+            # --- lines 16-21: ClientUpdate over this shard's clients (or
+            # their gated cohort, ``locals_c`` over ``cohort``); non-
+            # participants are masked out of the aggregate
+            locals_flat, locals_c, cohort = self._client_update(
+                g_flat, data, selected, state.round_idx
+            )
             deltas = locals_flat - g_flat[None, :]  # (N_loc, D)
             # compact deltas: deviation + the fedar/fedavg reduction only
             # touch cohort rows (the rest are exact zeros), so with the
@@ -1104,38 +1035,39 @@ class FedAREngine:
             self.model.train_flops(shape, epochs=self.fed.local_epochs)
         )
 
-    def _decide_sgd_route(self, data) -> str:
-        """The local-SGD route for this data, decided host-side from its
-        shapes before tracing: ``"fused_ragged"`` (one ragged-grid kernel
-        launch over the packed buckets), ``"fused"`` (the rectangular
-        kernel over the dense sample block) or ``"xla"`` (vmapped
-        ``client_update``).  A block the kernel cannot take goes to XLA
-        under ``sgd_impl="auto"`` and raises under an explicit
-        ``"kernel"``.  The route is a function of shapes and config alone,
-        so the jit caches (keyed on shapes) never mix routes."""
-        if not self._sgd_kernel:
+    def _decide_sgd_route(self) -> str:
+        """The local-SGD route: ``"fused_ragged"`` (the model's fused
+        kernel, one ragged-grid launch over every client block, dense or
+        packed) or ``"xla"`` (vmapped ``client_update``).  The kernel only
+        applies to families that ship one — an explicit ``"kernel"``
+        request on any other family runs the XLA path, loudly.  The kernel
+        streams one batch tile per grid step, so what must fit its compiled
+        VMEM limit is one ``local_batch_size`` tile whatever the layout or
+        the clients' widths; a tile that does not fit runs XLA under
+        ``sgd_impl="auto"`` and raises under an explicit ``"kernel"``."""
+        fed, model = self.fed, self.model
+        if resolve_impl(fed.sgd_impl, "sgd") != "kernel":
             return "xla"
-        bs = self.fed.local_batch_size
-        if "packed" in data:
-            # the ragged grid streams one batch tile per step
-            width, route = bs, "fused_ragged"
-        else:
-            width = data[self.model.data_keys[0]].shape[1]
-            route = "fused"
-        if self.model.fused_fits(width, bs):
-            return route
-        if self.fed.sgd_impl == "kernel":
+        if not model.supports_fused:
+            if fed.sgd_impl == "kernel":
+                warnings.warn(
+                    f'sgd_impl="kernel" requests the fused Pallas local-SGD '
+                    f"kernel, but model family {model.family!r} does not "
+                    f"ship one; falling back to the vmapped XLA path",
+                    stacklevel=3,
+                )
+            return "xla"
+        bs = fed.local_batch_size
+        if model.fused_fits(bs):
+            return "fused_ragged"
+        if fed.sgd_impl == "kernel":
             raise ValueError(
-                f'sgd_impl="kernel": a {width}-sample client block does '
-                f"not fit the fused local-SGD kernel's compiled VMEM limit; "
-                f'use sgd_impl="auto" or "einsum" for this fleet'
+                f'sgd_impl="kernel": a {bs}-sample batch tile does not fit '
+                f"the fused local-SGD kernel's compiled VMEM limit; use "
+                f'sgd_impl="auto" or "einsum", or a smaller '
+                f"local_batch_size"
             )
         return "xla"
-
-    def _prepare_call(self, data) -> None:
-        """Host-side checks and decisions before a jitted entry point."""
-        self._check_packed(data)
-        self.sgd_route = self._decide_sgd_route(data)
 
     def _check_packed(self, data) -> None:
         """Host-side layout check: a packed dict built for k shards only
@@ -1186,8 +1118,7 @@ class FedAREngine:
         return self._on_mesh(raw, self.data_specs(raw))
 
     def kernel_routes(self) -> dict:
-        """The backend each hot op takes: ``sgd`` is the decided
-        ``sgd_route`` (None until ``step``/``run`` has seen data); ``agg``,
+        """The backend each hot op takes: ``sgd`` is ``sgd_route``; ``agg``,
         ``defense`` and ``compress`` are ``"kernel"`` (Pallas) or
         ``"einsum"`` (XLA), or ``"none"`` where the subsystem is off; a
         top-k ``compress`` names its selection's backend."""
@@ -1204,7 +1135,7 @@ class FedAREngine:
     def lower_step(self, state, data, *, eval_set=None):
         """The lowered one-round program ``step`` would run on these
         inputs (``.as_text()`` shows which kernels it calls)."""
-        self._prepare_call(data)
+        self._check_packed(data)
         return self._step.lower(*self._placed(state, data, eval_set, None),
                                 train_flops=self._train_flops(data))
 
@@ -1214,7 +1145,7 @@ class FedAREngine:
         the compilations the call triggered); the call returns before the
         device finishes."""
         with span("fedar.prepare"):
-            self._prepare_call(data)
+            self._check_packed(data)
             args = self._placed(state, data, eval_set, force_straggler)
             train_flops = self._train_flops(data)
         with span("fedar.dispatch") as s:
@@ -1229,31 +1160,11 @@ class FedAREngine:
     def run(self, state, data, *, rounds: int, eval_set=None,
             force_straggler=None):
         """R rounds in a single ``lax.scan`` -> (state, stacked outputs)."""
-        self._prepare_call(data)
+        self._check_packed(data)
         return self._run(
             *self._placed(state, data, eval_set, force_straggler),
             rounds=rounds, train_flops=self._train_flops(data),
         )
-
-    def run_python_loop(self, state, data, *, rounds: int, eval_set=None,
-                        force_straggler=None):
-        """Seed-style reference driver: one EAGER (un-jitted) dispatch per
-        round with a device->host sync of every history row.  Kept as the
-        benchmark baseline the scan engine is measured against."""
-        self._prepare_call(data)
-        outs = []
-        for _ in range(rounds):
-            state, out = self._step_fn(
-                state, data, eval_set, force_straggler,
-                train_flops=self._train_flops(data),
-            )
-            # per-round host round-trip, exactly like the seed driver
-            outs.append(jax.tree.map(np.asarray, out))
-        stacked = RoundOutputs(
-            *(np.stack([getattr(o, f) for o in outs])
-              for f in RoundOutputs._fields)
-        )
-        return state, stacked
 
 
 class CohortEngine:

@@ -5,35 +5,22 @@ every selected client runs E epochs of batch SGD on its local shard.  The
 XLA path vmaps a ``lax.scan`` of ``jax.grad`` steps — each batch step
 round-trips the full parameter set through HBM.  This kernel fuses the
 whole per-client loop (epochs x batches of forward + backward + SGD update)
-into ONE ``pallas_call``: the grid walks the client rows of a (bucketed)
-cohort block, each grid step streams that client's sample slab HBM->VMEM
-once, keeps the evolving parameters resident in the output VMEM tiles, and
-iterates every batch against them — zero parameter traffic between steps.
+into ONE ``pallas_call``, ``local_sgd_fused_ragged``: every client block —
+the dense sample rectangle, or each width bucket of the packed layout — is
+flattened into a single (T, B, I) batch-tile buffer, and a
+``PrefetchScalarGridSpec`` grid (client, epoch, batch) streams each
+client's tiles through scalar-prefetched per-client tile offsets and batch
+counts.  The evolving parameters stay resident in the output VMEM tiles
+across a client's whole walk — zero parameter traffic between steps — and
+a client narrower than the widest one skips its tail grid steps, so mixed
+widths cost skipped steps, not separate launches.  A dense (R, n, I) block
+is the one-bucket case: every client has ``ceil(n / B)`` tiles.
 
 Masked tiles are skipped: a batch whose validity-mask count is zero (the
 pad-to-bucket tail of a packed shard, or a dummy mesh-fill row) is an exact
 no-op on the XLA path (the masked loss renormalizes to zero gradient), so
 ``pl.when`` guards the entire batch body and the kernel pays nothing for
-padding — the residual <=2x pad-to-bucket waste of the packed layout
-becomes pure skipped tiles here.
-
-Two entry points share the batch body:
-
-``local_sgd_fused``        — one rectangular client block (R, n, I); the
-                             grid walks clients, each grid step keeps the
-                             whole sample slab in VMEM and ``fori_loop``s
-                             its epochs x batches.
-``local_sgd_fused_ragged`` — the WHOLE bucketed packed layout in ONE
-                             launch: clients of every width bucket are
-                             flattened to a single (T, B, I) batch-tile
-                             buffer, and a ``PrefetchScalarGridSpec`` grid
-                             (client, epoch, batch) streams each client's
-                             tiles through scalar-prefetched per-client
-                             tile offsets / batch counts.  Ragged widths
-                             become skipped grid steps instead of separate
-                             ``pallas_call`` dispatches, so the per-bucket
-                             launch + gather overhead of the packed layout
-                             disappears.
+padding.
 
 The backward pass is written out by hand (softmax cross-entropy through the
 Table II per-robot hidden activation, ReLU or Softmax) and matches
@@ -51,12 +38,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Scoped-VMEM limit handed to the TPU compiler for the fused kernels, and the
-# budget ``fused_fits_vmem`` checks a block against.  v5e has 128 MiB of
-# VMEM per core; 32 MiB admits the paper's 784-128-10 MLP up to 2,760 samples
-# per client at B=20.  ``tests/test_tpu_compile.py`` compiles the widest width this
-# admits for a described v5e, so the estimate below is held to what the
-# compiler accepts.
+# Scoped-VMEM limit handed to the TPU compiler for the fused kernel, and the
+# budget ``fused_fits_vmem`` checks a batch tile against.  v5e has 128 MiB of
+# VMEM per core; 32 MiB admits the paper's 784-128-10 MLP up to batch tiles
+# of about 2,100 samples.  ``tests/test_tpu_compile.py`` compiles the widest
+# tile this admits for a described v5e, so the estimate below is held to
+# what the compiler accepts.
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
@@ -65,37 +52,36 @@ def _tile_bytes(rows: int, cols: int) -> int:
     return 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
 
 
-def fused_fits_vmem(n: int, input_dim: int, hidden: int, classes: int,
-                    batch: int = 20, limit: int = VMEM_LIMIT_BYTES) -> bool:
-    """Whether one client's ``local_sgd_fused`` grid step — n samples in
-    batches of ``batch`` — fits the scoped VMEM limit the kernel compiles
+def fused_fits_vmem(batch: int, input_dim: int, hidden: int, classes: int,
+                    limit: int = VMEM_LIMIT_BYTES) -> bool:
+    """Whether one grid step of ``local_sgd_fused_ragged`` — one batch tile
+    of ``batch`` samples — fits the scoped VMEM limit the kernel compiles
     under.  The engine decides its local-SGD route from this, host-side,
-    before tracing (``FedAREngine.sgd_route``).
+    before tracing (``FedAREngine.sgd_route``); a client's width never
+    enters, since its tiles stream through one at a time.
 
-    The padded footprint counted: the double-buffered (nb, B, I) sample
-    slab and its (nb, B, 1) label / mask columns, the double-buffered
-    in/out parameter tiles, and the batch body's parameter-sized
-    temporaries (loaded weights and gradients)."""
-    nb = -(-n // batch)
-    slab = nb * (_tile_bytes(batch, input_dim) + 2 * _tile_bytes(batch, 1))
+    The padded footprint counted: the double-buffered (B, I) sample tile
+    and its (B, 1) label / mask columns, the double-buffered in/out
+    parameter tiles, and the batch body's temporaries — parameter-sized
+    (loaded weights and gradients) and batch-sized (the six hidden-wide and
+    four class-wide activations of the forward and backward pass)."""
+    tile = _tile_bytes(batch, input_dim) + 2 * _tile_bytes(batch, 1)
     params = (
         _tile_bytes(input_dim, hidden) + _tile_bytes(1, hidden)
         + _tile_bytes(hidden, classes) + _tile_bytes(1, classes)
     )
-    temps = 2 * (_tile_bytes(input_dim, hidden) + _tile_bytes(hidden, classes))
-    return 2 * slab + 4 * params + temps <= limit
-
-
-def _compiler_params():
-    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+    temps = (
+        2 * (_tile_bytes(input_dim, hidden) + _tile_bytes(hidden, classes))
+        + 6 * _tile_bytes(batch, hidden) + 4 * _tile_bytes(batch, classes)
+    )
+    return 2 * tile + 4 * params + temps <= limit
 
 
 def _batch_body(xb, yb, mb, is_soft, w1o, b1o, w2o, b2o, *, lr):
     """One masked SGD step against the params resident in the output VMEM
-    tiles (shared by the rectangular and the ragged-grid kernels).
-    ``xb`` (B, I), ``yb`` (B, 1) int labels, ``mb`` (B, 1) float validity.
-    An all-padding batch is an exact no-op (the masked loss renormalizes to
-    zero gradient), so ``pl.when`` skips it entirely."""
+    tiles.  ``xb`` (B, I), ``yb`` (B, 1) int labels, ``mb`` (B, 1) float
+    validity.  An all-padding batch is an exact no-op (the masked loss
+    renormalizes to zero gradient), so ``pl.when`` skips it entirely."""
     cnt = jnp.sum(mb)
 
     @pl.when(cnt > 0.0)
@@ -141,31 +127,6 @@ def _batch_body(xb, yb, mb, is_soft, w1o, b1o, w2o, b2o, *, lr):
         b1o[...] = b1 - lr * db1
         w2o[...] = w2 - lr * dw2
         b2o[...] = b2 - lr * db2
-
-
-def _init_params(w1_ref, b1_ref, w2_ref, b2_ref, w1o, b1o, w2o, b2o):
-    w1o[...] = w1_ref[...]
-    b1o[...] = b1_ref[...]
-    w2o[...] = w2_ref[...]
-    b2o[...] = b2_ref[...]
-
-
-def _sgd_kernel(act_ref, x_ref, y_ref, m_ref, w1_ref, b1_ref, w2_ref, b2_ref,
-                w1o, b1o, w2o, b2o, *, lr, nb, epochs):
-    # one grid step == one client: params live in the output VMEM tiles and
-    # are updated in place across every batch of every epoch.  Batches sit
-    # on the slab's leading (untiled) axis, so each step's batch is an
-    # aligned whole-tile read.
-    _init_params(w1_ref, b1_ref, w2_ref, b2_ref, w1o, b1o, w2o, b2o)
-    is_soft = act_ref[pl.program_id(0)] == 1
-
-    def step(t, carry):
-        b = jax.lax.rem(t, nb)
-        _batch_body(x_ref[b], y_ref[b], m_ref[b], is_soft,
-                    w1o, b1o, w2o, b2o, lr=lr)
-        return carry
-
-    jax.lax.fori_loop(0, epochs * nb, step, 0)
 
 
 def _param_in_specs(inp, hid, classes):
@@ -215,67 +176,6 @@ def _params_args(w1, b1, w2, b2):
     )
 
 
-def _unpack_outs(outs, R):
-    return {"w1": outs[0], "b1": outs[1].reshape(R, -1), "w2": outs[2],
-            "b2": outs[3].reshape(R, -1)}
-
-
-@functools.partial(
-    jax.jit, static_argnames=("lr", "batch_size", "epochs", "interpret")
-)
-def local_sgd_fused(w1, b1, w2, b2, x, y, act, mask, *, lr: float,
-                    batch_size: int, epochs: int, interpret: bool = False):
-    """Fused local SGD over a block of clients.
-
-    w1 (I, H), b1 (H,), w2 (H, C), b2 (C,): the shared global model.
-    x (R, n, I) float; y (R, n) int; act (R,) int (0=relu, 1=softmax);
-    mask (R, n) bool/float validity (padding contributes zero gradient,
-    all-padding batches are skipped tiles).
-
-    Returns ``{"w1": (R, I, H), "b1": (R, H), "w2": (R, H, C),
-    "b2": (R, C)}`` — each client's post-SGD parameters, fp32.  The sample
-    axis is zero-padded up to a whole number of batches (mask-False, so the
-    tail never trains), matching the masked XLA path's ceil batching."""
-    R, n, inp = x.shape
-    hid = w1.shape[1]
-    classes = w2.shape[1]
-    nb = -(-n // batch_size)  # ceil: never drop real samples
-    pad = nb * batch_size - n
-    mask = mask.astype(jnp.float32)
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-        y = jnp.pad(y, ((0, 0), (0, pad)))
-        mask = jnp.pad(mask, ((0, 0), (0, pad)))
-    kernel = functools.partial(_sgd_kernel, lr=lr, nb=nb, epochs=epochs)
-    out_specs, out_shape = _param_out(R, inp, hid, classes)
-    slab = lambda i, *_: (i, 0, 0, 0)  # noqa: E731
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(R,),
-        in_specs=[
-            pl.BlockSpec((None, nb, batch_size, inp), slab),
-            pl.BlockSpec((None, nb, batch_size, 1), slab),
-            pl.BlockSpec((None, nb, batch_size, 1), slab),
-            *_param_in_specs(inp, hid, classes),
-        ],
-        out_specs=out_specs,
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-    )(
-        act.astype(jnp.int32),
-        x.astype(jnp.float32).reshape(R, nb, batch_size, inp),
-        y.astype(jnp.int32).reshape(R, nb, batch_size, 1),
-        mask.reshape(R, nb, batch_size, 1),
-        *_params_args(w1, b1, w2, b2),
-    )
-    return _unpack_outs(outs, R)
-
-
 def _ragged_kernel(act_ref, nb_ref, off_ref, x_ref, y_ref, m_ref,
                    w1_ref, b1_ref, w2_ref, b2_ref,
                    w1o, b1o, w2o, b2o, *, lr):
@@ -286,7 +186,10 @@ def _ragged_kernel(act_ref, nb_ref, off_ref, x_ref, y_ref, m_ref,
 
     @pl.when((e == 0) & (b == 0))
     def _():
-        _init_params(w1_ref, b1_ref, w2_ref, b2_ref, w1o, b1o, w2o, b2o)
+        w1o[...] = w1_ref[...]
+        b1o[...] = b1_ref[...]
+        w2o[...] = w2_ref[...]
+        b2o[...] = b2_ref[...]
 
     # ragged skip: grid batch steps past this client's own batch count are
     # no-ops (the index map clamps their tile fetch to a valid slot)
@@ -304,25 +207,29 @@ def _ragged_kernel(act_ref, nb_ref, off_ref, x_ref, y_ref, m_ref,
 def local_sgd_fused_ragged(w1, b1, w2, b2, xt, yt, mt, act, nb, off, *,
                            lr: float, epochs: int, nb_max: int,
                            interpret: bool = False):
-    """The WHOLE ragged bucketed layout in ONE ``pallas_call``.
+    """Local SGD for every client of one or more blocks in ONE
+    ``pallas_call``.
 
-    The caller flattens every width bucket into one batch-tile buffer:
+    The caller flattens its client blocks into one batch-tile buffer:
     ``xt`` (T, B, I) float, ``yt`` (T, B) int, ``mt`` (T, B) float validity
     — client r's tiles are ``xt[off[r] : off[r] + nb[r]]``.  ``act`` (R,)
     int per-client activation id, ``nb`` (R,) int32 per-client batch
     count, ``off`` (R,) int32 per-client tile offset (all scalar-prefetched
-    so the grid's index maps can address each client's slab); ``nb_max``
-    is the static grid bound ``max(nb)``.
+    so the grid's index maps can address each client's tiles); ``nb_max``
+    is the static grid bound ``max(nb)``.  A dense (R, n) block is the
+    one-bucket case: ``nb = ceil(n / B)`` for every client and
+    ``off = arange(R) * nb``.
 
     Grid (R, epochs, nb_max) — batch fastest, so each client's SGD walk is
     sequential while params stay resident in its output VMEM tiles; steps
-    with ``b >= nb[r]`` (a narrower client's tail of the widest bucket's
+    with ``b >= nb[r]`` (a narrower client's tail of the widest client's
     schedule) skip via ``pl.when``, which is how a SINGLE launch covers
-    every bucket width with zero per-bucket dispatch.
+    every width with zero per-bucket dispatch.
 
     Returns ``{"w1": (R, I, H), "b1": (R, H), "w2": (R, H, C),
-    "b2": (R, C)}`` — bit-identical to running ``local_sgd_fused`` per
-    bucket."""
+    "b2": (R, C)}``, each client's post-SGD parameters, fp32.  A client's
+    row depends on its own tiles alone: the same client in a launch of its
+    block by itself comes back bit for bit the same."""
     R = act.shape[0]
     T, batch, inp = xt.shape
     hid = w1.shape[1]
@@ -348,7 +255,8 @@ def local_sgd_fused_ragged(w1, b1, w2, b2, xt, yt, mt, act, nb, off, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(
         act.astype(jnp.int32),
@@ -359,4 +267,5 @@ def local_sgd_fused_ragged(w1, b1, w2, b2, xt, yt, mt, act, nb, off, *,
         mt.astype(jnp.float32).reshape(T, batch, 1),
         *_params_args(w1, b1, w2, b2),
     )
-    return _unpack_outs(outs, R)
+    return {"w1": outs[0], "b1": outs[1].reshape(R, -1), "w2": outs[2],
+            "b2": outs[3].reshape(R, -1)}

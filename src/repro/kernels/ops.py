@@ -9,8 +9,6 @@ while the module is imported.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 
 from repro.kernels import ref
@@ -18,7 +16,6 @@ from repro.kernels.compress import pack_codes as _pack_codes_kernel
 from repro.kernels.compress import unpack_codes as _unpack_codes_kernel
 from repro.kernels.fedavg_agg import fedavg_agg as _fedavg_agg_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
-from repro.kernels.local_sgd import local_sgd_fused as _local_sgd_kernel
 from repro.kernels.ssm_scan import ssm_scan as _ssm_kernel
 from repro.kernels.topk_select import topk_select as _topk_select_kernel
 
@@ -90,25 +87,6 @@ def topk_select(v, *, k: int, use_pallas: bool = True,
         return ref.topk_select_ref(v, k)
     itp = interpret_mode() if interpret is None else interpret
     return _topk_select_kernel(v, k=k, interpret=itp)
-
-
-def local_sgd(w1, b1, w2, b2, x, y, act, mask, *, lr: float, batch_size: int,
-              epochs: int, use_pallas: bool = True,
-              interpret: bool | None = None):
-    """Fused per-client local SGD over a block of clients (the FedAR
-    ClientUpdate hot path); ``use_pallas=False`` vmaps the pure-jnp oracle."""
-    if not use_pallas:
-        one = functools.partial(
-            ref.local_sgd_ref, lr=lr, batch_size=batch_size, epochs=epochs
-        )
-        return jax.vmap(
-            lambda xi, yi, ai, mi: one(w1, b1, w2, b2, xi, yi, ai, mi)
-        )(x, y, act, mask)
-    itp = interpret_mode() if interpret is None else interpret
-    return _local_sgd_kernel(
-        w1, b1, w2, b2, x, y, act, mask, lr=lr, batch_size=batch_size,
-        epochs=epochs, interpret=itp,
-    )
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, use_pallas: bool = True,

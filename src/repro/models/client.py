@@ -24,11 +24,12 @@ the dense path.
 
 Capability flags gate the engine's specialized hot paths:
 
-  ``supports_fused``   -- model ships a fused Pallas local-SGD kernel;
-                          ``fused_block_update`` may take a whole client
-                          block in one ``pallas_call`` when ``fused_fits``
-                          admits its width.  When False, the engine runs
-                          the vmapped XLA path (and warns if
+  ``supports_fused``   -- model ships a fused Pallas local-SGD kernel:
+                          ``fused_ragged_update`` takes every client block
+                          of a round, dense or packed, in one
+                          ``pallas_call`` when ``fused_fits`` admits one
+                          batch tile.  When False, the engine runs the
+                          vmapped XLA path (and warns if
                           ``sgd_impl="kernel"`` was forced).
   ``packed_supported`` -- model understands the size-bucketed packed layout
                           (``FederatedDataset.packed_arrays``); the packed
@@ -77,15 +78,16 @@ class ClientModel:
         raise NotImplementedError
 
     # ------------------------------------------------- hot-path hooks
-    def fused_fits(self, width: int, batch_size: int) -> bool:
-        """Whether the fused kernel can take a client block whose sample
-        axis is ``width`` wide (``supports_fused`` families override)."""
+    def fused_fits(self, batch_size: int) -> bool:
+        """Whether the fused kernel can take a batch tile of ``batch_size``
+        samples (``supports_fused`` families override)."""
         return False
 
-    def fused_block_update(self, global_flat, fields, sample_mask, *,
-                           lr, batch_size, epochs):
-        """Fused-kernel ClientUpdate over a whole client block: the stacked
-        post-SGD flat params (rows, D), in the same leaf order as
-        ``core.engine.flatten``.  The engine calls it only on the route it
-        decided from ``fused_fits``."""
+    def fused_ragged_update(self, global_flat, blocks, *, lr, batch_size,
+                            epochs):
+        """Fused-kernel ClientUpdate over a list of ``(fields,
+        sample_mask)`` client blocks of any widths (``sample_mask`` may be
+        ``None``): the stacked post-SGD flat params (sum rows, D), in block
+        order and in the same leaf order as ``core.engine.flatten``.  The
+        engine calls it only on the route it decided from ``fused_fits``."""
         raise NotImplementedError

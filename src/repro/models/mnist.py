@@ -14,11 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.fedar_mnist import MnistConfig
-from repro.kernels.local_sgd import (
-    fused_fits_vmem,
-    local_sgd_fused,
-    local_sgd_fused_ragged,
-)
+from repro.kernels.local_sgd import fused_fits_vmem, local_sgd_fused_ragged
 from repro.kernels.ops import interpret_mode
 from repro.models.client import ClientModel
 
@@ -174,47 +170,23 @@ class MnistClientModel(ClientModel):
             off += n
         return out
 
-    def fused_fits(self, width: int, batch_size: int) -> bool:
-        """Whether a client's ``width`` samples fit one grid step of the
-        fused kernel under its compiled VMEM limit."""
+    def fused_fits(self, batch_size: int) -> bool:
+        """Whether one batch tile fits a grid step of the fused kernel
+        under its compiled VMEM limit."""
         cfg = self.cfg
-        return fused_fits_vmem(width, cfg.input_dim, cfg.hidden,
-                               cfg.num_classes, batch=batch_size)
-
-    def fused_block_update(self, global_flat, fields, sample_mask, *,
-                           lr, batch_size, epochs):
-        """One ``pallas_call`` runs every client's whole masked
-        epochs x batches loop (the engine routes here only when
-        ``fused_fits`` admits the block width)."""
-        x, y, act = fields["x"], fields["y"], fields["activations"]
-        p = self._split_flat(global_flat)
-        mm = (
-            jnp.ones(x.shape[:2], bool) if sample_mask is None
-            else sample_mask
-        )
-        new = local_sgd_fused(
-            p["w1"], p["b1"], p["w2"], p["b2"], x, y, act, mm,
-            lr=lr, batch_size=batch_size, epochs=epochs,
-            interpret=interpret_mode(),
-        )
-        # flatten order must match ``flatten`` (dict leaves sort as
-        # b1, b2, w1, w2)
-        rows = x.shape[0]
-        return jnp.concatenate(
-            [new[k].reshape(rows, -1) for k in ("b1", "b2", "w1", "w2")],
-            axis=1,
-        )
+        return fused_fits_vmem(batch_size, cfg.input_dim, cfg.hidden,
+                               cfg.num_classes)
 
     def fused_ragged_update(self, global_flat, blocks, *, lr, batch_size,
                             epochs):
-        """The whole bucketed packed layout — ``blocks`` is a list of
-        ``(fields, sample_mask)`` rectangles of differing widths — in ONE
-        ragged-grid ``pallas_call`` (``local_sgd_fused_ragged``): every
-        bucket's clients flatten into a single batch-tile buffer addressed
-        by scalar-prefetched per-client offsets, so one launch replaces the
-        per-bucket dispatch loop.  Returns the (sum rows, D) post-SGD flat
-        params in block order (the engine routes here only when
-        ``fused_fits`` admits one batch tile)."""
+        """Every client block — ``blocks`` is a list of ``(fields,
+        sample_mask)`` rectangles, the dense sample block or the packed
+        layout's width buckets — in ONE ragged-grid ``pallas_call``
+        (``local_sgd_fused_ragged``): every block's clients flatten into a
+        single batch-tile buffer addressed by scalar-prefetched per-client
+        offsets.  Returns the (sum rows, D) post-SGD flat params in block
+        order (the engine routes here only when ``fused_fits`` admits one
+        batch tile)."""
         xts, yts, mts, acts, nbs = [], [], [], [], []
         for fields, m in blocks:
             x, y = fields["x"], fields["y"]

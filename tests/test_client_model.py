@@ -158,7 +158,7 @@ def test_kernel_request_falls_back_without_fused_model():
                     local_epochs=1, local_batch_size=4)
     with pytest.warns(UserWarning, match="falling back to the vmapped"):
         engine = FedAREngine(LMClientModel(cfg), fed, TaskRequirement())
-    assert engine._sgd_kernel is False
+    assert engine.sgd_route == "xla"
 
 
 def test_lm_model_rejects_packed_layout():

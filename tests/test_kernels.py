@@ -11,7 +11,7 @@ from repro.kernels.compress import pack_codes, unpack_codes
 from repro.kernels.defense_sim import sketch_similarity
 from repro.kernels.fedavg_agg import fedavg_agg
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.local_sgd import fused_fits_vmem, local_sgd_fused
+from repro.kernels.local_sgd import fused_fits_vmem, local_sgd_fused_ragged
 from repro.kernels.ssm_scan import ssm_scan
 
 
@@ -123,11 +123,47 @@ def _mlp(key, inp=16, hid=8, classes=10):
             jax.random.normal(k2, (hid, classes)) * 0.3, jnp.zeros((classes,)))
 
 
+def _ragged_inputs(buckets, bs):
+    """Tile width buckets of (x, y, mask, act) into the flat batch-tile
+    buffer + per-row (nb, off) geometry the kernel takes, each bucket's
+    sample axis zero-padded (mask-False) up to whole batches (mirrors
+    models.mnist.fused_ragged_update)."""
+    xts, yts, mts, acts, nbs = [], [], [], [], []
+    for x, y, m, a in buckets:
+        rows, w = x.shape[0], x.shape[1]
+        nb = -(-w // bs)
+        pad = nb * bs - w
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+        y = jnp.pad(y, ((0, 0), (0, pad)))
+        m = jnp.pad(m, ((0, 0), (0, pad)))
+        xts.append(x.reshape(rows * nb, bs, -1))
+        yts.append(y.reshape(rows * nb, bs))
+        mts.append(m.astype(jnp.float32).reshape(rows * nb, bs))
+        acts.append(a)
+        nbs.append(np.full(rows, nb, np.int32))
+    nb_arr = np.concatenate(nbs)
+    off = np.concatenate([[0], np.cumsum(nb_arr)[:-1]]).astype(np.int32)
+    return (jnp.concatenate(xts), jnp.concatenate(yts),
+            jnp.concatenate(mts), jnp.concatenate(acts),
+            jnp.asarray(nb_arr), jnp.asarray(off), int(nb_arr.max()))
+
+
+def _ragged_sgd(params, buckets, *, lr, batch_size, epochs):
+    """One ragged-grid launch over ``buckets`` (interpret mode)."""
+    xt, yt, mt, act, nb_arr, off, nb_max = _ragged_inputs(buckets,
+                                                           batch_size)
+    return local_sgd_fused_ragged(
+        *params, xt, yt, mt, act, nb_arr, off, lr=lr, epochs=epochs,
+        nb_max=nb_max, interpret=True,
+    )
+
+
 @pytest.mark.parametrize("act", [0, 1])
 @pytest.mark.parametrize("n,bs,epochs", [(40, 20, 2), (37, 10, 3), (8, 20, 1)])
 def test_local_sgd_fused_matches_oracle_and_model(act, n, bs, epochs):
     """The hand-written fused backward pass == jax.grad (the ref oracle AND
-    models.mnist.local_sgd), per Table II activation, ragged tails incl."""
+    models.mnist.local_sgd), per Table II activation, ragged tails incl.,
+    on a dense block (the kernel's one-bucket case)."""
     from repro.models.mnist import local_sgd as model_sgd
 
     w1, b1, w2, b2 = _mlp(jax.random.PRNGKey(act * 7 + n))
@@ -139,8 +175,8 @@ def test_local_sgd_fused_matches_oracle_and_model(act, n, bs, epochs):
     # ragged: full, partial, and tiny shards
     n_u = jnp.array([n, max(1, n // 2), 1])[:R]
     mask = jnp.arange(n)[None, :] < n_u[:, None]
-    got = local_sgd_fused(w1, b1, w2, b2, x, y, acts, mask, lr=0.1,
-                          batch_size=bs, epochs=epochs, interpret=True)
+    got = _ragged_sgd((w1, b1, w2, b2), [(x, y, mask, acts)], lr=0.1,
+                      batch_size=bs, epochs=epochs)
     for i in range(R):
         want = ref.local_sgd_ref(w1, b1, w2, b2, x[i], y[i], acts[i],
                                  mask[i], lr=0.1, batch_size=bs,
@@ -164,9 +200,11 @@ def test_local_sgd_fused_all_masked_is_noop():
     k = jax.random.PRNGKey(9)
     x = jax.random.normal(k, (1, 24, 16))
     y = jnp.zeros((1, 24), jnp.int32)
-    got = local_sgd_fused(w1, b1, w2, b2, x, y, jnp.zeros((1,), jnp.int32),
-                          jnp.zeros((1, 24), bool), lr=0.1, batch_size=20,
-                          epochs=2, interpret=True)
+    got = _ragged_sgd(
+        (w1, b1, w2, b2),
+        [(x, y, jnp.zeros((1, 24), bool), jnp.zeros((1,), jnp.int32))],
+        lr=0.1, batch_size=20, epochs=2,
+    )
     np.testing.assert_array_equal(got["w1"][0], w1)
     np.testing.assert_array_equal(got["b1"][0], b1)
     np.testing.assert_array_equal(got["w2"][0], w2)
@@ -184,9 +222,9 @@ def test_local_sgd_fused_dense_equals_unmasked_model_path():
     x = jax.random.normal(k, (2, 40, 16))
     y = jax.random.randint(jax.random.fold_in(k, 1), (2, 40), 0, 10)
     acts = jnp.array([0, 1], jnp.int32)
-    got = local_sgd_fused(w1, b1, w2, b2, x, y, acts,
-                          jnp.ones((2, 40), bool), lr=0.05, batch_size=20,
-                          epochs=2, interpret=True)
+    got = _ragged_sgd((w1, b1, w2, b2),
+                      [(x, y, jnp.ones((2, 40), bool), acts)], lr=0.05,
+                      batch_size=20, epochs=2)
     for i in range(2):
         dense = model_sgd(
             {"w1": w1, "b1": b1, "w2": w2, "b2": b2}, x[i], y[i], lr=0.05,
@@ -198,40 +236,19 @@ def test_local_sgd_fused_dense_equals_unmasked_model_path():
 
 
 def test_fused_fits_vmem_bounds():
-    """The VMEM estimate admits the paper's model at bucket widths and
-    rejects slabs that cannot fit."""
+    """The VMEM estimate admits the paper's model at its batch size and at
+    wide tiles, and rejects a batch tile that cannot fit."""
+    assert fused_fits_vmem(20, 784, 128, 10)
     assert fused_fits_vmem(512, 784, 128, 10)
     assert not fused_fits_vmem(65536, 784, 128, 10)
 
 
-def _ragged_inputs(buckets, bs):
-    """Tile mixed-width buckets of (x, y, mask, act) into the flat
-    batch-tile buffer + per-row (nb, off) geometry the ragged kernel takes
-    (mirrors models.mnist.fused_ragged_update)."""
-    xts, yts, mts, acts, nbs = [], [], [], [], []
-    for x, y, m, a in buckets:
-        rows, w = x.shape[0], x.shape[1]
-        nb = w // bs
-        xts.append(x.reshape(rows * nb, bs, -1))
-        yts.append(y.reshape(rows * nb, bs))
-        mts.append(m.astype(jnp.float32).reshape(rows * nb, bs))
-        acts.append(a)
-        nbs.append(np.full(rows, nb, np.int32))
-    nb_arr = np.concatenate(nbs)
-    off = np.concatenate([[0], np.cumsum(nb_arr)[:-1]]).astype(np.int32)
-    return (jnp.concatenate(xts), jnp.concatenate(yts),
-            jnp.concatenate(mts), jnp.concatenate(acts),
-            jnp.asarray(nb_arr), jnp.asarray(off))
-
-
 def test_local_sgd_fused_ragged_matches_per_bucket():
-    """ONE ragged-grid launch over mixed-width buckets is bit-equal to the
-    per-bucket ``local_sgd_fused`` dispatch loop it replaces — including a
-    fully-masked dummy row (mesh fill) and buckets whose batch count sits
-    below ``nb_max`` (the grid's tail steps must be true no-ops)."""
-    from repro.kernels.local_sgd import local_sgd_fused_ragged
-
-    w1, b1, w2, b2 = _mlp(jax.random.PRNGKey(11))
+    """ONE ragged-grid launch over mixed-width buckets is bit-equal to a
+    separate one-bucket launch of each bucket — including a fully-masked
+    dummy row (mesh fill) and buckets whose batch count sits below
+    ``nb_max`` (the grid's tail steps must be true no-ops)."""
+    params = _mlp(jax.random.PRNGKey(11))
     bs = 4
     k = jax.random.PRNGKey(12)
     buckets = []
@@ -246,24 +263,21 @@ def test_local_sgd_fused_ragged_matches_per_bucket():
         buckets.append([x, y, m, a])
     buckets[0][2] = buckets[0][2].at[1].set(False)  # dummy: all-masked row
     buckets[2][2] = buckets[2][2].at[0].set(False)  # all-masked whole batch
-    xt, yt, mt, act, nb_arr, off = _ragged_inputs(buckets, bs)
-    got = local_sgd_fused_ragged(
-        w1, b1, w2, b2, xt, yt, mt, act, nb_arr, off,
-        lr=0.1, epochs=2, nb_max=int(np.asarray(nb_arr).max()),
-        interpret=True,
-    )
+    got = _ragged_sgd(params, buckets, lr=0.1, batch_size=bs, epochs=2)
     r0 = 0
-    for x, y, m, a in buckets:
-        want = local_sgd_fused(w1, b1, w2, b2, x, y, a, m, lr=0.1,
-                               batch_size=bs, epochs=2, interpret=True)
+    for bucket in buckets:
+        want = _ragged_sgd(params, [bucket], lr=0.1, batch_size=bs, epochs=2)
+        rows = bucket[0].shape[0]
         for kk_ in ("w1", "b1", "w2", "b2"):
             np.testing.assert_array_equal(
-                np.asarray(got[kk_][r0:r0 + x.shape[0]]),
-                np.asarray(want[kk_]),
+                np.asarray(got[kk_][r0:r0 + rows]), np.asarray(want[kk_]),
             )
-        r0 += x.shape[0]
+        r0 += rows
     # the dummy rows specifically came back as the untouched globals
-    np.testing.assert_array_equal(np.asarray(got["w1"][1]), np.asarray(w1))
+    np.testing.assert_array_equal(np.asarray(got["w1"][1]),
+                                  np.asarray(params[0]))
+    np.testing.assert_array_equal(np.asarray(got["w1"][r0 - 2]),
+                                  np.asarray(params[0]))
 
 
 # ---------------------------------------------------------------------------

@@ -294,9 +294,9 @@ def test_gated_packed_equals_dense_full():
 def test_engine_sgd_kernel_routing_matches_xla():
     """sgd_impl="kernel" through the ENGINE (interpret mode off-TPU) must
     match the XLA vmap path — pins the engine glue the kernel tests can't
-    see: the fused_fits_vmem routing, the all-ones mask fallback for dense
-    fleets, and the b1/b2/w1/w2 concat order that must track flatten()'s
-    sorted-leaf order."""
+    see: the dense block as the ragged kernel's one-bucket case, the
+    all-ones mask fallback for dense fleets, and the b1/b2/w1/w2 concat
+    order that must track flatten()'s sorted-leaf order."""
     from repro.data.federated import scaled_fleet
 
     data = scaled_fleet(6, samples_per_client=40)
@@ -320,17 +320,18 @@ def test_engine_sgd_kernel_routing_matches_xla():
 @pytest.mark.parametrize("impl,layout,route", [
     ("einsum", "dense", "xla"),
     ("einsum", "packed", "xla"),
-    ("kernel", "dense", "fused"),
+    ("kernel", "dense", "fused_ragged"),
     ("kernel", "packed", "fused_ragged"),
 ])
 def test_sgd_route_recorded(impl, layout, route):
-    """The local-SGD route is decided host-side from the data's layout and
-    recorded on the engine (and in ``kernel_routes``) before tracing."""
+    """The local-SGD route is decided host-side from the config, recorded
+    on the engine (and in ``kernel_routes``) before tracing, and is the
+    route the round takes on either layout."""
     ds = make_federated("digits", 6, scenario="quantity_skew",
                         samples_per_client=20, seed=3)
     data = ds.arrays() if layout == "dense" else ds.packed_arrays()
     engine = _engine(6, local_epochs=1, sgd_impl=impl)
-    assert engine.sgd_route == ("xla" if impl == "einsum" else None)
+    assert engine.sgd_route == route
     engine.step(engine.init_state(), jax.tree.map(jnp.asarray, data))
     assert engine.sgd_route == route
     assert engine.kernel_routes()["sgd"] == route
@@ -350,23 +351,29 @@ def test_sgd_route_auto_follows_backend():
         assert routes["sgd"] == "xla"
         assert set(routes.values()) <= {"xla", "einsum", "none"}
     else:
-        assert routes["sgd"] == "fused" and routes["agg"] == "kernel"
+        assert routes["sgd"] == "fused_ragged" and routes["agg"] == "kernel"
 
 
 def test_explicit_kernel_too_wide_raises():
-    """An explicit ``sgd_impl="kernel"`` whose client block is wider than
-    the fused kernel's compiled VMEM limit admits raises before tracing,
-    instead of silently running the XLA path."""
+    """A client block of any width takes the kernel — its tiles stream one
+    at a time, so a 4,000-sample client needs no more VMEM than a 20-sample
+    one — but an explicit ``sgd_impl="kernel"`` whose batch tile is wider
+    than the fused kernel's compiled VMEM limit admits raises when the
+    engine is built, before any tracing, instead of silently running the
+    XLA path."""
     from repro.kernels.local_sgd import fused_fits_vmem
 
     width = 4000
-    assert not fused_fits_vmem(width, 784, 8, 10, batch=20)
     engine = _engine(2, local_epochs=1, sgd_impl="kernel")
     data = {"x": np.zeros((2, width, 784), np.float32),
             "y": np.zeros((2, width), np.int32),
             "activations": np.zeros(2, np.int32)}
+    engine._check_packed(data)  # all a step checks of the data
+    assert engine.kernel_routes()["sgd"] == "fused_ragged"
+    batch = 8192
+    assert not fused_fits_vmem(batch, 784, 8, 10)
     with pytest.raises(ValueError, match="does not fit the fused local-SGD"):
-        engine.step(engine.init_state(), data)
+        _engine(2, local_epochs=1, sgd_impl="kernel", local_batch_size=batch)
 
 
 def test_select_frac_validation():

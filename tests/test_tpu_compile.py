@@ -23,11 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.compress import pack_codes, unpack_codes
 from repro.kernels.defense_sim import sketch_similarity
 from repro.kernels.fedavg_agg import fedavg_agg
-from repro.kernels.local_sgd import (
-    fused_fits_vmem,
-    local_sgd_fused,
-    local_sgd_fused_ragged,
-)
+from repro.kernels.local_sgd import fused_fits_vmem, local_sgd_fused_ragged
 from repro.kernels.topk_select import ROWS, topk_select, topk_select_fits_vmem
 
 I, H, C = 784, 128, 10  # MnistConfig(): the paper's client MLP
@@ -73,44 +69,49 @@ def _params(spec):
     return spec((I, H)), spec((H,)), spec((H, C)), spec((C,))
 
 
+def _compile_sgd(spec, rows, nb, batch, epochs):
+    """Compile the fused local-SGD kernel over ``rows`` clients of ``nb``
+    batch tiles each (a dense block: the one-bucket case)."""
+    T = rows * nb
+    return _compile(
+        lambda *a: local_sgd_fused_ragged(*a, lr=0.1, epochs=epochs,
+                                          nb_max=nb),
+        *_params(spec), spec((T, batch, I)), spec((T, batch), i32),
+        spec((T, batch)), spec((rows,), i32), spec((rows,), i32),
+        spec((rows,), i32),
+    )
+
+
 @pytest.mark.parametrize("rows,n", [(12, 300), (K, 300)])
 def test_local_sgd_fused_compiles(spec, rows, n):
-    """The rectangular fused kernel: the paper's 12 robots at 300 samples,
-    and a K=512 cohort block."""
-    text = _compile(
-        lambda *a: local_sgd_fused(*a, lr=0.1, batch_size=B, epochs=E),
-        *_params(spec), spec((rows, n, I)), spec((rows, n), i32),
-        spec((rows,), i32), spec((rows, n)),
-    )
+    """A dense client block through the fused kernel: the paper's 12 robots
+    at 300 samples, and a K=512 cohort block."""
+    text = _compile_sgd(spec, rows, -(-n // B), B, E)
     assert "tpu_custom_call" in text
 
 
 def _widest_admitted():
-    n = B
-    while fused_fits_vmem(n + B, I, H, C, batch=B):
-        n += B
-    return n
+    batch = B
+    while fused_fits_vmem(batch + 8, I, H, C):
+        batch += 8
+    return batch
 
 
 def test_local_sgd_fused_vmem_budget_matches_compiler(spec):
-    """``fused_fits_vmem`` holds the block to what the compiler accepts:
-    the widest width it admits compiles under the kernel's VMEM limit, and
-    a block 1.6x wider is refused by the compiler itself."""
+    """``fused_fits_vmem`` holds a batch tile to what the compiler accepts:
+    the widest tile it admits compiles under the kernel's VMEM limit, and a
+    tile 1.6x wider is refused by the compiler itself."""
     widest = _widest_admitted()
-    assert widest >= 1000  # room for real quantity-skewed shards
+    assert widest >= 1000  # far past any client batch size in use
 
-    def compile_width(n):
-        return _compile(
-            lambda *a: local_sgd_fused(*a, lr=0.1, batch_size=B, epochs=1),
-            *_params(spec), spec((4, n, I)), spec((4, n), i32),
-            spec((4,), i32), spec((4, n)),
-        )
+    def compile_batch(batch):
+        return _compile_sgd(spec, 4, 3, batch, 1)
 
-    assert "tpu_custom_call" in compile_width(widest)
-    too_wide = int(widest * 1.6) // B * B
-    assert not fused_fits_vmem(too_wide, I, H, C, batch=B)
+    assert "tpu_custom_call" in compile_batch(widest)
+    too_wide = int(widest * 1.6) // 8 * 8
+    assert not fused_fits_vmem(too_wide, I, H, C)
     with pytest.raises(Exception, match="vmem"):
-        compile_width(too_wide)
+        compile_batch(too_wide)
 
 
 def test_local_sgd_fused_ragged_compiles(spec):
